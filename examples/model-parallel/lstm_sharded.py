@@ -85,7 +85,7 @@ def main():
     bshard = NamedSharding(mesh, P("dp"))
 
     def loss_fn(pv, x, y):
-        logits = cached(tuple(pv), key, True, x)[0][0]   # (B, T, V)
+        logits = cached(tuple(pv), key, True, mx.cpu(), x)[0][0]   # (B, T, V)
         logp = jax.nn.log_softmax(logits)
         return -jnp.mean(jnp.take_along_axis(
             logp, y[..., None].astype(jnp.int32), axis=-1))

@@ -52,7 +52,8 @@ from . import telemetry, threadsan
 
 __all__ = ["CompiledProgram", "tracked_jit", "aot_compile",
            "donate_argnums_for", "spmd_donate_enabled",
-           "explain_signature_change", "last_retrace", "reset"]
+           "explain_signature_change", "last_retrace", "reset",
+           "enable_compile_cache"]
 
 logger = logging.getLogger("mxnet_tpu.compiled")
 
@@ -63,6 +64,31 @@ _state = {"last_retrace": None}
 #: device_type values donation is skipped for: CPU backends do not
 #: implement buffer donation (JAX warns per compile and ignores it)
 _NO_DONATE_DEVICE_TYPES = ("cpu", "cpu_pinned", "cpu_shared")
+
+
+#: where the persistent compile cache lives when the environment does
+#: not place it: one fixed directory inside the checkout, because the
+#: path is part of the cache's key — a directory that moves never hits
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+
+
+def enable_compile_cache():
+    """Turn on jax's persistent compilation cache and return its
+    directory. For entry scripts (chip_smoke.py, bench.py, the serving
+    CLI, the example benchmarks) to call before their first compile —
+    never at ``import mxnet_tpu``, so the tests run without it.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself and
+    this sets nothing; otherwise the cache goes to
+    :data:`COMPILE_CACHE_DIR`."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    import jax
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
 
 
 def _enabled():
@@ -310,6 +336,14 @@ def _memory_of(compiled):
         return None
 
 
+def _any_tracer(args):
+    """Whether any leaf of ``args`` is a jax tracer: the call then sits
+    inside an outer trace and must go through plain jit dispatch."""
+    import jax
+    return any(isinstance(leaf, jax.core.Tracer)
+               for leaf in jax.tree_util.tree_leaves(args))
+
+
 def _hashable(x):
     try:
         hash(x)
@@ -409,9 +443,10 @@ class CompiledProgram:
         import jax
         if threadsan.ARMED:   # one attribute read when off
             threadsan.note_dispatch("compiled.%s" % self.site)
-        if kwargs or not jax.core.trace_state_clean():
+        if kwargs or _any_tracer(args):
             # called inside an outer trace (vjp/scan over a compiled
-            # program) or with kwargs: the plain dispatch path handles both
+            # program: some argument leaf is then a tracer) or with
+            # kwargs: the plain dispatch path handles both
             with self._mesh_scope():
                 return self._fn(*args, **kwargs)
         key = self._key(args)

@@ -6,16 +6,19 @@ first-class accelerator context; ``gpu(i)`` is kept as an API-compatible alias
 that resolves to the platform accelerator so reference user code
 (``ctx=mx.gpu(0)``) runs unchanged on TPU hosts.
 
-A Context maps onto a concrete ``jax.Device``. On CPU-only test hosts
-(``JAX_PLATFORMS=cpu`` with ``--xla_force_host_platform_device_count=N``) the
-accelerator contexts resolve onto the virtual host devices so the full test
-suite runs without a chip.
+A Context maps onto a concrete ``jax.Device``; :meth:`Context.jax_device`
+states the rule. In CPU mode (``JAX_PLATFORMS=cpu``, as the tests run, with
+``--xla_force_host_platform_device_count=N``) the accelerator contexts
+resolve onto the virtual host devices so the full test suite runs without a
+chip.
 """
 from __future__ import annotations
 
 import threading
 
 import jax
+
+from .base import MXNetError
 
 __all__ = ["Context", "cpu", "gpu", "tpu", "cpu_pinned", "current_context", "num_gpus", "num_tpus"]
 
@@ -71,9 +74,14 @@ class Context:
     def jax_device(self) -> "jax.Device":
         """Resolve to a concrete jax.Device.
 
-        cpu -> host platform device; tpu/gpu -> accelerator device of the
-        default backend, falling back to host devices when no accelerator is
-        attached (CPU test mode).
+        cpu contexts resolve to a host device whatever the default
+        backend is. tpu/gpu contexts resolve to local accelerator
+        ``device_id`` of the default backend, and a ``device_id`` beyond
+        the local count is an :class:`MXNetError`: a context never
+        lands on another chip than the one it names. The one exception
+        is CPU mode, when the default backend itself is ``cpu`` (the
+        tests' ``JAX_PLATFORMS=cpu``): accelerator contexts are then
+        emulated on the host devices, ``device_id`` wrapping over them.
         """
         # local_devices only: under jax.distributed, jax.devices() is the
         # GLOBAL list and would resolve to another process's
@@ -82,20 +90,23 @@ class Context:
             devs = _local_cpu_devices()
             return devs[min(self.device_id, len(devs) - 1)]
         devs = _accelerator_devices()
-        if not devs:
+        if not devs:   # CPU mode
             devs = _local_cpu_devices()
-        return devs[self.device_id % len(devs)]
+            return devs[self.device_id % len(devs)]
+        if self.device_id >= len(devs):
+            raise MXNetError(
+                "%s: this process has %d accelerator device(s) (%s)"
+                % (self, len(devs), devs[0].device_kind))
+        return devs[self.device_id]
 
     def empty_cache(self):
         """Reference `Context.empty_cache`; XLA manages its own pools: no-op."""
 
 
 def _accelerator_devices():
-    try:
-        devs = jax.local_devices()
-    except RuntimeError:
-        return []
-    return [d for d in devs if d.platform != "cpu"]
+    """This process's devices of the default backend, ``[]`` when that
+    backend is ``cpu``. A backend that fails to initialize raises."""
+    return [d for d in jax.local_devices() if d.platform != "cpu"]
 
 
 def _local_cpu_devices():
@@ -104,8 +115,14 @@ def _local_cpu_devices():
     jax.devices('cpu') list, whose head belongs to process 0."""
     try:
         return jax.local_devices(backend="cpu")
-    except RuntimeError:
-        return jax.devices("cpu")
+    except RuntimeError as exc:
+        # the platform list named the accelerator alone: jax creates only
+        # the backends it names. The default context is cpu(0) and
+        # iterators, loaded params and metrics live on the host, so the
+        # host backend has to exist beside the chip's
+        raise MXNetError(
+            "cpu contexts need jax's cpu backend; add it to the platform "
+            "list (JAX_PLATFORMS=%s,cpu)" % jax.default_backend()) from exc
 
 
 def cpu(device_id=0):

@@ -398,11 +398,19 @@ class HybridBlock(Block):
         self._param_order = names
         block = self
 
-        def traced(param_vals, key, is_train, *input_vals):
+        def traced(param_vals, key, is_train, ctx, *input_vals):
             from .. import autograd, random as _random
             from ..ops.invoke import _TLS as _invoke_tls
-            param_nds = {n: _from_data(v) for n, v in zip(names, param_vals)}
-            input_nds = [_from_data(v) if v is not None else None
+            # a tracer knows no device: the trace wraps its tracers in
+            # `ctx`, the context of the call that caused it, which ops
+            # that choose a lowering per device (the space-to-depth stem,
+            # the fused LSTM) read as `_ctx`. Without it every op of a net
+            # hybridized on the chip saw cpu(0) and took the CPU lowering.
+            # It is a static argument: one trace for each context, so a
+            # net moved with reset_ctx never replays the other's lowering.
+            param_nds = {n: _from_data(v, ctx)
+                         for n, v in zip(names, param_vals)}
+            input_nds = [_from_data(v, ctx) if v is not None else None
                          for v in input_vals]
             with _ParamOverride(block, param_nds):
                 with _random.key_scope(key):
@@ -437,7 +445,7 @@ class HybridBlock(Block):
             # them — the TPU analog of MXNET_BACKWARD_DO_MIRROR
             # (docs/architecture/note_memory.md); usage:
             # net.hybridize(remat=True)
-            traced = jax.checkpoint(traced, static_argnums=(2,))
+            traced = jax.checkpoint(traced, static_argnums=(2, 3))
         from .. import compiled as compiled_mod
         # one CompiledProgram per hybridized block: retraces (shape/dtype
         # churn at the block's inputs) surface as jit_retraces_total{site=}
@@ -445,7 +453,7 @@ class HybridBlock(Block):
         # rebuilt jits of ONE net diff while unrelated nets never
         # cross-diff
         self._cached_jit = compiled_mod.tracked_jit(
-            traced, "gluon.hybrid_forward", static_argnums=(2,),
+            traced, "gluon.hybrid_forward", static_argnums=(2, 3),
             lineage=id(self))
 
     def _collect_all_params(self):
@@ -463,6 +471,8 @@ class HybridBlock(Block):
         param_nds = [params[n].data() for n in names]
         param_vals = [p._data for p in param_nds]
         input_vals = [a._data if isinstance(a, NDArray) else a for a in args]
+        ctx = next((a.ctx for a in args if isinstance(a, NDArray)),
+                   param_nds[0].ctx if param_nds else None)
         key_anchor = param_vals[0] if param_vals else (
             input_vals[0] if input_vals else None)
         key = _random.next_key_like(key_anchor)
@@ -472,7 +482,7 @@ class HybridBlock(Block):
             # differentiable path: vjp through the jitted program; aux
             # (BN moving stats) rides along undifferentiated
             def f(pvals, ivals):
-                return self._cached_jit(pvals, key, is_train, *ivals)
+                return self._cached_jit(pvals, key, is_train, ctx, *ivals)
             outs, vjp_fn, aux_up = jax.vjp(f, param_vals, input_vals,
                                            has_aux=True)
             tape_inputs = param_nds + [a for a in args if isinstance(a, NDArray)]
@@ -485,14 +495,12 @@ class HybridBlock(Block):
                                  [o.shape for o in outs],
                                  [np.dtype(o.dtype) for o in outs],
                                  name=self.name)
-            ctx = args[0].ctx if args and isinstance(args[0], NDArray) else None
             out_nds = [_from_data(o, ctx) for o in outs]
             for i, o in enumerate(out_nds):
                 o._autograd_node = (node, i)
         else:
-            outs, aux_up = self._cached_jit(param_vals, key, is_train,
+            outs, aux_up = self._cached_jit(param_vals, key, is_train, ctx,
                                             *input_vals)
-            ctx = args[0].ctx if args and isinstance(args[0], NDArray) else None
             out_nds = [_from_data(o, ctx) for o in outs]
         # commit mutated aux states (BN moving stats) back to the params
         for n, v in aux_up.items():

@@ -668,9 +668,9 @@ class FusedApplier:
 
     Eager per-parameter updates cost one host->device dispatch each — for
     a ResNet-50 that is ~160 dispatches per step, which dominates step
-    time whenever dispatch latency is nontrivial (always true for a
-    remote/tunneled chip; the reference amortizes the same cost by
-    running updates inside engine bulk segments, graph_executor.cc:1377).
+    time whenever dispatch latency is nontrivial (the reference amortizes
+    the same cost by running updates inside engine bulk segments,
+    graph_executor.cc:1377).
 
     This wrapper traces the SAME registered update ops
     (`ops/optimizer_ops.py`) over every parameter inside a single jitted

@@ -1,17 +1,10 @@
-"""Version shim: `shard_map` moved from jax.experimental to jax core and
-renamed its replication-check kwarg (check_rep -> check_vma). One shim,
-shared by ring_attention / pipeline / moe."""
+"""`shard_map` as ring_attention / pipeline / moe call it: positional
+mesh and specs, replication checking off (`check_vma=False`)."""
 from __future__ import annotations
 
-try:
-    from jax import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-except ImportError:  # older jax: same call, pre-rename kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map
 
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+def shard_map(f, mesh, in_specs, out_specs):
+    return _shard_map(f, mesh=mesh, in_specs=in_specs,
+                      out_specs=out_specs, check_vma=False)

@@ -76,11 +76,11 @@ def init(coordinator_address=None, num_processes=None, process_id=None,
             chaos.maybe_timeout("dist.init")
             try:
                 if recoverable:
-                    _init_recoverable(coordinator_address, num_processes,
-                                      process_id)
-                else:
-                    jax.distributed.initialize(coordinator_address,
-                                               num_processes, process_id)
+                    # the runtime client's `recoverable` flag, through
+                    # the config state jax.distributed reads it from
+                    jax.config.update("jax_enable_recoverability", True)
+                jax.distributed.initialize(coordinator_address,
+                                           num_processes, process_id)
             except Exception:
                 # a failed connect leaves jax's global state partially
                 # initialized (client/service assigned BEFORE connect),
@@ -106,41 +106,6 @@ def init(coordinator_address=None, num_processes=None, process_id=None,
     if jax.process_count() > 1:
         start_heartbeat(float(os.environ.get(
             "MXNET_HEARTBEAT_INTERVAL", "5")))
-
-
-def _init_recoverable(coordinator_address, num_processes, process_id):
-    """jax.distributed.initialize with the runtime client's `recoverable`
-    flag set — not exposed through the public signature (jax 0.9), so the
-    client constructor is wrapped for the duration of the call; on ANY
-    incompatibility with this jax version (module moved, kwarg
-    unsupported), degrade to a plain initialize — a missing recoverable
-    flag must never stop the job from starting.
-    """
-    try:
-        from jax._src.lib import _jax as _jaxlib
-        orig = _jaxlib.get_distributed_runtime_client
-    except Exception:
-        import warnings
-        warnings.warn("recoverable init unsupported on this jax version; "
-                      "falling back to plain jax.distributed.initialize")
-        jax.distributed.initialize(coordinator_address, num_processes,
-                                   process_id)
-        return
-
-    def patched(*args, **kwargs):
-        kwargs["recoverable"] = True
-        try:
-            return orig(*args, **kwargs)
-        except TypeError:
-            kwargs.pop("recoverable", None)
-            return orig(*args, **kwargs)
-
-    _jaxlib.get_distributed_runtime_client = patched
-    try:
-        jax.distributed.initialize(coordinator_address, num_processes,
-                                   process_id)
-    finally:
-        _jaxlib.get_distributed_runtime_client = orig
 
 
 def _clear_jax_distributed_state():

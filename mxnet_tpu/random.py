@@ -10,8 +10,7 @@ deliberately not attempted; tests are statistical).
 Like the reference (one sampler per device, random_generator.h), the key
 chain is **per jax.Device**: splits execute on the device that will consume
 the bits. A single global key would live on the default device and drag
-every op on another device through a cross-device copy — on a remote-TPU
-platform that is a tunnel round trip per sample.
+every op on another device through a cross-device copy per sample.
 """
 from __future__ import annotations
 

@@ -76,6 +76,7 @@ from .. import telemetry
 from .. import threadsan
 from .. import xla_stats
 from ..base import MXNetError
+from ..context import cpu, num_tpus, tpu
 from ..predict import Predictor
 from . import reqtrace
 from .batching import bucket_sizes, pick_bucket, pad_rows, split_rows
@@ -211,6 +212,9 @@ class InferenceEngine:
     ctx : Context or list[Context], optional
         One context (replicated ``config.replicas`` times) or an
         explicit per-replica list (overrides ``config.replicas``).
+        Default: the local accelerator, ``tpu(0)``, when the default
+        backend has one; the CPU in CPU mode (``JAX_PLATFORMS=cpu``).
+        ``stats()["platform"]`` says where the replicas' buffers are.
     output_names : list[str], optional
         Partial-out binding, as `Predictor`.
     config : EngineConfig, optional
@@ -227,6 +231,8 @@ class InferenceEngine:
         self._example_shapes = {str(k): tuple(int(d) for d in v)
                                 for k, v in input_shapes.items()}
         self._buckets = bucket_sizes(self.config.max_batch_size)
+        if ctx is None:
+            ctx = tpu(0) if num_tpus() else cpu()
         if isinstance(ctx, (list, tuple)):
             ctxs = list(ctx)   # explicit list wins over config.replicas
         else:
@@ -269,6 +275,12 @@ class InferenceEngine:
             for name in self._example_shapes}
         self.num_outputs = self._replicas[0].preds[self._buckets[0]] \
             .num_outputs
+        # read off the bound buffers, not the contexts' names: what
+        # /healthz reports is where the weights and inputs really are
+        self.platform = ",".join(sorted({
+            dev.platform for rep in self._replicas
+            for arr in rep.preds[self._buckets[0]]._exec.arg_dict.values()
+            for dev in arr._data.devices()}))
 
         self._queue = _queue.Queue(maxsize=self.config.max_queue)
         self._work = _queue.Queue(maxsize=len(self._replicas))
@@ -596,6 +608,7 @@ class InferenceEngine:
                                  if r.thread is not None
                                  and r.thread.is_alive()),
             "replicas": len(self._replicas),
+            "platform": self.platform,
             "buckets": list(self._buckets),
             "warmup_compiles": self.warmup_compiles,
             "cold_compiles": self.cold_compiles(),

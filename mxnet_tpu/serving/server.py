@@ -23,6 +23,7 @@ exposing:
   ``peak_fraction`` (worst device peak / limit), and
   ``admission_rejections_total`` — so a placer can tell "this host
   cannot take another model" apart from "this host is busy".
+  ``platform`` says where the replicas' buffers are (``tpu``, ``cpu``).
 - ``GET /metrics`` — the whole telemetry registry as Prometheus text
   (`telemetry.dumps()`): serving counters/histograms, compile
   accounting, everything the process recorded.
@@ -294,6 +295,8 @@ def main(argv=None):
                          "deployments)")
     args = ap.parse_args(argv)
 
+    from ..compiled import enable_compile_cache
+    enable_compile_cache()   # a restarted server finds its buckets compiled
     with open(args.symbol, "r", encoding="utf-8") as fh:
         symbol_json = fh.read()
     cfg = EngineConfig(max_batch_size=args.max_batch,
@@ -307,7 +310,7 @@ def main(argv=None):
                                allow_shutdown=args.allow_shutdown)
     print("SERVING %s" % json.dumps({
         "host": args.host, "port": server.port,
-        "buckets": engine.buckets,
+        "platform": engine.platform, "buckets": engine.buckets,
         "warmup_compiles": engine.warmup_compiles}), flush=True)
     try:
         server.serve_forever()

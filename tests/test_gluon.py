@@ -240,3 +240,72 @@ def test_hybridized_nested_deferred_bn_updates_stats():
         net(x)
     mean = net.bn.running_mean.data().asnumpy()
     assert np.abs(mean).max() > 0.5, mean  # stats moved off init
+
+
+def test_hybridized_trace_keeps_the_callers_context():
+    """Ops inside a hybridized block see the context of the call as
+    `_ctx` (a tracer has no device of its own): the device-gated
+    lowerings (`_s2d_eligible`, `_fused_lstm_ok`) read it. Found on the
+    chip: the trace wrapped its tracers in the default cpu(0), so a net
+    hybridized on mx.tpu() never got the space-to-depth stem."""
+    seen = []
+
+    class Probe(gluon.HybridBlock):
+        def __init__(self):
+            super().__init__()
+            with self.name_scope():
+                self.dense = nn.Dense(2, in_units=3)
+
+        def hybrid_forward(self, F, x):
+            seen.append(x.ctx)
+            out = self.dense(x)
+            seen.append(out.ctx)
+            return out
+
+    for ctx in (mx.tpu(1), mx.cpu(0)):   # tpu(1): host device 1 in CPU mode
+        net = Probe()
+        net.initialize(ctx=ctx)
+        net.hybridize()
+        del seen[:]
+        out = net(mx.nd.ones((4, 3), ctx=ctx))
+        assert seen and all(c == ctx for c in seen), (ctx, seen)
+        assert out.ctx == ctx
+
+
+def test_hybridized_net_moved_to_another_context_is_traced_again():
+    """The context is part of the compiled program's key: ONE hybridized
+    net called on a second context at the same shape is traced for that
+    context, and does not replay the lowering chosen for the first (a
+    net first run on cpu() and then moved with reset_ctx(mx.tpu()) would
+    lose the space-to-depth stem and the fused LSTM again; the other way
+    round a Pallas lowering would run on host buffers)."""
+    traced_for = []
+
+    class Probe(gluon.HybridBlock):
+        def __init__(self):
+            super().__init__()
+            with self.name_scope():
+                self.dense = nn.Dense(2, in_units=3)
+
+        def hybrid_forward(self, F, x):
+            traced_for.append(x.ctx)
+            return self.dense(x)
+
+    net = Probe()
+    net.initialize(ctx=mx.cpu(0))
+    net.hybridize()
+    x = np.arange(12, dtype=np.float32).reshape(4, 3)
+    first = net(mx.nd.array(x, ctx=mx.cpu(0)))
+    assert traced_for == [mx.cpu(0)]
+    net.collect_params().reset_ctx(mx.tpu(1))
+    moved = net(mx.nd.array(x, ctx=mx.tpu(1)))
+    assert traced_for == [mx.cpu(0), mx.tpu(1)]      # traced again
+    assert moved.ctx == mx.tpu(1)
+    assert moved._data.devices() == {mx.tpu(1).jax_device()}
+    np.testing.assert_allclose(moved.asnumpy(), first.asnumpy(), rtol=1e-6)
+    # each context keeps its program: going back and forth traces no more
+    net(mx.nd.array(x, ctx=mx.tpu(1)))
+    net.collect_params().reset_ctx(mx.cpu(0))
+    back = net(mx.nd.array(x, ctx=mx.cpu(0)))
+    assert traced_for == [mx.cpu(0), mx.tpu(1)]
+    assert back.ctx == mx.cpu(0)

@@ -104,6 +104,35 @@ def test_dp_module_forward_outputs_global_batch():
     np.testing.assert_allclose(probs.sum(axis=1), 1.0, rtol=1e-5)
 
 
+def test_dp_module_takes_given_params_onto_the_mesh():
+    """`fit(arg_params=...)` copies the caller's host arrays INTO the
+    replicated buffers (NDArray.copyto keeps the destination's
+    placement): the params stay on all 8 devices and the run matches
+    the one-device run from the same params."""
+    X, y = _make_data()
+    rng = np.random.RandomState(3)
+    given = {"fc1_weight": rng.randn(16, 20) * 0.1, "fc1_bias": np.zeros(16),
+             "fc2_weight": rng.randn(3, 16) * 0.1, "fc2_bias": np.zeros(3)}
+
+    def fit(contexts):
+        it = mx.io.NDArrayIter(X, y, batch_size=32,
+                               label_name="softmax_label")
+        mod = mx.mod.Module(_mlp(), context=contexts)
+        mod.fit(it, num_epoch=2, optimizer_params=(("learning_rate", 0.5),),
+                arg_params={k: mx.nd.array(v) for k, v in given.items()})
+        return mod
+
+    dp = fit([mx.cpu(i) for i in range(8)])
+    for name in given:
+        w = dp._exec.arg_dict[name]._data
+        assert len(w.sharding.device_set) == 8, name
+    one = fit([mx.cpu(0)])
+    for name in given:
+        np.testing.assert_allclose(dp._exec.arg_dict[name].asnumpy(),
+                                   one._exec.arg_dict[name].asnumpy(),
+                                   rtol=2e-4, atol=2e-5, err_msg=name)
+
+
 def test_dp_module_rejects_indivisible_batch():
     X, y = _make_data(n=60)
     it = mx.io.NDArrayIter(X, y, batch_size=30, label_name="softmax_label")
