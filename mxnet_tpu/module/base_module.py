@@ -305,13 +305,20 @@ class BaseModule:
             data_iter = iter(train_data)
             end_of_batch = False
             next_data_batch = next(data_iter)
-            # every loop iteration is one stepprof step; the taxonomy
-            # phases inside come from _step/_step_scan/update (h2d,
-            # dispatch, device_compute, sync, opt_update) plus the two
-            # loop-level phases here: data_wait (iterator blocked) and
-            # device_compute via the metric readback — reading outputs
-            # to host is where the step's async device work is actually
-            # awaited, so that wait is device time, not "sync"
+            # every loop iteration is one stepprof step, and the steps
+            # tile the loop: the batch counters and the batch-end
+            # callbacks run INSIDE the step in both paths, so one step
+            # ends where the next begins (the `while` test between) and
+            # a step's `other` is the loop's own Python, the throughput
+            # counters and the user's callbacks. The taxonomy phases
+            # inside come from _step/_step_scan/update (h2d, dispatch,
+            # device_compute, sync, opt_update) plus the two loop-level
+            # phases here: data_wait (iterator blocked) and
+            # device_compute via the metric readback. That one is a
+            # host wait: reading outputs to host is where the loop
+            # waits for whatever of the step is still in flight, the
+            # staging of its batch included (the device's own time is
+            # the trace's, not this phase's)
             while not end_of_batch:
                 if use_scan:
                     # gather up to K batches, run them in one dispatch
@@ -393,16 +400,16 @@ class BaseModule:
                     with stepprof.phase("device_compute",
                                         via="update_metric"):
                         self.update_metric(eval_metric, data_batch.label)
-                _count_fit_batch(data_batch, eval_metric)
-                if monitor is not None:
-                    monitor.toc_print()
-                if batch_end_callback is not None:
-                    batch_end_params = BatchEndParam(epoch=epoch, nbatch=nbatch,
-                                                     eval_metric=eval_metric,
-                                                     locals=locals())
-                    for callback in _as_list(batch_end_callback):
-                        callback(batch_end_params)
-                nbatch += 1
+                    _count_fit_batch(data_batch, eval_metric)
+                    if monitor is not None:
+                        monitor.toc_print()
+                    if batch_end_callback is not None:
+                        batch_end_params = BatchEndParam(
+                            epoch=epoch, nbatch=nbatch,
+                            eval_metric=eval_metric, locals=locals())
+                        for callback in _as_list(batch_end_callback):
+                            callback(batch_end_params)
+                    nbatch += 1
 
             for name, val in eval_metric.get_name_value():
                 self.logger.info("Epoch[%d] Train-%s=%f", epoch, name, val)

@@ -588,13 +588,16 @@ class Module(BaseModule):
         # an open step record, so predict/score staging does not pollute
         # the step_h2d_seconds histogram (and .prom-derived verdicts)
         if stepprof.in_step():
-            with stepprof.phase("h2d"):
-                self._load_batch_impl(data_batch)
+            with stepprof.phase("h2d") as ph:
+                ph["bytes"] = self._load_batch_impl(data_batch)
         else:
             self._load_batch_impl(data_batch)
 
     def _load_batch_impl(self, data_batch):
+        """Stage the batch into the executor's inputs; returns the bytes
+        staged (the ``bytes`` of the ``h2d`` phase)."""
         data = data_batch.data
+        staged = 0
         for name, arr in zip(self._data_names, data):
             dst = self._exec.arg_dict[name]
             if dst.shape != arr.shape:
@@ -604,10 +607,14 @@ class Module(BaseModule):
                               zip(self._label_names, data_batch.label or [])] or None)
                 dst = self._exec.arg_dict[name]
             dst[:] = arr
+            staged += dst._data.nbytes
         if data_batch.label is not None:
             for name, arr in zip(self._label_names, data_batch.label):
-                if name in self._exec.arg_dict:
-                    self._exec.arg_dict[name][:] = arr
+                dst = self._exec.arg_dict.get(name)
+                if dst is not None:
+                    dst[:] = arr
+                    staged += dst._data.nbytes
+        return staged
 
     def forward_backward(self, data_batch):
         """Fused fwd+bwd: one compiled XLA dispatch (see executor)."""
@@ -684,6 +691,10 @@ class Module(BaseModule):
         live_names, indices, fused, step_fn, _ = self._fused_plan
         self._load_batch(data_batch)
         exec_ = self._exec
+        # the dispatch phase ends where the compiled call returns: its
+        # end is the step program's enqueue instant, which the
+        # benchmark's join with the device trace splits idle time on
+        # (benchmark/timeline.py); keep everything after the call out
         with stepprof.phase("dispatch", site="module.fused_step"):
             arg_vals, aux_vals = exec_._gather()
             key = exec_._next_key()
@@ -713,9 +724,7 @@ class Module(BaseModule):
                                         kind="sync")
             with stepprof.phase("device_compute", synced=True) as _dc:
                 jax.block_until_ready((outs, new_ws))
-            stepprof.note_device_sample(
-                _dc.seconds, batches=1,
-                flops_per_batch=xla_stats.flops_per_batch())
+            stepprof.note_device_sample(_dc.seconds, batches=1)
         for name, val in aux_up.items():
             exec_.aux_dict[name]._data = val
         for w, nv in zip(weights, new_ws):
@@ -968,9 +977,12 @@ class Module(BaseModule):
         if isinstance(data_batches, dict):
             placed = data_batches  # prestacked: staging already paid
         else:
-            with stepprof.phase("h2d", via="stack_batches"):
+            with stepprof.phase("h2d", via="stack_batches") as ph:
                 placed = self.stack_batches(data_batches)
+                ph["bytes"] = sum(v.nbytes for v in placed.values())
 
+        # as in _step: the phase ends at the return of the compiled
+        # call, the enqueue instant of this dispatch
         with stepprof.phase("dispatch", site="module.scan_step"):
             arg_vals, aux_vals = exec_._gather()
             grad_args = {n: arg_vals[n] for n in exec_._grad_names}
@@ -997,9 +1009,7 @@ class Module(BaseModule):
             with stepprof.phase("device_compute", synced=True,
                                 batches=K) as _dc:
                 jax.block_until_ready((ga, outs))
-            stepprof.note_device_sample(
-                _dc.seconds, batches=K,
-                flops_per_batch=xla_stats.flops_per_batch())
+            stepprof.note_device_sample(_dc.seconds, batches=K)
         for name, val in aux.items():
             exec_.aux_dict[name]._data = val
         # rebind EVERY carried arg (not just the updated weights): with

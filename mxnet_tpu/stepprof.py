@@ -11,9 +11,9 @@ per-phase timelines, JAX-native):
     h2d             host->device transfer / batch staging
     dispatch        python + tracing + call overhead until the async
                     XLA dispatch returns
-    device_compute  device busy, observed where the host actually waits
-                    on device results (metric readback / a sampled
-                    `block_until_ready` bracket — see "sampled sync")
+    device_compute  the host waits on device results (metric readback /
+                    a sampled `block_until_ready` bracket — see "sampled
+                    sync"): a host wait, see "Timeline" for what it holds
     sync            kvstore / collective gradient aggregation
     opt_update      optimizer apply (unfused path; fused steps carry it
                     inside `dispatch`'s one program)
@@ -53,14 +53,27 @@ a MULTICHIP run names its slow host instead of averaging it away.
 Sampled sync: a forced ``jax.block_until_ready`` bracket measures TRUE
 device time but serializes the pipeline, so it is off by default.
 ``MXNET_STEPPROF_SYNC_EVERY=N`` (or ``enable(sync_every=N)``) brackets
-every Nth step; `Module._step`/`_step_scan` honor it and cross-check the
-measured rate against ``cost_analysis`` FLOPs
-(``step_device_flops_per_second`` gauge, comparable to ``mfu``).
+every Nth step; `Module._step`/`_step_scan` honor it.
+
+Timeline: every step record also keeps WHEN things happened, so that a
+reader can lay the host's side of a run against another clock (the
+device trace's: ``benchmark/timeline.py``) after the run, from memory.
+A record holds ``seq`` (the step's number in this profiler), one clock
+pair read back to back at step entry (``time.time_ns()``,
+``time.perf_counter()``), and its phases in the order they ended as
+``(name, start offset from step entry in s, duration in s, attrs)``,
+one entry per occurrence, all on ``perf_counter``. :func:`timeline`
+returns the ring (``MXNET_STEPPROF_WINDOW`` steps) as plain data.
+``device_compute via=update_metric`` is a HOST WAIT, not device time:
+it ends when the outputs are on the host, and starts wherever the loop
+got round to asking, so it can hold the tail of the staging of the
+batch as well as the step program (device time is the trace's).
 
 Recording is always on and costs what the PR 2 fit spans cost (a dict
-lookup and two clock reads per phase); ``MXNET_STEPPROF=1`` additionally
-arms the `callback.Speedometer` one-line phase summary and the sampled
-sync default. Stdlib + telemetry only at import — jax is imported
+lookup and two clock reads per phase, plus one tuple per phase for the
+timeline); ``MXNET_STEPPROF=1`` additionally arms the
+`callback.Speedometer` one-line phase summary and the sampled sync
+default. Stdlib + telemetry only at import — jax is imported
 lazily inside the sampled-sync path only.
 
 Lock order: this module has ONE lock (the profiler ``_lock``); it may
@@ -83,7 +96,8 @@ __all__ = ["PHASES", "PHASE_OTHER", "StepProfiler", "profiler", "phase",
            "step", "record_step", "ImplicitStepper", "enabled",
            "enable", "disable",
            "should_sync", "note_device_sample", "totals", "shares",
-           "overlap", "classify", "verdict", "snapshot", "reset",
+           "overlap", "classify", "verdict", "snapshot", "timeline",
+           "reset",
            "write_host_snapshot", "merge_host_snapshots",
            "detect_stragglers", "report", "main"]
 
@@ -174,7 +188,8 @@ class _Phase:
     def __exit__(self, exc_type, exc, tb):
         self.seconds = time.perf_counter() - self._t0
         self._span.__exit__(exc_type, exc, tb)
-        self.prof._note_phase(self.name, self.seconds)
+        self.prof._note_phase(self.name, self.seconds, self._t0,
+                              self._span.attrs)
         return None
 
 
@@ -184,13 +199,14 @@ class _Step:
     handed to the profiler on exit. Extra attrs land in the JSONL span
     (``sp["batches"] = K``)."""
 
-    __slots__ = ("prof", "attrs", "phases", "synced", "batches",
-                 "_span", "_t0", "_outer")
+    __slots__ = ("prof", "attrs", "phases", "spans", "synced", "batches",
+                 "_span", "_t0", "_wall_ns", "_outer")
 
     def __init__(self, prof, batches=1, **attrs):
         self.prof = prof
         self.attrs = attrs
         self.phases = {}
+        self.spans = []   # (name, start - _t0, seconds, attrs), as ended
         self.synced = False
         self.batches = int(batches)
         self._span = telemetry.span("step", **attrs)
@@ -202,6 +218,9 @@ class _Step:
 
     def __enter__(self):
         self._span.__enter__()
+        # the record's clock pair, read back to back: what places this
+        # step's perf_counter offsets on the wall clock afterwards
+        self._wall_ns = time.time_ns()
         self._t0 = time.perf_counter()
         self._outer = getattr(self.prof._tl, "current", None)
         self.prof._tl.current = self
@@ -213,7 +232,9 @@ class _Step:
         self._span.__exit__(exc_type, exc, tb)
         if exc is None:
             self.prof._record(self.phases, wall, synced=self.synced,
-                              batches=self.batches)
+                              batches=self.batches,
+                              clock=(self._wall_ns, self._t0),
+                              spans=self.spans)
         return None
 
 
@@ -244,29 +265,23 @@ class StepProfiler:
     def step(self, batches=1, **attrs):
         return _Step(self, batches=batches, **attrs)
 
-    def _note_phase(self, name, seconds):
+    def _note_phase(self, name, seconds, t0, attrs=None):
+        """Fold one ended phase (``t0`` its start on ``perf_counter``)
+        into the step record open on this thread, if any."""
         rec = getattr(self._tl, "current", None)
         if rec is not None:
             rec.phases[name] = rec.phases.get(name, 0.0) + seconds
+            rec.spans.append((name, t0 - rec._t0, seconds, attrs))
 
-    def note_device_sample(self, seconds, batches=1, flops_per_batch=None):
+    def note_device_sample(self, seconds, batches=1):
         """Feed one *sampled-sync* device measurement (a forced
-        ``block_until_ready`` bracket): marks the open step as synced,
-        feeds the overlap estimator's true-device-time mean, and — when
-        the executable's FLOPs are known — cross-checks the implied
-        device rate against the roofline (``step_device_flops_per_second``
-        gauge, same denominator as ``mfu``)."""
+        ``block_until_ready`` bracket): marks the open step as synced
+        and feeds the overlap estimator's true-device-time mean."""
         rec = getattr(self._tl, "current", None)
         if rec is not None:
             rec.synced = True
         with self._lock:
             self._device_samples.append(float(seconds) / max(1, batches))
-        if flops_per_batch and seconds > 0:
-            rate = float(flops_per_batch) * max(1, batches) / seconds
-            telemetry.gauge(
-                "step_device_flops_per_second",
-                help="model FLOP/s implied by sampled-sync device_compute "
-                     "brackets (cross-check against mfu)").set(rate)
 
     def record_step(self, phases, wall, synced=False, batches=1):
         """Directly feed one step record (synthetic workloads, tests)."""
@@ -282,11 +297,17 @@ class StepProfiler:
         self._record(dict(phases), float(wall), synced=synced,
                      batches=batches)
 
-    def _record(self, phases, wall, synced=False, batches=1):
+    def _record(self, phases, wall, synced=False, batches=1,
+                clock=None, spans=()):
+        """``clock`` is the step's (time_ns, perf_counter) pair at entry
+        and ``spans`` its phases as they ended; a record fed without
+        them (:meth:`record_step`) has no place on the timeline."""
         other = max(0.0, wall - sum(phases.values()))
         rec = {"wall": wall, "phases": phases, "other": other,
-               "synced": bool(synced), "batches": max(1, int(batches))}
+               "synced": bool(synced), "batches": max(1, int(batches)),
+               "clock": clock, "spans": spans}
         with self._lock:
+            rec["seq"] = self._steps
             self._window.append(rec)
             self._steps += 1
             self._wall_total += wall
@@ -429,6 +450,26 @@ class StepProfiler:
             "overlap_seconds": hidden / n if d_est_pb is not None else None,
             "hidden_fraction": (hidden / dev) if dev > 0 else None,
         }
+
+    def timeline(self):
+        """The window's step records as plain lists and dicts, oldest
+        first: ``seq``, ``clock`` ``[time_ns, perf_counter]`` read back
+        to back at step entry (None for a :meth:`record_step` record),
+        ``wall``, ``other``, ``batches``, ``synced``, ``phases`` (summed
+        seconds by name) and ``spans``, one ``[name, start offset from
+        entry in s, duration in s, attrs]`` per phase occurrence in the
+        order they ended. An instant ``t`` on ``perf_counter`` lies at
+        ``clock[0] + (t - clock[1]) * 1e9`` Unix nanoseconds."""
+        with self._lock:
+            recs = list(self._window)
+        return [{"seq": r["seq"],
+                 "clock": list(r["clock"]) if r["clock"] else None,
+                 "wall": r["wall"], "other": r["other"],
+                 "batches": r["batches"], "synced": r["synced"],
+                 "phases": dict(r["phases"]),
+                 "spans": [[name, start, dur, dict(attrs or {})]
+                           for name, start, dur, attrs in r["spans"]]}
+                for r in recs]
 
     def snapshot(self):
         """One JSON-able view: identity, step stats, totals, shares,
@@ -598,16 +639,19 @@ class ImplicitStepper:
     def __init__(self, prof=None):
         self._prof = prof or profiler
         self._last_end = None
-        self._pending = {}
+        self._pending = []
 
     def carry_phase(self, name, seconds):
         """Attribute work done OUTSIDE the bracket (e.g.
         ``place_batch`` staging before the step call) to the next
         bracketed step, so it reaches shares/verdict instead of being
-        lost to the residual ``other`` bucket."""
+        lost to the residual ``other`` bucket. Call it as the work ends:
+        the timeline takes the phase to have started ``seconds`` ago
+        (on a stepper's first step that lies before the step's entry)."""
         if name not in PHASES:
             raise ValueError("unknown phase %r" % (name,))
-        self._pending[name] = self._pending.get(name, 0.0) + float(seconds)
+        seconds = float(seconds)
+        self._pending.append((name, seconds, time.perf_counter() - seconds))
 
     def bracket(self, **attrs):
         from contextlib import contextmanager
@@ -629,6 +673,7 @@ class ImplicitStepper:
                 # spans / mean_step_seconds all agree
                 delta = st._t0 - self._last_end
                 st._t0 = self._last_end
+                st._wall_ns -= int(delta * 1e9)
                 st._span._t0 = self._last_end
                 st._span._wall -= delta
             self._flush_pending()
@@ -648,15 +693,13 @@ class ImplicitStepper:
         return _cm()
 
     def _flush_pending(self):
-        if self._pending:
-            for name, seconds in self._pending.items():
-                self._prof._note_phase(name, seconds)
-            self._pending.clear()
+        for name, seconds, t0 in self._pending:
+            self._prof._note_phase(name, seconds, t0)
+        del self._pending[:]
 
 
-def note_device_sample(seconds, batches=1, flops_per_batch=None):
-    profiler.note_device_sample(seconds, batches=batches,
-                                flops_per_batch=flops_per_batch)
+def note_device_sample(seconds, batches=1):
+    profiler.note_device_sample(seconds, batches=batches)
 
 
 def totals():
@@ -673,6 +716,12 @@ def overlap():
 
 def snapshot():
     return profiler.snapshot()
+
+
+def timeline():
+    """The process profiler's ring of step records with their clocks and
+    ordered phases (:meth:`StepProfiler.timeline`)."""
+    return profiler.timeline()
 
 
 def reset():
@@ -795,13 +844,18 @@ def merge_host_snapshots(dir=None):
 #: a host is named a straggler only when the skew is a real fraction of
 #: its step time — jitter on an unskewed run must not accuse anyone
 STRAGGLER_MIN_RATIO = 0.2
+#: ... and at least this many seconds a step: under it the ratio alone
+#: names whoever the scheduler delayed (2 ms steps on a shared host read
+#: 0.8 ms of skew, 29 %, with nothing wrong; ROADMAP D10)
+STRAGGLER_MIN_SKEW = 0.005
 
 
 def detect_stragglers(dir=None):
     """Merge per-host snapshots and publish ``step_skew_seconds`` (max
     minus min mean step time across hosts) and ``straggler_host`` (the
-    slow host's id, or -1 when no host stands out / fewer than two
-    hosts report). Returns the merged view:
+    slow host's id, or -1 when no host stands out — by
+    :data:`STRAGGLER_MIN_RATIO` and :data:`STRAGGLER_MIN_SKEW` both — or
+    fewer than two hosts report). Returns the merged view:
     ``{"skew_seconds", "straggler_host", "hosts": {...}}``."""
     hosts = {h: d for h, d in merge_host_snapshots(dir).items()
              if d.get("steps", 0) > 0}
@@ -812,14 +866,16 @@ def detect_stragglers(dir=None):
         slow = max(means, key=lambda h: means[h])
         fast = min(means, key=lambda h: means[h])
         skew = means[slow] - means[fast]
-        if means[slow] > 0 and skew / means[slow] >= STRAGGLER_MIN_RATIO:
+        if skew >= STRAGGLER_MIN_SKEW and \
+                skew / means[slow] >= STRAGGLER_MIN_RATIO:
             straggler = slow
     telemetry.gauge("step_skew_seconds",
                     help="max-min mean step wall time across hosts "
                          "(0 until two hosts report)").set(skew)
     telemetry.gauge("straggler_host",
                     help="host id whose steps are slowest by more than "
-                         "%d%% (-1: none)" % (STRAGGLER_MIN_RATIO * 100)
+                         "%d%% and %g s (-1: none)"
+                         % (STRAGGLER_MIN_RATIO * 100, STRAGGLER_MIN_SKEW)
                     ).set(straggler)
     return {"skew_seconds": skew, "straggler_host": straggler,
             "hosts": hosts}

@@ -124,13 +124,11 @@ def test_note_device_sample_marks_step_and_gauges(fresh):
     with stepprof.step():
         with stepprof.phase("device_compute", synced=True):
             pass
-        stepprof.note_device_sample(0.05, batches=5,
-                                    flops_per_batch=1e9)
+        stepprof.note_device_sample(0.05, batches=5)
     ov = stepprof.overlap()
     # 0.05 s over 5 batches -> 0.01 s/batch entered the estimator
     assert ov["device_busy_est"] == pytest.approx(0.01)
-    g = telemetry.get_metric("step_device_flops_per_second")
-    assert g is not None and g.value == pytest.approx(1e9 * 5 / 0.05)
+    assert stepprof.timeline()[-1]["synced"] is True
 
 
 # ---------------------------------------------------------------------------
@@ -481,6 +479,175 @@ def test_phase_spans_round_trip_chrome_trace(fresh, tmp_path):
     evs = [e for e in json.load(open(out))["traceEvents"]
            if e["name"].startswith("step.")]
     assert all(e["ph"] == "X" and e["dur"] >= 0 for e in evs)
+
+
+# ---------------------------------------------------------------------------
+# Timeline: step records with their clocks and ordered phases
+# ---------------------------------------------------------------------------
+
+def _check_spans_tile(rec):
+    """Spans lie inside the step, in order, without overlap, and with
+    ``other`` sum to ``wall``."""
+    edge = 0.0
+    for name, start, dur, attrs in rec["spans"]:
+        assert name in stepprof.PHASES and isinstance(attrs, dict)
+        assert start >= edge - 1e-9 and dur >= 0.0, rec["spans"]
+        edge = start + dur
+    assert edge <= rec["wall"] + 1e-9
+    assert sum(d for _, _, d, _ in rec["spans"]) + rec["other"] == \
+        pytest.approx(rec["wall"], abs=1e-9)
+    summed = {}
+    for name, _, dur, _ in rec["spans"]:
+        summed[name] = summed.get(name, 0.0) + dur
+    assert summed == pytest.approx(rec["phases"])
+
+
+def test_timeline_spans_in_order_and_tile_the_step(fresh):
+    with stepprof.step(batches=2):
+        with stepprof.phase("h2d") as ph:
+            time.sleep(0.002)
+            ph["bytes"] = 77
+        with stepprof.phase("dispatch", site="x"):
+            time.sleep(0.001)
+        time.sleep(0.001)   # nobody's: lands in `other`
+        for _ in range(2):  # one entry per occurrence, not a sum
+            with stepprof.phase("device_compute", via="update_metric"):
+                time.sleep(0.001)
+    (rec,) = stepprof.timeline()
+    assert [sp[0] for sp in rec["spans"]] == \
+        ["h2d", "dispatch", "device_compute", "device_compute"]
+    assert rec["spans"][0][3] == {"bytes": 77}
+    assert rec["spans"][1][3] == {"site": "x"}
+    assert rec["spans"][2][3] == {"via": "update_metric"}
+    assert rec["batches"] == 2 and rec["other"] >= 0.001
+    assert rec["phases"]["device_compute"] >= 0.002
+    _check_spans_tile(rec)
+    json.dumps(stepprof.timeline())   # plain lists and dicts
+
+
+def test_timeline_seq_rises_by_one_and_ring_stays_bounded(fresh,
+                                                          monkeypatch):
+    monkeypatch.setenv("MXNET_STEPPROF_WINDOW", "16")
+    prof = stepprof.StepProfiler()
+    for _ in range(40):
+        with prof.step():
+            with prof.phase("dispatch"):
+                pass
+    recs = prof.timeline()
+    assert len(recs) == 16
+    assert [r["seq"] for r in recs] == list(range(24, 40))
+    entries = [r["clock"][1] for r in recs]
+    assert entries == sorted(entries)
+    # a failed step leaves no record and takes no number
+    with pytest.raises(RuntimeError):
+        with prof.step():
+            raise RuntimeError("boom")
+    with prof.step():
+        pass
+    assert prof.timeline()[-1]["seq"] == 40
+
+
+def test_timeline_clock_pair_places_a_phase_on_the_wall_clock(fresh):
+    with stepprof.step():
+        time.sleep(0.003)
+        beside = time.time_ns()
+        with stepprof.phase("dispatch"):
+            time.sleep(0.001)
+    (rec,) = stepprof.timeline()
+    wall_ns, perf = rec["clock"]
+    assert isinstance(wall_ns, int) and isinstance(perf, float)
+    (_, start, _, _), = rec["spans"]
+    assert start >= 0.003
+    assert abs(wall_ns + start * 1e9 - beside) < 1e6   # within 1 ms
+
+
+def test_timeline_of_fed_records_has_no_clock(fresh):
+    prof = stepprof.StepProfiler(window=8)
+    prof.record_step({"dispatch": 0.01}, wall=0.02)
+    (rec,) = prof.timeline()
+    assert rec["clock"] is None and rec["spans"] == []
+    assert rec["seq"] == 0 and rec["phases"] == {"dispatch": 0.01}
+
+
+def test_timeline_implicit_stepper_stretch_moves_both_clocks(fresh):
+    stepper = stepprof.ImplicitStepper()
+    with stepper.bracket():
+        pass
+    time.sleep(0.004)            # the user's forward/backward
+    with stepprof.phase("h2d") as ph:
+        time.sleep(0.002)        # staging before the step call
+    stepper.carry_phase("h2d", ph.seconds)
+    beside = time.time_ns()
+    with stepper.bracket():
+        pass
+    first, second = stepprof.timeline()
+    # the second step reaches back to where the first ended (its record
+    # was booked in between), on both clocks of its pair
+    seam = second["clock"][1] - (first["clock"][1] + first["wall"])
+    assert 0.0 <= seam < 3e-3
+    assert second["wall"] >= 0.006
+    (name, start, dur, _), = second["spans"]
+    assert name == "h2d" and dur == pytest.approx(ph.seconds)
+    assert 0.004 <= start and start + dur <= second["wall"]
+    assert abs(second["clock"][0] + (start + dur) * 1e9 - beside) < 1e6
+
+
+@pytest.mark.parametrize("per_dispatch", [1, 4])
+def test_fit_timeline_tiles_the_loop_and_h2d_carries_bytes(fresh,
+                                                           per_dispatch):
+    from mxnet_tpu import xla_stats
+    data = mx.sym.var("data")
+    fc = mx.sym.FullyConnected(data, num_hidden=4, name="fc")
+    net = mx.sym.SoftmaxOutput(fc, name="softmax")
+    x = np.random.RandomState(0).uniform(size=(256, 10)).astype(np.float32)
+    it = mx.io.NDArrayIter(x, np.zeros(256, np.float32), batch_size=16)
+    mod = mx.mod.Module(net, context=mx.cpu())
+    mod.fit(it, num_epoch=1, eval_metric="acc",
+            batches_per_dispatch=per_dispatch,
+            batch_end_callback=lambda param: time.sleep(0.003))
+    before = xla_stats.compile_counts()
+    recs = stepprof.timeline()
+    assert xla_stats.compile_counts() == before   # reading compiles nothing
+    assert len(recs) == 16 // per_dispatch
+    assert [r["seq"] for r in recs] == list(range(len(recs)))
+    batch_bytes = 16 * 10 * 4 + 16 * 4     # float32 data and labels
+    for rec in recs:
+        _check_spans_tile(rec)
+        assert rec["batches"] == per_dispatch
+        names = [sp[0] for sp in rec["spans"]]
+        assert names.count("h2d") == 1 and names.count("dispatch") == 1
+        assert names.index("h2d") < names.index("dispatch")
+        # one read-back wait a batch, each after the dispatch
+        assert names.count("device_compute") == per_dispatch
+        assert names.index("dispatch") < names.index("device_compute")
+        (h2d,) = [sp for sp in rec["spans"] if sp[0] == "h2d"]
+        assert h2d[3]["bytes"] == batch_bytes * per_dispatch
+        # the callbacks run inside the step: their time is its `other`
+        assert rec["other"] >= 0.003 * per_dispatch
+    # the loop tiles: a step begins where the one before it ended
+    seams = [b["clock"][1] - (a["clock"][1] + a["wall"])
+             for a, b in zip(recs, recs[1:])]
+    assert all(seam >= 0.0 for seam in seams)
+    assert sorted(seams)[len(seams) // 2] < 1e-3, seams
+    covered = sum(r["wall"] for r in recs) + sum(seams)
+    assert covered == pytest.approx(
+        recs[-1]["clock"][1] + recs[-1]["wall"] - recs[0]["clock"][1])
+    assert sum(seams) < 0.1 * covered
+
+
+def test_straggler_needs_an_absolute_skew_too(fresh, tmp_path):
+    # 29 % apart, but 0.8 ms: what six test workers on one host do to a
+    # 2 ms step (ROADMAP D10); the ratio alone would accuse host 1
+    _host_snapshot(tmp_path, 0, 0.0020)
+    _host_snapshot(tmp_path, 1, 0.0028)
+    res = stepprof.detect_stragglers(str(tmp_path))
+    assert res["skew_seconds"] == pytest.approx(0.0008, rel=0.01)
+    assert res["straggler_host"] == -1
+    assert telemetry.get_metric("straggler_host").value == -1
+    # the same ratio with a skew a scheduler does not explain
+    _host_snapshot(tmp_path, 1, 0.0280)
+    _host_snapshot(tmp_path, 0, 0.0200)
+    assert stepprof.detect_stragglers(str(tmp_path))["straggler_host"] == 1
 
 
 # ---------------------------------------------------------------------------
