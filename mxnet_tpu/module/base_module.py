@@ -191,10 +191,19 @@ class BaseModule:
         """Reference base_module.py:395 training loop.
 
         TPU extension: ``batches_per_dispatch=K`` groups K batches into ONE
-        device dispatch (`Module._step_scan`: the batches are staged to the
-        device and a lax.scan carries params/optimizer state through the K
-        fused train steps). Metrics and batch callbacks still fire per
-        batch, from the scan's stacked per-step outputs.
+        device dispatch (`Module._step_scan`: a lax.scan carries
+        params/optimizer state through the K fused train steps). Metrics
+        and batch callbacks still fire per batch, from the scan's stacked
+        per-step outputs.
+
+        Input placement runs one dispatch ahead, always: while step n runs
+        `fit` fetches batch n+1 (the next group of K) and hands it to
+        :meth:`prepare`, which `Module` answers by putting it on the
+        device; the step then binds what it finds there (the group's
+        members are stacked on the device, never on the host). No step
+        runs ahead: when batch n's callbacks run, n+1 steps have been
+        dispatched. The iterator runs one batch, with K one group and one
+        batch, ahead of the callbacks.
 
         SPMD extension: ``spmd=`` selects a `parallel.spmd` sharding
         policy (``"data_parallel"`` / ``"fsdp"`` / ``"tensor"``, a
@@ -316,71 +325,62 @@ class BaseModule:
             # phases here: data_wait (iterator blocked) and
             # device_compute via the metric readback. That one is a
             # host wait: reading outputs to host is where the loop
-            # waits for whatever of the step is still in flight, the
-            # staging of its batch included (the device's own time is
-            # the trace's, not this phase's)
-            while not end_of_batch:
-                if use_scan:
-                    # gather up to K batches, run them in one dispatch
-                    group = [next_data_batch]
-                    with stepprof.step() as _sp:
-                        with stepprof.phase("data_wait",
-                                            gather="scan"):
-                            while len(group) < batches_per_dispatch:
-                                try:
-                                    nb = next(data_iter)
-                                    self.prepare(
-                                        nb,
-                                        sparse_row_id_fn=sparse_row_id_fn)
-                                except StopIteration:
-                                    end_of_batch = True
-                                    break
-                                if nb.data[0].shape != \
-                                        group[0].data[0].shape:
-                                    next_data_batch = nb  # bucket edge
-                                    break
-                                group.append(nb)
+            # waits for whatever of the step is still in flight (the
+            # device's own time is the trace's, not this phase's).
+            #
+            # Input placement runs one dispatch ahead of the step that
+            # consumes it, and only placement does: `prepare` puts batch
+            # n+1 (scan path: the next group of K) on the device while
+            # step n runs, between the dispatch and the read-back that
+            # waits for it, in an h2d phase of its own beside data_wait.
+            # Step n+1 is dispatched after batch n's callbacks, as ever.
+            # The first batch of an epoch is staged by its step (the
+            # first group's members as the iterator hands them over)
+            group = None
+            while use_scan and not (end_of_batch and group is None):
+                with stepprof.step() as _sp:
+                    if group is None:   # the epoch's first group
+                        group, next_data_batch, end_of_batch = \
+                            self._gather_group(
+                                data_iter, next_data_batch,
+                                batches_per_dispatch, sparse_row_id_fn)
+                    _sp["batches"] = len(group)
+                    # one dispatch for the group; a group of one and a
+                    # module without a scan plan step batch by batch
+                    stacked = self._step_scan(group) \
+                        if len(group) > 1 else False
+                    next_group = None
+                    if not end_of_batch:
+                        next_group, next_data_batch, end_of_batch = \
+                            self._gather_group(
+                                data_iter, next_data_batch,
+                                batches_per_dispatch, sparse_row_id_fn)
+                    for k_i, b in enumerate(group):
+                        if stacked is False:  # per-batch fallback
+                            self._step(b)
+                        with stepprof.phase("device_compute",
+                                            via="update_metric"):
+                            if stacked:
+                                outs = {name: out[k_i]
+                                        for name, out in
+                                        zip(self.output_names, stacked)}
+                                eval_metric.update_dict(
+                                    dict(zip(self._label_names,
+                                             b.label or [])),
+                                    outs)
                             else:
-                                try:
-                                    next_data_batch = next(data_iter)
-                                    self.prepare(
-                                        next_data_batch,
-                                        sparse_row_id_fn=sparse_row_id_fn)
-                                except StopIteration:
-                                    end_of_batch = True
-                        _sp["batches"] = len(group)
-                        if len(group) > 1:
-                            stacked = self._step_scan(group)
-                        else:
-                            stacked = False
-                        for k_i, b in enumerate(group):
-                            if stacked is False:  # per-batch fallback
-                                self._step(b)
-                            with stepprof.phase("device_compute",
-                                                via="update_metric"):
-                                if stacked:
-                                    outs = {name: out[k_i]
-                                            for name, out in
-                                            zip(self.output_names,
-                                                stacked)}
-                                    eval_metric.update_dict(
-                                        dict(zip(self._label_names,
-                                                 b.label or [])),
-                                        outs)
-                                else:
-                                    self.update_metric(eval_metric,
-                                                       b.label)
-                            _count_fit_batch(b, eval_metric)
-                            if batch_end_callback is not None:
-                                batch_end_params = BatchEndParam(
-                                    epoch=epoch, nbatch=nbatch,
-                                    eval_metric=eval_metric,
-                                    locals=locals())
-                                for callback in \
-                                        _as_list(batch_end_callback):
-                                    callback(batch_end_params)
-                            nbatch += 1
-                    continue
+                                self.update_metric(eval_metric, b.label)
+                        _count_fit_batch(b, eval_metric)
+                        if batch_end_callback is not None:
+                            batch_end_params = BatchEndParam(
+                                epoch=epoch, nbatch=nbatch,
+                                eval_metric=eval_metric,
+                                locals=locals())
+                            for callback in _as_list(batch_end_callback):
+                                callback(batch_end_params)
+                        nbatch += 1
+                    group = next_group
+            while not end_of_batch:
                 data_batch = next_data_batch
                 with stepprof.step() as _sp:
                     if monitor is not None:
@@ -392,11 +392,12 @@ class BaseModule:
                     with stepprof.phase("data_wait") as _dspan:
                         try:
                             next_data_batch = next(data_iter)
-                            self.prepare(next_data_batch,
-                                         sparse_row_id_fn=sparse_row_id_fn)
                         except StopIteration:
                             end_of_batch = True
                             _dspan["end_of_epoch"] = True
+                    if not end_of_batch:
+                        self.prepare(next_data_batch,
+                                     sparse_row_id_fn=sparse_row_id_fn)
                     with stepprof.phase("device_compute",
                                         via="update_metric"):
                         self.update_metric(eval_metric, data_batch.label)
@@ -432,6 +433,28 @@ class BaseModule:
                     self.logger.info("Epoch[%d] Validation-%s=%f", epoch, name, val)
 
             train_data.reset()
+
+    def _gather_group(self, data_iter, first, size, sparse_row_id_fn):
+        """The next group of the scan path: up to ``size`` batches of one
+        shape, from ``first`` (fetched already) on. Returns ``(group, the
+        batch fetched beyond it or None, whether the iterator has
+        ended)``: a shape change (bucket edge) ends the group early, and
+        so does the iterator. Each member is handed to :meth:`prepare` as
+        it comes, so it travels to the device while the iterator fetches
+        the next and, from the second group on, while the dispatch before
+        runs; ``data_wait`` covers the iterator alone."""
+        group, nb = [], first
+        while True:
+            group.append(nb)
+            self.prepare(nb, sparse_row_id_fn=sparse_row_id_fn)
+            with stepprof.phase("data_wait", gather="scan"):
+                try:
+                    nb = next(data_iter)
+                except StopIteration:
+                    return group, None, True
+            if len(group) == size or \
+                    nb.data[0].shape != group[0].data[0].shape:
+                return group, nb, False
 
     # -- symbol/params ---------------------------------------------------
     @property

@@ -16,9 +16,11 @@ from __future__ import annotations
 import logging
 import warnings
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-from ..base import MXNetError
+from ..base import MXNetError, device_of
 from ..context import Context, cpu
 from ..executor import Executor
 from ..initializer import Uniform, InitDesc
@@ -589,32 +591,107 @@ class Module(BaseModule):
         # the step_h2d_seconds histogram (and .prom-derived verdicts)
         if stepprof.in_step():
             with stepprof.phase("h2d") as ph:
-                ph["bytes"] = self._load_batch_impl(data_batch)
+                ph["bytes"], ph["staged_ahead"] = \
+                    self._load_batch_impl(data_batch)
         else:
             self._load_batch_impl(data_batch)
 
     def _load_batch_impl(self, data_batch):
-        """Stage the batch into the executor's inputs; returns the bytes
-        staged (the ``bytes`` of the ``h2d`` phase)."""
+        """Bind the batch to the executor's inputs: what :meth:`prepare`
+        put on the device ahead is bound as it is (and leaves the batch:
+        one use), the rest is placed now. Returns the bytes bound in all
+        and those of them found ready, the ``bytes`` and ``staged_ahead``
+        of the ``h2d`` phase."""
         data = data_batch.data
-        staged = 0
-        for name, arr in zip(self._data_names, data):
+        if any(self._exec.arg_dict[n].shape != a.shape
+               for n, a in zip(self._data_names, data)):
+            # dynamic batch (bucketing/last small batch): rebind via reshape
+            self.reshape([(n, a.shape) for n, a in zip(self._data_names, data)],
+                         [(n, a.shape) for n, a in
+                          zip(self._label_names, data_batch.label or [])] or None)
+        ahead = _take_staged(data_batch)
+        bound = found = 0
+        for name, arr in self._batch_inputs(data_batch):
             dst = self._exec.arg_dict[name]
-            if dst.shape != arr.shape:
-                # dynamic batch (bucketing/last small batch): rebind via reshape
-                self.reshape([(n, a.shape) for n, a in zip(self._data_names, data)],
-                             [(n, a.shape) for n, a in
-                              zip(self._label_names, data_batch.label or [])] or None)
-                dst = self._exec.arg_dict[name]
-            dst[:] = arr
-            staged += dst._data.nbytes
-        if data_batch.label is not None:
-            for name, arr in zip(self._label_names, data_batch.label):
-                dst = self._exec.arg_dict.get(name)
-                if dst is not None:
-                    dst[:] = arr
-                    staged += dst._data.nbytes
-        return staged
+            ready = _staged_for(ahead, name, arr)
+            if ready is None:
+                dst[:] = arr
+            else:
+                dst._data = ready
+                found += ready.nbytes
+            bound += dst._data.nbytes
+        return bound, found
+
+    def _batch_inputs(self, data_batch):
+        """[(name, the batch's array)] for every data and label name the
+        executor has a slot for."""
+        pairs = list(zip(self._data_names, data_batch.data))
+        for name, arr in zip(self._label_names, data_batch.label or []):
+            if name in self._exec.arg_dict:
+                pairs.append((name, arr))
+        return pairs
+
+    def _input_placement(self, name):
+        """Where the executor takes input ``name``: its ``NamedSharding``
+        under ``context=[several]`` / ``spmd=``, else the bound device."""
+        shardings = self._exec._shardings
+        if shardings is not None and name in shardings:
+            return shardings[name]
+        return device_of(self._exec.arg_dict[name]._data)
+
+    def _place_input(self, name, arr):
+        """``arr`` as the executor's slot ``name`` takes it: the slot's
+        dtype, on its device or sharding, by one asynchronous transfer (an
+        array that is there already comes back as it is)."""
+        dtype = self._exec.arg_dict[name].dtype
+        if isinstance(arr, NDArray):
+            val = arr._data if arr.dtype == dtype else arr._data.astype(dtype)
+        else:
+            val = np.asarray(arr, dtype=dtype)
+        return jax.device_put(val, self._input_placement(name))
+
+    def prepare(self, data_batch, sparse_row_id_fn=None):
+        """Put ``data_batch`` on the device one dispatch ahead of the step
+        that consumes it (reference module.py ``prepare``; `fit` calls it
+        on batch n+1 while step n runs). For every bound data and label
+        name it issues the transfer :meth:`_load_batch_impl` /
+        :meth:`stack_batches` would issue later and returns at once; the
+        device arrays travel with THIS use of the batch (``_staged``, which
+        the consumer takes off it), each beside the array it was made
+        from, so a batch object handed out again is transferred again.
+        Left to the consumer as before: arrays that live on the bound
+        placement already, sparse arrays, and a batch whose shape is not
+        the bound one (bucket edge, short last batch). Nothing in the
+        executor is touched."""
+        assert self.binded
+        if stepprof.in_step():
+            with stepprof.phase("h2d", via="prepare") as ph:
+                ph["bytes"] = self._stage_ahead(data_batch)
+        else:
+            self._stage_ahead(data_batch)
+
+    def _stage_ahead(self, data_batch):
+        arg_dict = self._exec.arg_dict
+        _take_staged(data_batch)   # an earlier call's, never consumed
+        inputs = self._batch_inputs(data_batch)
+        if any(arr.shape != arg_dict[name].shape for name, arr in inputs):
+            return 0
+        staged, nbytes = {}, 0
+        for name, arr in inputs:
+            if isinstance(arr, NDArray):
+                if arr.stype != "default" or \
+                        device_of(arr._data) == self._input_placement(name):
+                    continue
+            elif not isinstance(arr, np.ndarray):
+                continue
+            staged[name] = (arr, self._place_input(name, arr))
+            nbytes += staged[name][1].nbytes
+        if staged:
+            try:
+                data_batch._staged = staged
+            except AttributeError:   # a batch type that takes no attribute
+                return 0
+        return nbytes
 
     def forward_backward(self, data_batch):
         """Fused fwd+bwd: one compiled XLA dispatch (see executor)."""
@@ -822,9 +899,11 @@ class Module(BaseModule):
     # -- scanned multi-batch step ---------------------------------------
     def _step_scan(self, data_batches):
         """Run ``len(data_batches)`` fused train steps in ONE device
-        dispatch: the batches are stacked and staged to the device up
-        front, and a ``lax.scan`` carries (params, optimizer states, aux,
-        RNG key) through the K steps.
+        dispatch: each batch goes to the device on its own (`fit` has
+        :meth:`prepare` put it there while the dispatch before runs), the K
+        are stacked there (:meth:`stack_batches`), and a ``lax.scan``
+        carries (params, optimizer states, aux, RNG key) through the K
+        steps.
 
         TPU-native throughput feature with no reference analog: the
         reference pays one engine push per op per batch
@@ -839,11 +918,8 @@ class Module(BaseModule):
         rebound (use plain `_step` when per-batch gradients are needed).
 
         ``data_batches`` may also be a prestacked dict from
-        :meth:`stack_batches` — the staging (stack + device placement) then
-        happened ahead of time, off the step's critical path (a data
-        pipeline can stage superbatch N+1 while N trains; over a
-        high-latency PJRT link the staging round-trips otherwise serialize
-        with the dispatch).
+        :meth:`stack_batches` — placement and stack then happened ahead
+        of time, and the dict can be fed again and again.
         """
         if isinstance(data_batches, dict):
             K = next(iter(data_batches.values())).shape[0]
@@ -978,7 +1054,8 @@ class Module(BaseModule):
             placed = data_batches  # prestacked: staging already paid
         else:
             with stepprof.phase("h2d", via="stack_batches") as ph:
-                placed = self.stack_batches(data_batches)
+                placed, ph["staged_ahead"] = \
+                    self._stack_batches(data_batches)
                 ph["bytes"] = sum(v.nbytes for v in placed.values())
 
         # as in _step: the phase ends at the return of the compiled
@@ -1028,49 +1105,40 @@ class Module(BaseModule):
         """Stage K DataBatches as ONE stacked (K, batch, ...) device array
         per input, placed/sharded for :meth:`_step_scan`.
 
-        Device-resident batches stack on-device (no host round trip); host
-        batches stack in numpy and move in one transfer. Calling this ahead
-        of the step keeps input staging off the dispatch critical path."""
-        import numpy as _np
-        import jax
-        import jax.numpy as jnp
-        exec_ = self._exec
+        Every member goes to the bound device (the executor's sharding
+        under ``context=[several]`` / ``spmd=``) on its own: the arrays
+        :meth:`prepare` put there ahead where the batch carries them, else
+        one asynchronous transfer each, host numpy and CPU-backend NDArray
+        alike; one small program on that device then stacks the K members.
+        Nothing is stacked on the host. `fit` prepares a group's members
+        while the dispatch before it runs; a caller that holds the stacked
+        dict can hand it to ``_step_scan`` again and again."""
+        return self._stack_batches(data_batches)[0]
 
-        def _stack(vals):
-            if any(isinstance(v, NDArray) for v in vals):
-                # stack on device: host members UPLOAD (async h2d)
-                # instead of device members syncing back through
-                # asnumpy — the old mixed path drained the dispatch
-                # pipeline once per device-resident batch
-                return jnp.stack([v._data if isinstance(v, NDArray)
-                                  else jnp.asarray(v) for v in vals])
-            return _np.stack([_np.asarray(v) for v in vals])
-
-        stacked = {}
-        for i, name in enumerate(self._data_names):
-            stacked[name] = _stack([b.data[i] for b in data_batches])
-        for i, name in enumerate(self._label_names):
-            if name not in exec_.arg_dict:
-                continue
-            stacked[name] = _stack([b.label[i] for b in data_batches])
-        placed = {}
-        for name, arr in stacked.items():
-            dst = exec_.arg_dict[name]
-            if arr.dtype != dst.dtype:
-                arr = arr.astype(dst.dtype)
-            if exec_._shardings is not None and name in exec_._shardings:
+    def _stack_batches(self, data_batches):
+        """:meth:`stack_batches`, with the bytes found staged ahead."""
+        aheads = [_take_staged(b) for b in data_batches]
+        columns = zip(*(self._batch_inputs(b) for b in data_batches))
+        shardings = self._exec._shardings
+        placed, found = {}, 0
+        for column in columns:
+            name = column[0][0]
+            members = []
+            for (_, arr), ahead in zip(column, aheads):
+                ready = _staged_for(ahead, name, arr)
+                if ready is None:
+                    ready = self._place_input(name, arr)
+                else:
+                    found += ready.nbytes
+                members.append(ready)
+            stacked = _stack_members(*members)
+            if shardings is not None and name in shardings:
                 from jax.sharding import NamedSharding, PartitionSpec as P
-                sh = exec_._shardings[name]
-                spec = P(*((None,) + tuple(sh.spec)))
-                placed[name] = jax.device_put(
-                    arr, NamedSharding(sh.mesh, spec))
-            else:
-                from ..base import device_of
-                dev = device_of(dst._data)
-                cur = None if isinstance(arr, _np.ndarray) else device_of(arr)
-                placed[name] = arr if cur == dev \
-                    else jax.device_put(arr, dev)
-        return placed
+                sh = shardings[name]
+                stacked = jax.device_put(stacked, NamedSharding(
+                    sh.mesh, P(*((None,) + tuple(sh.spec)))))
+            placed[name] = stacked
+        return placed, found
 
     def get_outputs(self, merge_multi_context=True):
         assert self.binded and self.params_initialized
@@ -1119,6 +1187,31 @@ class Module(BaseModule):
         else:
             with open(fname, "rb") as f:
                 self._updater.set_states(f.read())
+
+
+def _take_staged(data_batch):
+    """What :meth:`Module.prepare` left on ``data_batch``, taken off it:
+    {input name: (the array it was made from, the device array)}, or None.
+    A staged array serves one use of the batch."""
+    staged = getattr(data_batch, "_staged", None)
+    if staged is not None:
+        del data_batch._staged
+    return staged
+
+
+def _staged_for(staged, name, arr):
+    """The device array staged for input ``name``, if it was made from
+    ``arr`` itself (a batch whose arrays were swapped since is placed
+    anew); else None."""
+    src, ready = (staged or {}).get(name, (None, None))
+    return ready if src is arr else None
+
+
+@jax.jit
+def _stack_members(*members):
+    """K arrays of one placement as one (K, ...) array there, by one
+    program on that placement."""
+    return jnp.stack(members)
 
 
 def _norm_shapes(shapes):
