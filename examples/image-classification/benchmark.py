@@ -91,10 +91,6 @@ def main():
                         "measures sustained step throughput with input "
                         "staging off the critical path (a real pipeline "
                         "stages superbatch N+1 while N trains)")
-    p.add_argument("--pack", action="store_true",
-                   help="carry rank<=1 params (BN vectors, momenta) as "
-                        "one flat buffer per dtype inside the scan "
-                        "(Module.scan_pack_small)")
     p.add_argument("--profile", type=str, default=None, metavar="DIR",
                    help="capture an XPlane trace of the timed region into "
                         "DIR; analyze with python -m mxnet_tpu.xplane DIR")
@@ -119,7 +115,6 @@ def main():
                        args.dtype, ctx, args.lr, layout=args.layout)
     mod.scan_unroll = args.scan_unroll
     mod.scan_donate_params = args.donate
-    mod.scan_pack_small = args.pack
 
     rng = np.random.RandomState(0)
     K = args.batches_per_dispatch

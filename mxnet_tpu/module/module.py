@@ -54,70 +54,6 @@ def _create_kvstore(kvstore, num_device, arg_params):
     return (kv, update_on_kvstore)
 
 
-_PACK_ALIGN_BYTES = 4096  # one native (8x sublane, 128 lane) tile:
-#                           1024 f32 / 2048 bf16 elements; keeps every
-#                           unpack slice layout-aligned so it can fuse
-#                           into its consumer instead of costing a
-#                           relayout copy+fence
-
-
-def _pack_plan(d):
-    """Packing layout for the rank<=1 leaves of a name->array dict:
-    ([(dtype, [(name, shape, size, offset)], total)], small_names).
-
-    Offsets are native-tile aligned (BYTE-based — a fixed element count
-    would misalign 2-byte dtypes): with element-granular packing
-    (round 4) every unpacked slice started mid-tile, so XLA emitted a
-    small relayout copy + TensorCore fence per USE — the exact swarm
-    packing was meant to kill (measured +0.5% only). Tile-aligned slices
-    are layout-identical to a standalone array."""
-    small = sorted(n for n, v in d.items() if getattr(v, "ndim", 2) <= 1)
-    by_dt = {}
-    for n in small:
-        by_dt.setdefault(str(d[n].dtype), []).append(n)
-    plans = []
-    for dt in sorted(by_dt):
-        align = max(1, _PACK_ALIGN_BYTES // int(np.dtype(dt).itemsize))
-        metas, off = [], 0
-        for n in by_dt[dt]:
-            v = d[n]
-            sz = 1
-            for s in v.shape:
-                sz *= int(s)
-            metas.append((n, tuple(v.shape), sz, off))
-            off += -(-sz // align) * align
-        plans.append((dt, metas, off))
-    return plans, frozenset(small)
-
-
-def _pack_tree(d, plan):
-    """-> ([one flat buffer per dtype], {big leaves unchanged})."""
-    import jax.numpy as jnp
-    plans, small = plan
-    packed = []
-    for dt, metas, total in plans:
-        parts, pos = [], 0
-        for n, _, sz, off in metas:
-            if off > pos:  # alignment spacer (see _PACK_ALIGN)
-                parts.append(jnp.zeros((off - pos,), dtype=dt))
-            parts.append(jnp.ravel(d[n]))
-            pos = off + sz
-        if total > pos:
-            parts.append(jnp.zeros((total - pos,), dtype=dt))
-        packed.append(jnp.concatenate(parts))
-    rest = {n: v for n, v in d.items() if n not in small}
-    return packed, rest
-
-
-def _unpack_tree(packed, rest, plan):
-    plans, _ = plan
-    out = dict(rest)
-    for buf, (_, metas, _) in zip(packed, plans):
-        for n, shape, sz, off in metas:
-            out[n] = buf[off:off + sz].reshape(shape)
-    return out
-
-
 class Module(BaseModule):
     _fused = None  # fused optimizer applier, resolved at first update
 
@@ -937,10 +873,8 @@ class Module(BaseModule):
         # longer compile; set via Module.scan_unroll or
         # fit(..., scan_unroll=U). 1 = plain while loop.
         unroll = max(1, int(getattr(self, "scan_unroll", 1) or 1))
-        pack_small = bool(getattr(self, "scan_pack_small", False))
         plan_key = ("scan", K, unroll,
-                    bool(getattr(self, "scan_donate_params", False)),
-                    pack_small)
+                    bool(getattr(self, "scan_donate_params", False)))
         scan_fn = None if self._scan_plans is None \
             else self._scan_plans.get(plan_key)
         if self._fused_plan is False or self.inputs_need_grad:
@@ -952,77 +886,22 @@ class Module(BaseModule):
         if scan_fn is None:
             from jax import lax
 
-            def step_core(ga, aux, sv, k, consts, xs, lrs, wds, rescale):
-                """One train step of the scan body — THE single copy of
-                the per-step semantics, shared by the plain and the
-                packed carry forms."""
-                k, sub = jax.random.split(k)
-                outs, aux_up, new_ws, new_states, _ = step_raw(
-                    ga, {**consts, **xs}, aux, sub, lrs, wds, rescale, sv)
-                ga = dict(ga)
-                for n, w in zip(live_names, new_ws):
-                    ga[n] = w
-                return ga, {**aux, **aux_up}, list(new_states), k, outs
-
             def scan_step(grad_args, consts, stacked, aux_vals, key,
                           lrs, wds, rescale, state_vals):
                 def body(carry, xs):
                     ga, aux, sv, k = carry
-                    ga, aux, sv, k, outs = step_core(
-                        ga, aux, sv, k, consts, xs, lrs, wds, rescale)
-                    return (ga, aux, sv, k), tuple(outs)
+                    k, sub = jax.random.split(k)
+                    outs, aux_up, new_ws, new_states, _ = step_raw(
+                        ga, {**consts, **xs}, aux, sub, lrs, wds, rescale, sv)
+                    ga = dict(ga)
+                    for n, w in zip(live_names, new_ws):
+                        ga[n] = w
+                    return (ga, {**aux, **aux_up}, list(new_states), k), \
+                        tuple(outs)
                 (ga, aux, sv, _), outs = lax.scan(
                     body, (grad_args, aux_vals, state_vals, key), stacked,
                     unroll=unroll)
                 return ga, aux, sv, outs
-
-            def scan_step_packed(grad_args, consts, stacked, aux_vals, key,
-                                 lrs, wds, rescale, state_vals):
-                """Module.scan_pack_small: carry the hundreds of rank<=1
-                arrays (BN scales/biases/stats, their momenta) as ONE flat
-                buffer per dtype. Each small carried array otherwise costs
-                a VMEM staging copy + TensorCore fence per while iteration
-                (~1.4us each; ~1,300/step on ResNet-50 = ~4% of step
-                time); packed, the swarm collapses to a few big carries
-                and the per-use unpack slices fuse into consumers."""
-                sv_flat = {"%d.%d" % (i, j): a
-                           for i, t in enumerate(state_vals)
-                           for j, a in enumerate(t)}
-                sv_arity = [len(t) for t in state_vals]
-                plans = [_pack_plan(d) for d in
-                         (grad_args, aux_vals, sv_flat)]
-                packs = [_pack_tree(d, p) for d, p in
-                         zip((grad_args, aux_vals, sv_flat), plans)]
-
-                def restore_sv(svf):
-                    return [tuple(svf["%d.%d" % (i, j)]
-                                  for j in range(sv_arity[i]))
-                            for i in range(len(sv_arity))]
-
-                def body(carry, xs):
-                    (pga, rga), (paux, raux), (psv, rsv), k = carry
-                    ga = _unpack_tree(pga, rga, plans[0])
-                    aux = _unpack_tree(paux, raux, plans[1])
-                    sv = restore_sv(_unpack_tree(psv, rsv, plans[2]))
-                    ga, aux, sv, k, outs = step_core(
-                        ga, aux, sv, k, consts, xs, lrs, wds, rescale)
-                    svf = {"%d.%d" % (i, j): a
-                           for i, t in enumerate(sv)
-                           for j, a in enumerate(t)}
-                    return (_pack_tree(ga, plans[0]),
-                            _pack_tree(aux, plans[1]),
-                            _pack_tree(svf, plans[2]), k), tuple(outs)
-
-                (pga_c, paux_c, psv_c, _), outs = lax.scan(
-                    body, (packs[0], packs[1], packs[2], key), stacked,
-                    unroll=unroll)
-                ga = _unpack_tree(pga_c[0], pga_c[1], plans[0])
-                aux = _unpack_tree(paux_c[0], paux_c[1], plans[1])
-                sv = restore_sv(_unpack_tree(psv_c[0], psv_c[1], plans[2]))
-                return ga, aux, sv, outs
-
-            if pack_small:
-                scan_step = scan_step_packed
 
             # donate the optimizer states only — matching _step's policy
             # (params are NOT donated: user code may hold raw views of the

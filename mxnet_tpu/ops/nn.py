@@ -87,22 +87,17 @@ def _layout_spec(params, nd):
 def _s2d_eligible(params, data, weight, kernel, stride, dilate, groups,
                   caxis):
     """True when the stride-2 small-input-channel stem rewrite applies
-    (2-D conv, <=4 input channels, kernel <=8, no dilation/groups) and
-    the op is lowering for a TPU — on the MXU a 3-channel conv wastes 125 of
-    128 input lanes; the space-to-depth form packs 4x more.
+    (2-D NCHW conv, <=4 input channels, kernel <=8, no dilation/groups)
+    and the op is lowering for a TPU — on the MXU a 3-channel conv wastes
+    125 of 128 input lanes; the space-to-depth form packs 4x more.
 
-    NCHW: default ON (round-1 win). NHWC: gate MXNET_S2D_NHWC, default
-    OFF — measured 2,769 vs ~2,790 img/s on ResNet-50 bf16 bs128 train
-    (round 5): XLA's NHWC small-channel stem emitters are already decent
-    and the s2d relayout costs more than the lane packing recovers."""
-    if caxis == len(kernel) + 1 and not _env_on("MXNET_S2D_NHWC"):
-        return False
-    if caxis not in (1, len(kernel) + 1) or len(kernel) != 2 or groups != 1:
+    NHWC stems stay plain convolutions: the rewrite measured no faster
+    there (PERF.md section 6, PR 30)."""
+    if caxis != 1 or len(kernel) != 2 or groups != 1:
         return False
     if stride != (2, 2) or dilate != (1, 1):
         return False
-    cin = weight.shape[1] if caxis == 1 else weight.shape[-1]
-    if cin > 4 or max(kernel) > 8:
+    if weight.shape[1] > 4 or max(kernel) > 8:
         return False
     from .pallas_kernels import is_tpu
     if not is_tpu():
@@ -114,10 +109,15 @@ def _s2d_eligible(params, data, weight, kernel, stride, dilate, groups,
     return True
 
 
-def _s2d_geometry(H, W, kh, kw, ph, pw):
-    """Shared padding geometry for the space-to-depth conv rewrites:
-    -> (out_h, out_w, kh8, kw8, eh, ew). The exactness of the rewrite
-    rests on this arithmetic — ONE copy for both layouts."""
+def _space_to_depth_conv(data, weight, pad):
+    """EXACT rewrite of a stride-2 NCHW conv as a stride-1 conv over a
+    2x2 space-to-depth input (the MLPerf-TPU ResNet stem trick): the 7x7x3
+    kernel zero-pads to 8x8 and rearranges to 4x4x12, quadrupling MXU input
+    -lane occupancy. Same function, same gradients — jax.vjp differentiates
+    through the reshapes."""
+    N, C, H, W = data.shape
+    O, _, kh, kw = weight.shape
+    ph, pw = pad
     out_h = (H + 2 * ph - kh) // 2 + 1
     out_w = (W + 2 * pw - kw) // 2 + 1
     kh8, kw8 = 2 * ((kh + 1) // 2), 2 * ((kw + 1) // 2)
@@ -129,19 +129,6 @@ def _s2d_geometry(H, W, kh, kw, ph, pw):
     # beyond every tap the sliced output reads
     eh += (H + ph + eh) % 2
     ew += (W + pw + ew) % 2
-    return out_h, out_w, kh8, kw8, eh, ew
-
-
-def _space_to_depth_conv(data, weight, pad):
-    """EXACT rewrite of a stride-2 NCHW conv as a stride-1 conv over a
-    2x2 space-to-depth input (the MLPerf-TPU ResNet stem trick): the 7x7x3
-    kernel zero-pads to 8x8 and rearranges to 4x4x12, quadrupling MXU input
-    -lane occupancy. Same function, same gradients — jax.vjp differentiates
-    through the reshapes."""
-    N, C, H, W = data.shape
-    O, _, kh, kw = weight.shape
-    ph, pw = pad
-    out_h, out_w, kh8, kw8, eh, ew = _s2d_geometry(H, W, kh, kw, ph, pw)
     x = jnp.pad(data, ((0, 0), (0, 0), (ph, eh), (pw, ew)))
     Hp, Wp = x.shape[2], x.shape[3]
     # space-to-depth 2x2: channel order (c, a, b)
@@ -157,273 +144,6 @@ def _space_to_depth_conv(data, weight, pad):
     return out[:, :, :out_h, :out_w]
 
 
-def _space_to_depth_conv_nhwc(data, weight, pad):
-    """NHWC twin of `_space_to_depth_conv`: stride-2 conv as a stride-1
-    conv over a 2x2 space-to-depth input, packed channel order
-    (ph, pw, c) applied identically to input and kernel so the
-    contraction is the same sum, just reindexed."""
-    N, H, W, C = data.shape
-    O, kh, kw, _ = weight.shape
-    ph, pw = pad
-    out_h, out_w, kh8, kw8, eh, ew = _s2d_geometry(H, W, kh, kw, ph, pw)
-    x = jnp.pad(data, ((0, 0), (ph, eh), (pw, ew), (0, 0)))
-    Hp, Wp = x.shape[1], x.shape[2]
-    x2 = x.reshape(N, Hp // 2, 2, Wp // 2, 2, C)
-    x2 = x2.transpose(0, 1, 3, 2, 4, 5).reshape(N, Hp // 2, Wp // 2, 4 * C)
-    w8 = jnp.pad(weight, ((0, 0), (0, kh8 - kh), (0, kw8 - kw), (0, 0)))
-    w2 = w8.reshape(O, kh8 // 2, 2, kw8 // 2, 2, C)
-    w2 = w2.transpose(0, 1, 3, 2, 4, 5).reshape(O, kh8 // 2, kw8 // 2,
-                                                4 * C)
-    dn = lax.conv_dimension_numbers(x2.shape, w2.shape,
-                                    ("NHWC", "OHWI", "NHWC"))
-    out = lax.conv_general_dilated(x2, w2, (1, 1), [(0, 0), (0, 0)],
-                                   dimension_numbers=dn)
-    return out[:, :out_h, :out_w, :]
-
-
-def _conv1x1_dot_wanted(stride):
-    """MXNET_CONV1X1_DOT: default '0' — 1x1 convs stay convolutions.
-
-    Measured on ResNet-50 bf16 bs128 NHWC, rewriting 1x1 convs as dots
-    LOSES ~4% step time ('all') / ~3% ('strided'): XLA's conv emitters
-    win on BN/relu epilogue fusion, and even the lhs-dilated strided
-    dgrad beats the pad+matmul form once fusion is accounted for. The
-    modes stay env-gated for models where pointwise convs dominate
-    differently: 'strided' rewrites only stride>1 1x1 convs, 'all'/'1'
-    rewrites every 1x1."""
-    mode = os.environ.get("MXNET_CONV1X1_DOT", "0")
-    if mode == "0":
-        return False
-    if mode == "all" or mode == "1":
-        return True
-    return max(stride) > 1
-
-
-def _conv1x1_as_dot(data, weight, stride, caxis):
-    """1x1 conv as strided-slice + dot_general.
-
-    TPU-first rewrite: 36 of ResNet-50's 53 convs are 1x1; lowering them as
-    matmuls instead of conv_general_dilated means their autodiff transposes
-    are matmuls too — the input gradient of a STRIDED 1x1 conv becomes
-    pad(dy @ W^T) (a bandwidth op) instead of an lhs-dilated convolution
-    (which computes on a grid of injected zeros), and the weight gradient
-    becomes a plain f32-accumulated MXU matmul. The slice's transpose is an
-    interior pad; XLA derives both for free.
-    """
-    nd = data.ndim - 2
-    w2 = weight.reshape(weight.shape[0], -1)    # (O, C) for OI1..1 / O1..1I
-    if caxis == 1:
-        x = data[(slice(None), slice(None))
-                 + tuple(slice(None, None, s) for s in stride)]
-        out = lax.dot_general(x, w2, (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-        # (N, *spatial, O) -> (N, O, *spatial)
-        out = out.transpose((0, nd + 1) + tuple(range(1, nd + 1)))
-    else:
-        x = data[(slice(None),)
-                 + tuple(slice(None, None, s) for s in stride)]
-        out = lax.dot_general(x, w2, (((data.ndim - 1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)
-    return out.astype(data.dtype)
-
-
-@_functools.lru_cache(maxsize=None)
-def _conv1x1_strided_fn(stride, dspec, wspec, caxis, dshape):
-    """Strided 1x1 conv with a hand-written transpose (jax.custom_vjp).
-
-    Forward stays `lax.conv_general_dilated` — XLA's conv emitters fuse the
-    BN/relu epilogues better than a dot (measured, see _conv1x1_dot_wanted).
-    The AUTODIFF transpose of a strided conv, however, is an lhs-dilated
-    convolution that computes over a grid of interior zeros — on ResNet-50
-    bf16 those stage-entry dgrads run at 6-12 TF/s vs ~130 for forward
-    convs. Here dgrad = interior-pad(dy @ W^T) (one MXU matmul + a
-    bandwidth pad) and wgrad = dy^T @ x_strided (one f32-accumulated
-    matmul); the strided input slice is the only residual kept.
-
-    Default OFF (MXNET_CONV1X1_BWD=1 to enable): on ResNet-50 bf16 bs128
-    NHWC the matmul form measured ~3% SLOWER end-to-end — breaking the
-    conv up denies XLA the dgrad-conv + BN-backward-reduce output fusion,
-    and the materialized pad costs more than the dilated emitter saves.
-    Kept for architectures where strided pointwise convs dominate.
-
-    Cached per (stride, layout, input shape): jit retraces per shape
-    signature anyway, so the cache is bounded by the model's conv configs.
-    """
-    nd = len(stride)
-
-    def conv_fwd(data, weight):
-        dn = lax.conv_dimension_numbers(data.shape, weight.shape,
-                                        (dspec, wspec, dspec))
-        return lax.conv_general_dilated(
-            data, weight, window_strides=stride,
-            padding=[(0, 0)] * nd, dimension_numbers=dn)
-
-    f = jax.custom_vjp(conv_fwd)
-
-    def fwd_rule(data, weight):
-        if caxis == 1:
-            xs = data[(slice(None), slice(None))
-                      + tuple(slice(None, None, s) for s in stride)]
-        else:
-            xs = data[(slice(None),)
-                      + tuple(slice(None, None, s) for s in stride)]
-        return conv_fwd(data, weight), (xs, weight)
-
-    def bwd_rule(res, dy):
-        xs, weight = res
-        w2 = weight.reshape(weight.shape[0], -1)        # (O, C)
-        if caxis == 1:
-            sp = tuple(range(2, 2 + nd))
-            dz = lax.dot_general(dy, w2, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-            # (N, *sp_out, C) -> (N, C, *sp_out)
-            dz = dz.transpose((0, nd + 1) + tuple(range(1, nd + 1)))
-            dw = lax.dot_general(
-                dy, xs, (((0,) + sp, (0,) + sp), ((), ())),
-                preferred_element_type=jnp.float32)     # (O, C)
-            sp_off = 2
-        else:
-            sp = tuple(range(1, 1 + nd))
-            dz = lax.dot_general(dy, w2, (((nd + 1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-            dw = lax.dot_general(
-                dy, xs, (((0,) + sp, (0,) + sp), ((), ())),
-                preferred_element_type=jnp.float32)     # (O, C)
-            sp_off = 1
-        dz = dz.astype(xs.dtype)
-        pads = [(0, 0, 0)] * dz.ndim
-        for ax, s in enumerate(stride):
-            full = dshape[sp_off + ax]
-            cur = dz.shape[sp_off + ax]
-            pads[sp_off + ax] = (0, full - ((cur - 1) * s + 1), s - 1)
-        dx = lax.pad(dz, jnp.zeros((), dz.dtype), pads)
-        return dx, dw.reshape(weight.shape).astype(weight.dtype)
-
-    f.defvjp(fwd_rule, bwd_rule)
-    return f
-
-
-def _env_on(name, default="0"):
-    """Boolean env gate: '0'/''/'false'/'off'/'no' (any case) disable."""
-    return os.environ.get(name, default).lower() not in (
-        "0", "", "false", "off", "no")
-
-
-def _env_int(name, default=0):
-    try:
-        return int(os.environ.get(name, "") or default)
-    except ValueError:
-        return default
-
-
-def _plain_1x1(kernel, pad, dilate, groups):
-    """Pointwise conv: 1x1 kernel, no padding/dilation/groups."""
-    return (set(kernel) == {1} and set(pad) == {0} and set(dilate) == {1}
-            and groups == 1)
-
-
-def _pointwise_conv_fwd(dspec, wspec, stride):
-    """Forward lowering shared by every custom-VJP 1x1 path: the plain
-    conv_general_dilated (XLA's emitters win on fwd epilogue fusion)."""
-    nd = len(stride)
-
-    def conv_fwd(data, weight):
-        dn = lax.conv_dimension_numbers(data.shape, weight.shape,
-                                        (dspec, wspec, dspec))
-        return lax.conv_general_dilated(
-            data, weight, window_strides=stride,
-            padding=[(0, 0)] * nd, dimension_numbers=dn)
-    return conv_fwd
-
-
-@_functools.lru_cache(maxsize=None)
-def _conv1x1_pallas_fn(stride, dspec, wspec, dshape):
-    """NHWC stride-2 1x1 conv whose input gradient is the Pallas
-    matmul+interleave kernel (`conv_kernels.conv1x1_s2_dgrad`).
-
-    Forward stays `lax.conv_general_dilated` (healthy, ~130 TF/s).  The
-    default dgrad is XLA's lhs-dilated conv emitter at 6-12 TF/s on the
-    ResNet stage-entry shapes; the Pallas kernel does the compact matmul
-    and writes the zero-interleaved dx in one pass.  wgrad becomes one
-    f32-accumulated MXU matmul over the strided input slice (the only
-    residual kept).  Gate: MXNET_CONV1X1_PALLAS (see _convolution).
-    """
-    conv_fwd = _pointwise_conv_fwd(dspec, wspec, stride)
-    f = jax.custom_vjp(conv_fwd)
-
-    def fwd_rule(data, weight):
-        xs = data[:, ::stride[0], ::stride[1], :]
-        return conv_fwd(data, weight), (xs, weight)
-
-    def bwd_rule(res, dy):
-        from .conv_kernels import conv1x1_s2_dgrad
-        xs, weight = res
-        w2 = weight.reshape(weight.shape[0], -1)        # (O, C) for OHWI
-        dx = conv1x1_s2_dgrad(dy, w2, dshape[1], dshape[2])
-        dw = lax.dot_general(dy, xs, (((0, 1, 2), (0, 1, 2)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        return dx, dw.reshape(weight.shape).astype(weight.dtype)
-
-    f.defvjp(fwd_rule, bwd_rule)
-    return f
-
-
-def _conv1x1_pallas_wanted(kernel, stride, pad, dilate, groups, caxis, nd,
-                           dshape):
-    if not _env_on("MXNET_CONV1X1_PALLAS"):
-        return False
-    if (not _plain_1x1(kernel, pad, dilate, groups)
-            or nd != 2 or caxis != nd + 1):
-        return False
-    if stride != (2, 2):
-        return False
-    # kernel needs the exact 2x interleave view (H==2*Ho) and a
-    # lane-aligned channel count
-    return (dshape[1] % 2 == 0 and dshape[2] % 2 == 0
-            and dshape[3] % 128 == 0)
-
-
-@_functools.lru_cache(maxsize=None)
-def _conv1x1_s1_dot_bwd_fn(dspec, wspec):
-    """NHWC stride-1 1x1 conv with dot_general gradients (fwd unchanged).
-
-    XLA's conv TRANSPOSE emitter picks batch-in-sublanes layouts for the
-    56x56-stage 64-channel dgrads (10-23 TF/s measured); expressing the
-    same contraction as an explicit dot keeps it a plain MXU matmul.
-    Gate: MXNET_CONV1X1_S1DOT=<min-channel threshold> (see _convolution).
-    """
-    conv_fwd = _pointwise_conv_fwd(dspec, wspec, (1, 1))
-    f = jax.custom_vjp(conv_fwd)
-
-    def fwd_rule(data, weight):
-        return conv_fwd(data, weight), (data, weight)
-
-    def bwd_rule(res, dy):
-        x, weight = res
-        w2 = weight.reshape(weight.shape[0], -1)        # (O, C)
-        dx = lax.dot_general(dy, w2, (((3,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        dw = lax.dot_general(dy, x, (((0, 1, 2), (0, 1, 2)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        return dx.astype(x.dtype), dw.reshape(weight.shape).astype(weight.dtype)
-
-    f.defvjp(fwd_rule, bwd_rule)
-    return f
-
-
-def _conv1x1_s1_dot_wanted(kernel, stride, pad, dilate, groups, caxis, nd,
-                           weight):
-    thresh = _env_int("MXNET_CONV1X1_S1DOT")
-    if thresh <= 0:
-        return False
-    if (not _plain_1x1(kernel, pad, dilate, groups)
-            or nd != 2 or caxis != nd + 1):
-        return False
-    if stride != (1, 1):
-        return False
-    return min(weight.shape[0], weight.shape[-1]) <= thresh
-
-
 @register("Convolution")
 def _convolution(params, data, weight, *bias):
     kernel = tuple(params["kernel"])
@@ -435,22 +155,7 @@ def _convolution(params, data, weight, *bias):
     dspec, wspec, caxis = _layout_spec(params, nd)
     if _s2d_eligible(params, data, weight, kernel, stride, dilate, groups,
                      caxis):
-        out = (_space_to_depth_conv(data, weight, pad) if caxis == 1
-               else _space_to_depth_conv_nhwc(data, weight, pad))
-    elif (_plain_1x1(kernel, pad, dilate, groups)
-          and _conv1x1_dot_wanted(stride)):
-        out = _conv1x1_as_dot(data, weight, stride, caxis)
-    elif _conv1x1_pallas_wanted(kernel, stride, pad, dilate, groups, caxis,
-                                nd, data.shape):
-        out = _conv1x1_pallas_fn(stride, dspec, wspec,
-                                 data.shape)(data, weight)
-    elif _conv1x1_s1_dot_wanted(kernel, stride, pad, dilate, groups, caxis,
-                                nd, weight):
-        out = _conv1x1_s1_dot_bwd_fn(dspec, wspec)(data, weight)
-    elif (_plain_1x1(kernel, pad, dilate, groups) and max(stride) > 1
-          and _env_on("MXNET_CONV1X1_BWD")):
-        out = _conv1x1_strided_fn(stride, dspec, wspec, caxis,
-                                  data.shape)(data, weight)
+        out = _space_to_depth_conv(data, weight, pad)
     else:
         dn = lax.conv_dimension_numbers(data.shape, weight.shape,
                                         (dspec, wspec, dspec))
@@ -504,35 +209,6 @@ def _deconvolution(params, data, weight, *bias):
 # ---------------------------------------------------------------------------
 # Pooling (reference nn/pooling-inl.h)
 # ---------------------------------------------------------------------------
-def _pool_max_slices(data, window, strides, padding, init):
-    """Strided max pool as an elementwise max over k^nd strided slices.
-
-    MXNET_POOL_SLICES, default OFF — measured 15% SLOWER end-to-end
-    (8,425 vs 9,966 img/s ResNet-50 bs32 inference; both numbers from
-    the same bench-loop variant in the same session — the canonical
-    baseline loop measures 10,033): reduce_window's
-    379 GB/s looked like bandwidth headroom, but the 9-slice maximum
-    chain materializes intermediates XLA's window emitter never builds.
-    Kept as the measured-negative-result artifact (same pattern as
-    MXNET_CONV1X1_*; see docs/perf/resnet50_train_attribution.md for
-    the methodology). Exact same values; autodiff gives a maximum-chain
-    VJP instead of select-and-scatter (grads agree up to tie-routing,
-    like the reference's cuDNN vs CPU pooling backends).
-    """
-    import itertools
-    padspec = [(lo, hi, 0) for lo, hi in padding]
-    xp = lax.pad(data, jnp.asarray(init, data.dtype), padspec)
-    out_sz = [(xp.shape[a] - window[a]) // strides[a] + 1
-              for a in range(data.ndim)]
-    out = None
-    for offs in itertools.product(*[range(k) for k in window]):
-        sl = tuple(slice(o, o + strides[a] * (out_sz[a] - 1) + 1,
-                         strides[a]) for a, o in enumerate(offs))
-        piece = xp[sl]
-        out = piece if out is None else jnp.maximum(out, piece)
-    return out
-
-
 @register("Pooling", aliases=("Pooling_v1",))
 def _pooling(params, data):
     pool_type = params.get("pool_type", "max")
@@ -580,12 +256,8 @@ def _pooling(params, data):
         _, _, padding = _full(kernel, stride, extra)
     if pool_type == "max":
         init = -jnp.inf if jnp.issubdtype(data.dtype, jnp.floating) else jnp.iinfo(data.dtype).min
-        if (_env_on("MXNET_POOL_SLICES") and not global_pool
-                and max(stride) > 1 and int(np.prod(kernel)) <= 9):
-            out = _pool_max_slices(data, window, strides, padding, init)
-        else:
-            out = lax.reduce_window(data, init, lax.max, window, strides,
-                                    padding)
+        out = lax.reduce_window(data, init, lax.max, window, strides,
+                                padding)
         if params.get("_fold_relu"):
             # executor relu->maxpool fold: maxpool(relu(x)) ==
             # max(maxpool(x), 0); grads agree (see _plan_relu_pool_fold)
@@ -670,7 +342,6 @@ def _bn_stats(axis, eps, data):
     # accumulation: it loses ~log2(mean^2/var) bits, fine for
     # normalization-scale activations; set MXNET_BN_CENTERED_VAR=1 for
     # the exact two-pass form (pathological large-mean/low-var inputs).
-    data = _bn_barrier_if_big(data)
     x32 = data.astype(jnp.float32)
     n = 1.0
     for i in red_axes:
@@ -680,32 +351,6 @@ def _bn_stats(axis, eps, data):
     mean = s / n
     var = jnp.maximum(ss / n - mean * mean, 0.0)
     return mean, var, red_axes, bshape
-
-
-def _bn_barrier_elems():
-    try:
-        return int(os.environ.get("MXNET_BN_BARRIER_ELEMS", "0"))
-    except ValueError:
-        return 0
-
-
-def _bn_barrier_if_big(x):
-    """Size-conditioned fusion barrier for BN statistics.
-
-    Letting XLA fuse BN-stat reductions into the producing convolution's
-    epilogue is a net win for small activations (saves a full read), but
-    for the LARGE early-stage activations the combined "convolution
-    fusion" drops the conv to 6-12 TF/s (measured, xplane r50 bs128 —
-    vs ~130 TF/s clean). Measured END-TO-END though, barriers lose:
-    all-barrier cost ~2 ms/step (removed with the single-pass stats) and
-    a 32M-element threshold still measured ~5% slower — the separate
-    reduce pass plus lost epilogue fusion outweighs the cleaner conv.
-    Default 0 (no barrier); MXNET_BN_BARRIER_ELEMS=N barriers tensors
-    above N elements for architectures where the tradeoff flips."""
-    lim = _bn_barrier_elems()
-    if lim and x.size > lim:
-        return lax.optimization_barrier(x)
-    return x
 
 
 def _bn_apply(data, g, beta, mean, var, eps, bshape):
@@ -745,14 +390,12 @@ def _bn_core_bwd(axis, eps, res, cts):
     inv_b = inv.reshape(bshape)
     xhat = (data.astype(jnp.float32) - mean_b) * inv_b  # recomputed, fused
     dy32 = dy.astype(jnp.float32)
-    # keep the dgamma/dbeta reductions out of the upstream dgrad-conv
-    # fusion for LARGE dy (same tradeoff as _bn_barrier_if_big forward)
-    sdy = _bn_barrier_if_big(dy)
-    sdy32 = sdy.astype(jnp.float32)
-    sxhat = xhat if sdy is dy else \
-        (_bn_barrier_if_big(data).astype(jnp.float32) - mean_b) * inv_b
+    # the two sums read dy through a convert of their own: with dx's
+    # shared, the TPU compiler emits another program (PERF.md section 6,
+    # PR 30), so merging them is a change to measure, not a tidy-up
+    sdy32 = dy.astype(jnp.float32)
     sum_dy = jnp.sum(sdy32, axis=red_axes)
-    sum_dy_xhat = jnp.sum(sdy32 * sxhat, axis=red_axes)
+    sum_dy_xhat = jnp.sum(sdy32 * xhat, axis=red_axes)
     coef = (g.astype(jnp.float32) * inv).reshape(bshape)
     dx = coef * (dy32 - sum_dy.reshape(bshape) / n
                  - xhat * (sum_dy_xhat.reshape(bshape) / n))

@@ -142,8 +142,8 @@ class _Captured(Exception):
 
 def test_resnet50_module_train_step_bf16_bs128(one_chip, on_tpu,
                                                monkeypatch, tmp_path):
-    """The README's train row: `Module` over ResNet-50 NHWC, bf16 params
-    and data, batch 128, the fused fwd+bwd+SGD-momentum `_step`. Built
+    """The benchmark's ResNet-50 program at `chip_smoke.py`'s batch:
+    `Module` over ResNet-50 NHWC, bf16 params and data, batch 128, the fused fwd+bwd+SGD-momentum `_step`. Built
     as chip_smoke.py's train phase builds it; the one dispatch is
     stopped at the CompiledProgram and compiled for the chip instead."""
     import chip_smoke

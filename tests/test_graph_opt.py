@@ -1,11 +1,12 @@
-"""Graph-optimization passes: conv-bias->BN elision and 1x1-conv-as-dot.
+"""Graph-optimization passes: conv-bias->BN elision, and the pointwise
+convolutions against numpy.
 
 The fold pass (executor._plan_conv_bias_bn_fold) removes the mathematically
 -zero-gradient bias of a conv feeding a BatchNorm (the Gluon zoo's
-BottleneckV1 pattern, reference gluon/model_zoo/vision/resnet.py:107,113);
-the 1x1 rewrite (ops/nn._conv1x1_as_dot) lowers pointwise convs to
-dot_general so their autodiff transposes are matmuls, not lhs-dilated
-convolutions. Both must be numerically invisible to users.
+BottleneckV1 pattern, reference gluon/model_zoo/vision/resnet.py:107,113)
+and must be numerically invisible to users. A 1x1 convolution is a matrix
+product over the channels: value and both gradients are held to that form
+written in numpy, strided or not, in both layouts.
 """
 import os
 
@@ -179,10 +180,35 @@ def test_relu_pool_fold_skips_shared_relu():
     assert_almost_equal(relu_sum, np.maximum(x, 0).sum(), rtol=1e-5)
 
 
+def _kept_pixels(x, stride, layout):
+    return x[:, :, ::stride[0], ::stride[1]] if layout == "NCHW" \
+        else x[:, ::stride[0], ::stride[1], :]
+
+
+def _conv1x1_numpy(x, w, stride, layout):
+    """1x1 convolution written from its definition: a matrix product over
+    the channels at every kept pixel."""
+    return np.einsum(
+        "nchw,oc->nohw" if layout == "NCHW" else "nhwc,oc->nhwo",
+        _kept_pixels(x, stride, layout), w.reshape(w.shape[0], -1))
+
+
+def _conv1x1_grads_numpy(dy, x, w, stride, layout):
+    """Its gradients in closed form: dx is dy @ W written into the kept
+    pixels of a zero array, dw is dy^T @ x[kept]."""
+    forms = ("nohw,oc->nchw", "nohw,nchw->oc") if layout == "NCHW" \
+        else ("nhwo,oc->nhwc", "nhwo,nhwc->oc")
+    dx = np.zeros_like(x)
+    _kept_pixels(dx, stride, layout)[...] = np.einsum(
+        forms[0], dy, w.reshape(w.shape[0], -1))
+    dw = np.einsum(forms[1], dy, _kept_pixels(x, stride, layout))
+    return dx, dw.reshape(w.shape)
+
+
 @pytest.mark.parametrize("layout,stride", [
     ("NCHW", (1, 1)), ("NCHW", (2, 2)), ("NHWC", (1, 1)), ("NHWC", (2, 2)),
 ])
-def test_conv1x1_as_dot_matches_conv(monkeypatch, layout, stride):
+def test_conv1x1_matches_einsum(layout, stride):
     rng = np.random.RandomState(11)
     if layout == "NCHW":
         x = rng.uniform(-1, 1, (2, 6, 8, 8)).astype(np.float32)
@@ -190,24 +216,18 @@ def test_conv1x1_as_dot_matches_conv(monkeypatch, layout, stride):
     else:
         x = rng.uniform(-1, 1, (2, 8, 8, 6)).astype(np.float32)
         w = rng.uniform(-1, 1, (4, 1, 1, 6)).astype(np.float32)
-
-    def run():
-        return mx.nd.Convolution(mx.nd.array(x), mx.nd.array(w),
-                                 kernel=(1, 1), stride=stride, num_filter=4,
-                                 no_bias=True, layout=layout).asnumpy()
-
-    monkeypatch.setenv("MXNET_CONV1X1_DOT", "0")
-    ref = run()
-    monkeypatch.setenv("MXNET_CONV1X1_DOT", "all")
-    opt = run()
-    assert opt.shape == ref.shape
-    assert_almost_equal(opt, ref, rtol=1e-4, atol=1e-5)
+    out = mx.nd.Convolution(mx.nd.array(x), mx.nd.array(w),
+                            kernel=(1, 1), stride=stride, num_filter=4,
+                            no_bias=True, layout=layout).asnumpy()
+    ref = _conv1x1_numpy(x, w, stride, layout)
+    assert out.shape == ref.shape
+    assert_almost_equal(out, ref, rtol=1e-4, atol=1e-5)
 
 
 @pytest.mark.parametrize("layout", ["NCHW", "NHWC"])
-def test_conv1x1_strided_custom_bwd(monkeypatch, layout):
-    """Custom VJP for strided 1x1 convs: grads must match the autodiff
-    transpose of the plain conv path."""
+def test_conv1x1_strided_grads(layout):
+    """Strided 1x1 conv: the input gradient is dy @ W written into the
+    kept pixels of a zero array, the weight gradient dy^T @ x[::2, ::2]."""
     from mxnet_tpu.test_utils import check_numeric_gradient
     rng = np.random.RandomState(13)
     if layout == "NCHW":
@@ -219,32 +239,26 @@ def test_conv1x1_strided_custom_bwd(monkeypatch, layout):
     conv = mx.sym.Convolution(mx.sym.var("data"), mx.sym.var("weight"),
                               kernel=(1, 1), stride=(2, 2), num_filter=4,
                               no_bias=True, layout=layout)
-    out_shape = (2, 4, 4, 4) if layout == "NCHW" else (2, 4, 4, 4)
-    head_np = rng.uniform(-1, 1, out_shape).astype(np.float32)
-
-    def run_grads():
-        exe = conv.bind(mx.cpu(),
-                        args={"data": mx.nd.array(x), "weight": mx.nd.array(w)},
-                        args_grad={"data": mx.nd.zeros(x.shape),
-                                   "weight": mx.nd.zeros(w.shape)})
-        exe.forward(is_train=True)
-        exe.backward(mx.nd.array(head_np))
-        return (exe.outputs[0].asnumpy(),
-                [g.asnumpy() for g in exe.grad_arrays])
-
-    monkeypatch.setenv("MXNET_CONV1X1_BWD", "0")
-    out_ref, grads_ref = run_grads()
-    monkeypatch.setenv("MXNET_CONV1X1_BWD", "1")
-    out_opt, grads_opt = run_grads()
-    assert_almost_equal(out_opt, out_ref, rtol=1e-5, atol=1e-6)
-    for go, gr in zip(grads_opt, grads_ref):
-        assert_almost_equal(go, gr, rtol=1e-4, atol=1e-5)
+    head_np = rng.uniform(-1, 1, (2, 4, 4, 4)).astype(np.float32)
+    exe = conv.bind(mx.cpu(),
+                    args={"data": mx.nd.array(x), "weight": mx.nd.array(w)},
+                    args_grad={"data": mx.nd.zeros(x.shape),
+                               "weight": mx.nd.zeros(w.shape)})
+    exe.forward(is_train=True)
+    exe.backward(mx.nd.array(head_np))
+    out_ref = _conv1x1_numpy(x, w, (2, 2), layout)
+    dx_ref, dw_ref = _conv1x1_grads_numpy(head_np, x, w, (2, 2), layout)
+    assert_almost_equal(exe.outputs[0].asnumpy(), out_ref,
+                        rtol=1e-5, atol=1e-6)
+    assert_almost_equal(exe.grad_dict["data"].asnumpy(), dx_ref,
+                        rtol=1e-4, atol=1e-5)
+    assert_almost_equal(exe.grad_dict["weight"].asnumpy(), dw_ref,
+                        rtol=1e-4, atol=1e-5)
     check_numeric_gradient(conv, {"data": x, "weight": w},
                            numeric_eps=1e-2, rtol=5e-2, atol=1e-3)
 
 
-def test_conv1x1_as_dot_gradients(monkeypatch):
-    monkeypatch.setenv("MXNET_CONV1X1_DOT", "all")
+def test_conv1x1_numeric_gradients():
     from mxnet_tpu.test_utils import check_numeric_gradient
     rng = np.random.RandomState(5)
     x = rng.uniform(-1, 1, (2, 3, 6, 6)).astype(np.float32)
@@ -254,3 +268,65 @@ def test_conv1x1_as_dot_gradients(monkeypatch):
                               no_bias=True)
     check_numeric_gradient(conv, {"data": x, "weight": w},
                            numeric_eps=1e-2, rtol=5e-2, atol=1e-3)
+
+
+@pytest.mark.parametrize("shape", [
+    (4, 4, 4, 256, 128),      # (N, Ho, Wo, K, C): tiny c3-entry-like
+    (2, 7, 7, 256, 128),      # odd spatial extents, c5-downsample-like
+    (8, 2, 2, 128, 256),
+])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_strided_1x1_dgrad_matches_matmul_interleave(shape, dtype):
+    """The input gradient of the stride-2 NHWC 1x1 conv at ResNet's
+    stage-entry widths: dy @ W in the even rows and columns, and exactly
+    zero in the odd ones."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import nn as nn_ops
+    N, Ho, Wo, K, C = shape
+    rng = np.random.RandomState(0)
+    dy = jnp.asarray(rng.randn(N, Ho, Wo, K), dtype)
+    w = jnp.asarray(rng.randn(K, 1, 1, C), dtype)
+    params = {"kernel": (1, 1), "stride": (2, 2), "no_bias": True,
+              "layout": "NHWC", "num_filter": K}
+    x = jnp.zeros((N, 2 * Ho, 2 * Wo, C), dtype)
+    _, vjp = jax.vjp(lambda d: nn_ops._convolution(params, d, w)[0], x)
+    got = np.asarray(vjp(dy)[0], np.float32)
+    want = np.zeros(x.shape, np.float32)
+    want[:, ::2, ::2, :] = np.einsum(
+        "nhwo,oc->nhwc", np.asarray(dy, np.float32),
+        np.asarray(w, np.float32).reshape(K, C))
+    tol = 1e-4 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+    assert np.all(got[:, 1::2, :, :] == 0) and np.all(got[:, :, 1::2, :] == 0)
+
+
+@pytest.mark.parametrize("stride,shapes", [
+    ((2, 2), (2, 8, 8, 128, 64)),
+    ((1, 1), (2, 8, 8, 128, 64)),
+    ((2, 2), (2, 8, 8, 96, 64)),      # channels not lane-aligned
+])
+def test_conv1x1_nhwc_value_and_grads_match_einsum(stride, shapes):
+    """Forward and both gradients of sum(conv(x, w)^2) against the einsum
+    forms, at widths where the channels fill (or miss) whole lanes."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import nn as nn_ops
+    N, H, W, C, K = shapes
+    rng = np.random.RandomState(1)
+    x = rng.randn(N, H, W, C).astype(np.float32)
+    w = rng.randn(K, 1, 1, C).astype(np.float32)
+    params = {"kernel": (1, 1), "stride": stride, "no_bias": True,
+              "layout": "NHWC", "num_filter": K}
+
+    def conv(x, w):
+        return nn_ops._convolution(params, x, w)[0]
+
+    got_y = conv(jnp.asarray(x), jnp.asarray(w))
+    got_dx, got_dw = jax.grad(lambda x, w: jnp.sum(conv(x, w) ** 2),
+                              argnums=(0, 1))(jnp.asarray(x), jnp.asarray(w))
+    want_y = _conv1x1_numpy(x, w, stride, "NHWC")
+    want_dx, want_dw = _conv1x1_grads_numpy(2 * want_y, x, w, stride, "NHWC")
+    for got, want in ((got_y, want_y), (got_dx, want_dx), (got_dw, want_dw)):
+        np.testing.assert_allclose(np.asarray(got), want,
+                                   rtol=2e-3, atol=2e-3)

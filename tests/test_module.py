@@ -241,9 +241,11 @@ def test_python_loss_module():
     assert correct / total > 0.9, correct / total
 
 
-def test_step_scan_pack_small_matches_unpacked():
-    """Module.scan_pack_small (flat-packed rank<=1 carries) must produce
-    the same training trajectory as the plain scan."""
+def test_step_scan_carries_batchnorm_state():
+    """One `_step_scan` over 4 batches against four `_step`s on a network
+    with a BatchNorm under momentum SGD: the scan's carry holds the moving
+    statistics and the momenta beside the parameters, so all of them, and
+    every step's outputs, must come out the same."""
     import numpy as np
 
     def build():
@@ -262,6 +264,11 @@ def test_step_scan_pack_small_matches_unpacked():
                                              "momentum": 0.9})
         return mod
 
+    def momenta(mod):
+        return {name: mod._updater.states[i].asnumpy()
+                for i, name in enumerate(mod._param_names)
+                if mod._updater.states.get(i) is not None}
+
     rng = np.random.RandomState(5)
     batches = [mx.io.DataBatch(
         data=[mx.nd.array(rng.randn(6, 4).astype(np.float32))],
@@ -269,21 +276,31 @@ def test_step_scan_pack_small_matches_unpacked():
         for _ in range(4)]
 
     ref = build()
-    packed = build()
+    scanned = build()
     a0, x0 = ref.get_params()  # same initial weights for both
-    packed.set_params(a0, x0)
-    out_ref = ref._step_scan(batches)
-    assert out_ref is not False
-    packed.scan_pack_small = True
-    out_pk = packed._step_scan(batches)
-    assert out_pk is not False
-    for a, b in zip(out_pk, out_ref):
-        assert np.allclose(a.asnumpy(), b.asnumpy(), rtol=1e-5, atol=1e-6)
+    scanned.set_params(a0, x0)
+    x0 = {name: v.asnumpy() for name, v in x0.items()}
+    out_ref = []
+    for batch in batches:
+        ref._step(batch)
+        out_ref.append(ref.get_outputs()[0].asnumpy())
+    out_scan = scanned._step_scan(batches)
+    assert out_scan is not False and len(out_scan) == 1
+    assert np.allclose(out_scan[0].asnumpy(), np.stack(out_ref),
+                       rtol=1e-5, atol=1e-6)
     a_ref, aux_ref = ref.get_params()
-    a_pk, aux_pk = packed.get_params()
-    for name in a_ref:
-        assert np.allclose(a_pk[name].asnumpy(), a_ref[name].asnumpy(),
-                           rtol=1e-5, atol=1e-6), name
+    a_scan, aux_scan = scanned.get_params()
+    assert set(aux_ref) == {"bn_moving_mean", "bn_moving_var"}
     for name in aux_ref:
-        assert np.allclose(aux_pk[name].asnumpy(), aux_ref[name].asnumpy(),
+        # the statistics moved off their initial 0 / 1, four times over
+        assert not np.allclose(aux_ref[name].asnumpy(), x0[name])
+    m_ref, m_scan = momenta(ref), momenta(scanned)
+    assert set(m_ref) == set(a_ref) and set(m_scan) == set(a_ref)
+    for have, want in ((a_scan, a_ref), (aux_scan, aux_ref)):
+        for name in want:
+            assert np.allclose(have[name].asnumpy(), want[name].asnumpy(),
+                               rtol=1e-5, atol=1e-6), name
+    for name in m_ref:
+        assert np.abs(m_ref[name]).max() > 0, name
+        assert np.allclose(m_scan[name], m_ref[name],
                            rtol=1e-5, atol=1e-6), name

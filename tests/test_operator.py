@@ -507,70 +507,88 @@ def test_batchnorm_backward_oracle():
                                            rtol=2e-4, atol=2e-4)
 
 
-def test_pool_slices_matches_reduce_window():
-    """MXNET_POOL_SLICES (slice-form strided max pool): forward exact,
-    gradients match the reduce_window lowering away from ties."""
-    import os
+@pytest.mark.parametrize("layout", ["NCHW", "NHWC"])
+def test_maxpool_3x3_s2_value_and_grad(layout):
+    """ResNet's stem pool (3x3, stride 2, pad 1: overlapping windows)
+    against numpy: each output is its window's maximum, and the gradient
+    of sum(out^2) reaches each input through the windows it wins."""
     import jax
     import jax.numpy as jnp
     from mxnet_tpu.ops import nn as nn_ops
 
     rng = np.random.RandomState(0)
-    # distinct values => no ties, so both backward conventions agree
-    x = jnp.asarray(rng.permutation(2 * 8 * 13 * 13).reshape(2, 8, 13, 13)
-                    .astype(np.float32))
+    # distinct values => no ties, so the gradient's routing is unique
+    x = rng.permutation(2 * 8 * 13 * 13).reshape(2, 8, 13, 13) \
+        .astype(np.float32)
+    xp = np.pad(x, ((0, 0), (0, 0), (1, 1), (1, 1)),
+                constant_values=-np.inf)
+    want = np.empty((2, 8, 7, 7), np.float32)
+    want_g = np.zeros(xp.shape, np.float32)
+    for i in range(7):
+        for j in range(7):
+            win = xp[:, :, 2 * i:2 * i + 3, 2 * j:2 * j + 3].reshape(2, 8, 9)
+            top = win.argmax(axis=2)
+            want[:, :, i, j] = win.max(axis=2)
+            for n in range(2):
+                for c in range(8):
+                    a, b = divmod(top[n, c], 3)
+                    want_g[n, c, 2 * i + a, 2 * j + b] += 2 * want[n, c, i, j]
+    want_g = want_g[:, :, 1:-1, 1:-1]
     params = {"kernel": (3, 3), "stride": (2, 2), "pad": (1, 1),
-              "pool_type": "max"}
+              "pool_type": "max", "layout": layout}
+    to, back = ((0, 2, 3, 1), (0, 3, 1, 2)) if layout == "NHWC" \
+        else ((0, 1, 2, 3),) * 2
 
-    def run(x):
-        return nn_ops._pooling(params, x)[0]
+    def run(v):
+        return nn_ops._pooling(params, v)[0]
 
-    old = os.environ.get("MXNET_POOL_SLICES")
-    try:
-        os.environ["MXNET_POOL_SLICES"] = "0"
-        want = run(x)
-        gw = jax.grad(lambda v: jnp.sum(run(v) ** 2))(x)
-        os.environ["MXNET_POOL_SLICES"] = "1"
-        got = run(x)
-        gg = jax.grad(lambda v: jnp.sum(run(v) ** 2))(x)
-    finally:
-        if old is None:
-            os.environ.pop("MXNET_POOL_SLICES", None)
-        else:
-            os.environ["MXNET_POOL_SLICES"] = old
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    np.testing.assert_allclose(np.asarray(gg), np.asarray(gw), rtol=1e-6)
+    xin = jnp.asarray(x.transpose(to))
+    got = np.asarray(run(xin)).transpose(back)
+    got_g = np.asarray(jax.grad(lambda v: jnp.sum(run(v) ** 2))(xin)) \
+        .transpose(back)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_allclose(got_g, want_g, rtol=1e-6)
 
 
-def test_space_to_depth_conv_nhwc_matches_direct():
-    """NHWC twin of the stem rewrite (round 5): exact same function as
-    the stride-2 NHWC conv, gradients included."""
+def test_nhwc_stem_conv_matches_im2col():
+    """ResNet's NHWC stem (7x7, stride 2, pad 3, 3 channels), which lowers
+    as a plain convolution: value and both gradients against an im2col
+    matrix product written in numpy."""
     import jax
     import jax.numpy as jnp
-    from jax import lax
-    from mxnet_tpu.ops.nn import _space_to_depth_conv_nhwc
+    from mxnet_tpu.ops import nn as nn_ops
 
     rng = np.random.RandomState(0)
-    for (C, k, pad, H) in [(3, 7, 3, 32), (1, 3, 1, 28), (4, 5, 2, 63),
-                           (3, 8, 3, 64)]:
-        x = jnp.asarray(rng.randn(2, H, H, C).astype(np.float32))
-        w = jnp.asarray(rng.randn(8, k, k, C).astype(np.float32))
-        dn = lax.conv_dimension_numbers(x.shape, w.shape,
-                                        ("NHWC", "OHWI", "NHWC"))
+    N, H, C, O, k, pad = 2, 32, 3, 8, 7, 3
+    x = rng.randn(N, H, H, C).astype(np.float32)
+    w = rng.randn(O, k, k, C).astype(np.float32)
+    Ho = (H + 2 * pad - k) // 2 + 1
+    head = rng.randn(N, Ho, Ho, O).astype(np.float32)
+    xp = np.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
+    cols = np.stack([xp[:, 2 * i:2 * i + k, 2 * j:2 * j + k, :]
+                     .reshape(N, k * k * C)
+                     for i in range(Ho) for j in range(Ho)], axis=1)
+    w2 = w.reshape(O, k * k * C)
+    want = (cols @ w2.T).reshape(N, Ho, Ho, O)
+    head2 = head.reshape(N, Ho * Ho, O)
+    want_dw = np.einsum("npo,npk->ok", head2, cols).reshape(w.shape)
+    dcols = (head2 @ w2).reshape(N, Ho, Ho, k, k, C)
+    want_dx = np.zeros_like(xp)
+    for i in range(Ho):
+        for j in range(Ho):
+            want_dx[:, 2 * i:2 * i + k, 2 * j:2 * j + k, :] += dcols[:, i, j]
+    want_dx = want_dx[:, pad:-pad, pad:-pad, :]
+    params = {"kernel": (k, k), "stride": (2, 2), "pad": (pad, pad),
+              "no_bias": True, "layout": "NHWC", "num_filter": O}
 
-        def f_ref(x, w):
-            return lax.conv_general_dilated(
-                x, w, (2, 2), [(pad, pad), (pad, pad)],
-                dimension_numbers=dn).sum()
+    def conv(x, w):
+        return nn_ops._convolution(params, x, w)[0]
 
-        def f_got(x, w):
-            return _space_to_depth_conv_nhwc(x, w, (pad, pad)).sum()
-
-        ref = lax.conv_general_dilated(x, w, (2, 2), [(pad, pad), (pad, pad)],
-                                       dimension_numbers=dn)
-        got = _space_to_depth_conv_nhwc(x, w, (pad, pad))
-        assert ref.shape == got.shape
-        assert float(jnp.abs(ref - got).max()) < 1e-4
-        for a, b in zip(jax.grad(f_ref, (0, 1))(x, w),
-                        jax.grad(f_got, (0, 1))(x, w)):
-            assert float(jnp.abs(a - b).max()) < 1e-3
+    got = conv(jnp.asarray(x), jnp.asarray(w))
+    got_dx, got_dw = jax.grad(
+        lambda x, w: jnp.sum(conv(x, w) * head), (0, 1))(
+            jnp.asarray(x), jnp.asarray(w))
+    assert got.shape == want.shape
+    assert np.abs(np.asarray(got) - want).max() < 1e-4
+    assert np.abs(np.asarray(got_dx) - want_dx).max() < 1e-3
+    assert np.abs(np.asarray(got_dw) - want_dw).max() < 1e-3

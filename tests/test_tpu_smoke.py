@@ -368,25 +368,26 @@ def test_group2ctx_spans_tpu_and_cpu():
     assert abs(acc_g - acc_p) < 0.05
 
 
-def test_conv1x1_s2_dgrad_kernel_on_chip():
-    """The Pallas strided-1x1 dgrad kernel (env-gated off by default —
-    measured negative end-to-end, see docs/perf/
-    resnet50_train_attribution.md) must stay CORRECT on real hardware:
-    Mosaic lowering (i32 index maps, double-buffered VMEM budget) is
-    exactly what CPU interpret mode cannot exercise."""
+def test_strided_1x1_dgrad_on_chip():
+    """The input gradient of the stride-2 NHWC 1x1 conv at ResNet-50's
+    stage-entry widths, as the chip's own conv-transpose emitter computes
+    it: dy @ W in the even rows and columns, zero in the odd ones."""
     _tpu_ctx()
     import jax
     import jax.numpy as jnp
-    from mxnet_tpu.ops.conv_kernels import conv1x1_s2_dgrad
+    from mxnet_tpu.ops import nn as nn_ops
 
     rng = np.random.RandomState(0)
     for N, Ho, K, C in ((16, 28, 512, 256), (16, 7, 256, 128)):
         dy = jnp.asarray(rng.randn(N, Ho, Ho, K), jnp.bfloat16)
-        w2 = jnp.asarray(rng.randn(K, C), jnp.bfloat16)
-        got = np.asarray(conv1x1_s2_dgrad(dy, w2, 2 * Ho, 2 * Ho),
-                         np.float32)
+        w = jnp.asarray(rng.randn(K, 1, 1, C), jnp.bfloat16)
+        params = {"kernel": (1, 1), "stride": (2, 2), "no_bias": True,
+                  "layout": "NHWC", "num_filter": K}
+        x = jnp.zeros((N, 2 * Ho, 2 * Ho, C), jnp.bfloat16)
+        _, vjp = jax.vjp(lambda d: nn_ops._convolution(params, d, w)[0], x)
+        got = np.asarray(vjp(dy)[0], np.float32)
         want = np.einsum("nhwk,kc->nhwc", np.asarray(dy, np.float32),
-                         np.asarray(w2, np.float32))
+                         np.asarray(w, np.float32).reshape(K, C))
         np.testing.assert_allclose(got[:, ::2, ::2, :], want,
                                    rtol=5e-2, atol=5e-1)
         assert (got[:, 1::2] == 0).all() and (got[:, :, 1::2] == 0).all()
