@@ -3,9 +3,38 @@
 Full registry: Accuracy, TopKAccuracy, F1, Perplexity, MAE, MSE, RMSE,
 CrossEntropy, NegativeLogLikelihood, PearsonCorrelation, Loss, Torch, Caffe,
 CustomMetric, CompositeEvalMetric, np/create helpers.
+
+**What lags, and what forces a fold.** `update(labels, preds)` copies the
+outputs to the host, which waits for the step that produces them. Inside
+`Module.fit`'s training loop an `update_dict` therefore only QUEUES the
+update, and the metric's own unchanged `update` folds it into
+`sum_metric` / `num_inst` one dispatch later, when `fit` has handed the
+next step to the device: the same numpy arithmetic on the same arrays in
+the same order, so the value is the immediate sequence's bit for bit.
+The queue holds the arrays and not their holders (a label `NDArray` an
+iterator rebinds later does not change what is folded), and the outputs'
+copy to the host starts when they are queued. Everywhere else
+(`score`, `predict`, a hand loop, Gluon scripts) nothing is queued:
+`update_dict` calls `update` at once, and so does `update` called
+directly, always.
+
+Every observation folds whatever is queued first: `get`,
+`get_name_value`, `str()`, and any read or write of `sum_metric` /
+`num_inst` (they are properties), which covers the `get` of `F1`,
+`Perplexity`, `CompositeEvalMetric`, `CustomMetric` and of a user's
+subclass that reads them. One reader is not an observation: `fit`'s own
+health sweep (`runprof`, every 16th batch) takes the value as folded,
+one dispatch behind, through `_name_value_as_folded`, forces nothing,
+and passes while nothing has been folded since the last `reset`.
+`reset` drops what is queued; its sums are zeroed either way. A
+subclass that overrides `update` alone is deferred like the rest; one
+that overrides `update_dict` without `super` is never deferred. A deferred `update` that raises does so at the fold, one
+dispatch after the batch that caused it or at the next observation, with
+a note that names the batch.
 """
 from __future__ import annotations
 
+import collections
 import math
 
 import numpy as _np
@@ -38,12 +67,34 @@ def check_label_shapes(labels, preds, wrap=False, shape=False):
     return labels, preds
 
 
+def _held(arrays):
+    """``arrays`` as they are now, for a fold that comes later: each dense
+    `NDArray`'s buffer under a holder of its own (the iterator may rebind a
+    recycled batch's label, an executor its outputs), its copy to the host
+    started. Anything else (a sparse array) is held as it is."""
+    held = []
+    for arr in arrays:
+        if type(arr) is NDArray:
+            arr = NDArray(arr._data, arr._ctx)
+            start = getattr(arr._data, "copy_to_host_async", None)
+            if start is not None:
+                start()
+        held.append(arr)
+    return held
+
+
 class EvalMetric:
     def __init__(self, name, output_names=None, label_names=None, **kwargs):
         self.name = str(name)
         self.output_names = output_names
         self.label_names = label_names
         self._kwargs = kwargs
+        # the updates `update_dict` has queued, oldest first, as (number of
+        # the batch, labels, preds); `_lag` of them may stay queued
+        self._pending = collections.deque()
+        self._lag = 0
+        self._folding = False
+        self._batch = self._queued = self._lagged = 0
         self.reset()
 
     def __str__(self):
@@ -65,12 +116,99 @@ class EvalMetric:
             label = [label[name] for name in self.label_names if name in label]
         else:
             label = list(label.values())
-        self.update(label, pred)
+        if not self._lag:
+            self._fold()    # nothing, unless a loop left its last ones
+            self.update(label, pred)
+            return
+        self._pending.append((self._batch, _held(label), _held(pred)))
+        self._batch += 1
+        self._queued += 1
+        self._lagged += self._fold(keep=self._lag)
 
     def update(self, labels, preds):
         raise NotImplementedError()
 
+    def _defer(self, lag):
+        """`Module.fit`'s: from now on `update_dict` queues its update and
+        folds the oldest until at most ``lag`` stay queued (the batches of
+        the dispatch `fit` has just issued); 0 is every other caller's:
+        `update` at once."""
+        if lag and not self._lag:
+            self._batch = 0
+        self._lag = lag
+
+    def _fold(self, keep=0):
+        """Folds the oldest queued updates through `update` until at most
+        ``keep`` are left and returns how many that were. Does nothing
+        while a fold runs: `update` itself reads and writes the sums."""
+        if self._folding:
+            return 0
+        folded = 0
+        while len(self._pending) > keep:
+            batch, labels, preds = self._pending.popleft()
+            self._folding = True
+            try:
+                self.update(labels, preds)
+            except Exception as exc:
+                exc.add_note(
+                    "raised by the metric update of batch %d since Module.fit "
+                    "began to defer them: update_dict queued it, and it is "
+                    "folded one dispatch later or at the next read" % batch)
+                raise
+            finally:
+                self._folding = False
+            folded += 1
+        return folded
+
+    def _lag_counts(self):
+        """(updates queued, queued updates folded behind a later one)
+        since the last call: what `fit` writes on its
+        ``device_compute via=update_metric`` phase. A fold that an
+        observation forced counts in neither."""
+        counts = self._queued, self._lagged
+        self._queued = self._lagged = 0
+        return counts
+
+    def _name_value_as_folded(self):
+        """`get_name_value` over the updates folded so far, the queued ones
+        left queued: for `fit`'s health sweep alone, which can do with a
+        value one dispatch behind and must not make the loop wait for the
+        step it has just issued. No pairs while updates are queued and
+        none has been folded since the last `reset`: there is no value
+        yet, and `get` would call that NaN."""
+        queued = self._pending
+        if not queued:
+            return self.get_name_value()
+        if not self._num_inst:
+            return []
+        self._pending = collections.deque()
+        try:
+            return self.get_name_value()
+        finally:
+            self._pending = queued
+
+    @property
+    def sum_metric(self):
+        self._fold()
+        return self._sum_metric
+
+    @sum_metric.setter
+    def sum_metric(self, value):
+        self._fold()
+        self._sum_metric = value
+
+    @property
+    def num_inst(self):
+        self._fold()
+        return self._num_inst
+
+    @num_inst.setter
+    def num_inst(self, value):
+        self._fold()
+        self._num_inst = value
+
     def reset(self):
+        self._pending.clear()
         self.num_inst = 0
         self.sum_metric = 0.0
 
@@ -114,6 +252,19 @@ class CompositeEvalMetric(EvalMetric):
     def update(self, labels, preds):
         for metric in self.metrics:
             metric.update(labels, preds)
+
+    def _defer(self, lag):
+        for metric in self.metrics:
+            metric._defer(lag)
+
+    def _lag_counts(self):
+        """The children's counts, summed."""
+        counts = [metric._lag_counts() for metric in self.metrics]
+        return tuple(map(sum, zip(*counts))) or (0, 0)
+
+    def _name_value_as_folded(self):
+        return [pair for metric in self.metrics
+                for pair in metric._name_value_as_folded()]
 
     def reset(self):
         try:
@@ -195,9 +346,8 @@ class F1(EvalMetric):
         self.reset()
 
     def reset(self):
+        super().reset()
         self.tp = self.fp = self.fn = 0.0
-        self.num_inst = 0
-        self.sum_metric = 0.0
 
     @staticmethod
     def _f1(tp, fp, fn):

@@ -49,7 +49,10 @@ def _count_fit_batch(batch, eval_metric=None):
                           ).inc(samples)
     if eval_metric is not None and runprof.should_check():
         try:
-            runprof.observe_metrics(eval_metric.get_name_value())
+            # one dispatch behind where `fit` defers the metric's updates:
+            # the sentinels need a value, not the loop's wait for this
+            # step; nothing while nothing is folded since the last reset
+            runprof.observe_metrics(eval_metric._name_value_as_folded())
         except runprof.RunHealthError:
             raise   # MXNET_RUNPROF_HALT: a tripped sentinel stops fit
         except Exception as exc:  # a broken metric must not stop fit
@@ -205,6 +208,14 @@ class BaseModule:
         dispatched. The iterator runs one batch, with K one group and one
         batch, ahead of the callbacks.
 
+        The metric is read one dispatch behind (`metric.py`): inside the
+        loop batch n's `update_dict` is queued and folded into the sums
+        after step n+1 has been handed to the device, so the loop waits
+        for step n while the chip already has the next; a callback (or
+        anything else) that reads the metric gets every queued update
+        folded first and the value it would have got before. Not under a
+        ``monitor``.
+
         SPMD extension: ``spmd=`` selects a `parallel.spmd` sharding
         policy (``"data_parallel"`` / ``"fsdp"`` / ``"tensor"``, a
         ``ShardingPolicy``, or an option dict) for the bind — parameters
@@ -299,6 +310,16 @@ class BaseModule:
                 "fit_exception",
                 error="%s: %s" % (type(exc).__name__, str(exc)[:400]))
             raise
+        finally:
+            eval_metric._defer(0)
+
+    def _update_metric_waiting(self, eval_metric, data_batch):
+        """`update_metric` in the loop's ``device_compute`` phase, with what
+        the metric queued and folded behind a later dispatch written on it
+        (the per-batch path's)."""
+        with stepprof.phase("device_compute", via="update_metric") as wait:
+            self.update_metric(eval_metric, data_batch.label)
+            wait["queued"], wait["lagged"] = eval_metric._lag_counts()
 
     def _fit_loop(self, train_data, eval_data, eval_metric,
                   validation_metric, epoch_end_callback,
@@ -336,6 +357,22 @@ class BaseModule:
             # Step n+1 is dispatched after batch n's callbacks, as ever.
             # The first batch of an epoch is staged by its step (the
             # first group's members as the iterator hands them over)
+            #
+            # The metric is read one dispatch behind: `update_dict` queues
+            # batch n's labels and outputs and folds those of the dispatch
+            # before (`EvalMetric._defer`: as many may stay queued as the
+            # dispatch just issued has batches; none under a monitor).
+            # So the loop's wait, in the device_compute phase, is for step
+            # n-1 while step n is already on the device, and the compiled
+            # call of step n+1 runs under step n. That wait is the loop's
+            # back-pressure: the host is never more than one dispatch
+            # ahead. It comes BEFORE the next batch (group) is fetched and
+            # staged, so the device holds no more staged input than it did
+            # when the wait was for step n (under a monitor nothing lags
+            # and the read of step n keeps its place behind the staging).
+            # Anything that reads the metric folds all of it first
+            # (metric.py); an epoch's last dispatch is folded by the
+            # epoch's own read below.
             group = None
             while use_scan and not (end_of_batch and group is None):
                 with stepprof.step() as _sp:
@@ -349,17 +386,13 @@ class BaseModule:
                     # module without a scan plan step batch by batch
                     stacked = self._step_scan(group) \
                         if len(group) > 1 else False
+                    eval_metric._defer(len(group) if stacked else 1)
                     next_group = None
-                    if not end_of_batch:
-                        next_group, next_data_batch, end_of_batch = \
-                            self._gather_group(
-                                data_iter, next_data_batch,
-                                batches_per_dispatch, sparse_row_id_fn)
                     for k_i, b in enumerate(group):
                         if stacked is False:  # per-batch fallback
                             self._step(b)
                         with stepprof.phase("device_compute",
-                                            via="update_metric"):
+                                            via="update_metric") as _wait:
                             if stacked:
                                 outs = {name: out[k_i]
                                         for name, out in
@@ -370,6 +403,16 @@ class BaseModule:
                                     outs)
                             else:
                                 self.update_metric(eval_metric, b.label)
+                            _wait["queued"], _wait["lagged"] = \
+                                eval_metric._lag_counts()
+                        if k_i == 0 and not end_of_batch:
+                            # the group's first fold has waited for the
+                            # dispatch before: the next group travels
+                            # under this one
+                            next_group, next_data_batch, end_of_batch = \
+                                self._gather_group(
+                                    data_iter, next_data_batch,
+                                    batches_per_dispatch, sparse_row_id_fn)
                         _count_fit_batch(b, eval_metric)
                         if batch_end_callback is not None:
                             batch_end_params = BatchEndParam(
@@ -380,6 +423,8 @@ class BaseModule:
                                 callback(batch_end_params)
                         nbatch += 1
                     group = next_group
+            # one batch to a dispatch from here on
+            eval_metric._defer(0 if monitor is not None else 1)
             while not end_of_batch:
                 data_batch = next_data_batch
                 with stepprof.step() as _sp:
@@ -389,6 +434,8 @@ class BaseModule:
                         self.update()
                     else:
                         self._step(data_batch)
+                    if monitor is None:
+                        self._update_metric_waiting(eval_metric, data_batch)
                     with stepprof.phase("data_wait") as _dspan:
                         try:
                             next_data_batch = next(data_iter)
@@ -398,9 +445,10 @@ class BaseModule:
                     if not end_of_batch:
                         self.prepare(next_data_batch,
                                      sparse_row_id_fn=sparse_row_id_fn)
-                    with stepprof.phase("device_compute",
-                                        via="update_metric"):
-                        self.update_metric(eval_metric, data_batch.label)
+                    if monitor is not None:
+                        # no lag: the read of this step's own outputs, with
+                        # the next batch travelling under the step as ever
+                        self._update_metric_waiting(eval_metric, data_batch)
                     _count_fit_batch(data_batch, eval_metric)
                     if monitor is not None:
                         monitor.toc_print()
@@ -412,6 +460,7 @@ class BaseModule:
                             callback(batch_end_params)
                     nbatch += 1
 
+            eval_metric._defer(0)
             for name, val in eval_metric.get_name_value():
                 self.logger.info("Epoch[%d] Train-%s=%f", epoch, name, val)
             toc = time.time()

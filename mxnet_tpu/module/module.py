@@ -976,7 +976,11 @@ class Module(BaseModule):
             if dst is not None:
                 dst._data = val
         fused.commit_states(indices, sv)
-        exec_.outputs = [_from_data(o[-1], exec_._ctx) for o in outs]
+        # K - 1, not -1: jax normalises a negative index with eager scalar
+        # ops on the device, and with `fit` one dispatch ahead one of them
+        # held this call until the dispatch BEFORE had ended (727 ms, in no
+        # phase; PERF.md section 6, PR 31)
+        exec_.outputs = [_from_data(o[K - 1], exec_._ctx) for o in outs]
         self._params_dirty = True
         return [_from_data(o, exec_._ctx) for o in outs]
 

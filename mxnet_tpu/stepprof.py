@@ -65,9 +65,14 @@ pair read back to back at step entry (``time.time_ns()``,
 one entry per occurrence, all on ``perf_counter``. :func:`timeline`
 returns the ring (``MXNET_STEPPROF_WINDOW`` steps) as plain data.
 ``device_compute via=update_metric`` is a HOST WAIT, not device time:
-it ends when the outputs are on the host, and starts wherever the loop
-got round to asking, so it can hold the tail of the staging of the
-batch as well as the step program (device time is the trace's).
+since `fit` reads the metric one dispatch behind it is the wait for the
+dispatch BEFORE the one the step has just issued (the fold of that
+dispatch's queued updates: `metric.py`), with this step's program
+already on the device behind it. It ends when those outputs are on the
+host and starts wherever the loop got round to asking. Its attrs
+``queued`` / ``lagged`` count the updates this phase queued and the
+queued ones it folded; an update that a read of the metric forces is
+folded outside any phase, in ``other`` (device time is the trace's).
 
 Recording is always on and costs what the PR 2 fit spans cost (a dict
 lookup and two clock reads per phase, plus one tuple per phase for the

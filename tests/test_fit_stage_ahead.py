@@ -5,6 +5,9 @@ The module is bound to another host device than the one the batches live
 on (`mx.tpu(1)` is host device 1 in CPU mode, the batches are on
 `mx.cpu()`), so that placing a batch is a transfer here as it is on the
 chip; the `context=[several]` cases take the sharded placement."""
+import logging
+import time
+
 import numpy as np
 import pytest
 
@@ -127,12 +130,12 @@ def _hand_loop(where, arrays):
     return states, outs, values
 
 
-def _fit(where, it, k, callback=None):
+def _fit(where, it, k, callback=None, metric="ce", monitor=None):
     mod = mx.mod.Module(_net(), context=_contexts(where))
     stepprof.reset()
-    mod.fit(it, eval_metric="ce", optimizer="sgd", optimizer_params=SGD,
+    mod.fit(it, eval_metric=metric, optimizer="sgd", optimizer_params=SGD,
             arg_params=_params(), num_epoch=1, batch_end_callback=callback,
-            batches_per_dispatch=k)
+            batches_per_dispatch=k, monitor=monitor)
     return mod
 
 
@@ -313,3 +316,115 @@ def test_bucketing_module_stages_through_the_bucket():
     staged = batch._staged["data"][1]
     mod.forward_backward(batch)
     assert mod._curr_module._exec.arg_dict["data"]._data is staged
+
+
+class TimedCE(mx.metric.CrossEntropy):
+    """Cross-entropy that notes when each update was folded."""
+
+    def __init__(self):
+        super().__init__()
+        self.at = []
+
+    def update(self, labels, preds):
+        self.at.append(time.perf_counter())
+        super().update(labels, preds)
+
+
+def _placed(rec, name):
+    """[(start, end)] of ``rec``'s phases called ``name``, on perf_counter."""
+    t0 = rec["clock"][1]
+    return [(t0 + start, t0 + start + dur)
+            for n, start, dur, _ in rec["spans"] if n == name]
+
+
+NEVER, EVERY, MONITOR = "never read", "read every batch", "under a monitor"
+
+
+@pytest.mark.parametrize("reads", [NEVER, EVERY, MONITOR])
+@pytest.mark.parametrize("k", [1, 4])
+def test_the_metric_is_read_one_dispatch_behind(k, reads, caplog):
+    """With nothing reading the metric, batch n's update is queued in its
+    own step and folded in the wait of the dispatch after, once that
+    dispatch's compiled call has returned; a callback that reads every
+    batch forces every fold and sees the hand loop's values; a monitor
+    turns the lag off. One epoch, a short last group and a shape change;
+    the epoch's logged value is the hand loop's in all three."""
+    arrays = _mixed_arrays()
+    _, _, values = _hand_loop(ONE, arrays)
+    it = ListIter([_batch(x, y) for x, y in arrays])
+    metric, seen = TimedCE(), []
+
+    def callback(param):
+        if reads == EVERY:
+            assert param.eval_metric.get()[1] == pytest.approx(
+                values[param.nbatch], rel=1e-4)
+        seen.append(param.nbatch)
+
+    monitor = mx.Monitor(100) if reads == MONITOR else None
+    with caplog.at_level(logging.INFO):
+        _fit(ONE, it, k, callback, metric, monitor)
+    assert seen == list(range(TOTAL)) and len(metric.at) == TOTAL
+    logged = [r.getMessage() for r in caplog.records
+              if "Train-cross-entropy" in r.getMessage()]
+    assert len(logged) == 1
+    assert float(logged[0].split("=")[1]) == pytest.approx(values[-1],
+                                                           rel=1e-4)
+    assert metric._lag == 0 and not metric._pending
+
+    steps = stepprof.timeline()
+    sizes = [r["batches"] for r in steps]
+    scan = k > 1 and monitor is None
+    assert sizes == ([4, 3, 4, 2] if scan else [1] * TOTAL)
+    first = 0    # number of the record's first batch
+    for i, rec in enumerate(steps):
+        waits = [a for n, _, _, a in rec["spans"]
+                 if n == "device_compute" and a.get("via") == "update_metric"]
+        assert len(waits) == rec["batches"]
+        queued = sum(a["queued"] for a in waits)
+        lagged = sum(a["lagged"] for a in waits)
+        own = metric.at[first:first + rec["batches"]]
+        (_, called), = _placed(rec, "dispatch") or [(None, None)]
+        begun = _placed(rec, "device_compute")[0][0]
+        end = rec["clock"][1] + rec["wall"]
+        if reads == MONITOR:
+            # folded at once, in the step's own wait, which keeps its
+            # place behind the staging of the next batch
+            assert (queued, lagged) == (0, 0)
+            assert all(begun <= t <= end for t in own)
+            staged = [start for n, start, _, a in rec["spans"]
+                      if n == "h2d" and a.get("via") == "prepare"]
+            assert len(staged) == (i + 1 < len(steps))
+            assert all(rec["clock"][1] + start <= begun for start in staged)
+        elif reads == EVERY:
+            # forced by the callback, after the step's wait had ended
+            assert (queued, lagged) == (rec["batches"], 0)
+            assert all(called <= begun <= t <= end for t in own)
+        else:
+            assert queued == rec["batches"]
+            assert lagged == (sizes[i - 1] if i else 0)
+            # this step's own updates wait for the next dispatch's call
+            # (the epoch's last: for the epoch's read of the metric)
+            after = _placed(steps[i + 1], "dispatch")[0][1] \
+                if i + 1 < len(steps) else end
+            assert all(t >= after for t in own)
+            # and those of the dispatch before were folded in this step's
+            # waits, its own compiled call having returned
+            if i:
+                before = metric.at[first - sizes[i - 1]:first]
+                assert all(called <= begun <= t <= end for t in before)
+        first += rec["batches"]
+
+
+def test_lagged_and_forced_folds_log_the_same_epoch_value(caplog):
+    """The same floats in the same order: the epoch's line is the same text
+    whether every fold lagged or every fold was forced."""
+    lines = []
+    for reads in (False, True):
+        caplog.clear()
+        arrays = _arrays(9)
+        with caplog.at_level(logging.INFO):
+            _fit(ONE, ListIter([_batch(x, y) for x, y in arrays]), 1,
+                 (lambda p: p.eval_metric.get()) if reads else None)
+        lines += [r.getMessage() for r in caplog.records
+                  if "Train-cross-entropy" in r.getMessage()]
+    assert len(lines) == 2 and lines[0] == lines[1]
