@@ -422,6 +422,14 @@ class CompiledProgram:
         with self._mesh_scope():
             return self._fn.lower(*args, **kwargs)
 
+    def compiled_text(self):
+        """The optimized HLO text of every executable this program holds:
+        the instructions under the names a device trace shows them by, each
+        with the ``op_name`` it was traced under (`jax.named_scope`), which
+        the trace itself leaves out."""
+        return [entry.compiled.as_text() for entry in self._cache.values()
+                if entry.compiled is not None]
+
     def warmup(self, *args):
         """AOT-compile the signature of ``args`` into the cache WITHOUT
         executing the program (serving/bench warm start). Returns self.

@@ -155,6 +155,60 @@ def _plan_relu_pool_fold(sym: Symbol, nodes, folds):
         folds[id(n)] = ("fold_relu",)
 
 
+def _scope_attr(node, key):
+    """The value `mx.AttrScope(key=...)` left on ``node``, or None."""
+    return node.attrs.get("__attrs__", {}).get(key)
+
+
+def _plan_mirror_segments(sym: Symbol, nodes, folds):
+    """Recomputation, expressed in the symbol (the reference's memory
+    mirroring, `MXNET_BACKWARD_DO_MIRROR` with its ``force_mirroring`` /
+    ``mirror_stage`` node attributes, graph_executor.cc:282): op nodes that
+    carry the same ``mirror_stage`` (`mx.AttrScope(mirror_stage=...)`) and
+    are contiguous in topological order, variables aside, form one segment.
+    A training evaluation runs each segment under `jax.checkpoint`: the
+    backward pass keeps the segment's inputs and recomputes the rest.
+
+    Returns [(indices into ``nodes``, external inputs [(node id, output)],
+    outputs used outside [(node id, output)])]; empty for a symbol without
+    the attribute, whose evaluation is then what it always was."""
+    runs, run, stage = [], [], None
+    for i, n in enumerate(nodes):
+        if n.is_var():
+            continue
+        here = _scope_attr(n, "mirror_stage")
+        if run and here != stage:
+            runs.append(run)
+            run = []
+        if here is not None:
+            run.append(i)
+        stage = here
+    if run:
+        runs.append(run)
+    if not runs:
+        return []
+    consumers = _consumer_map(sym, nodes)
+    segments = []
+    for run in runs:
+        inside = {id(nodes[i]) for i in run}
+        ins, outs = [], []
+        for i in run:
+            n = nodes[i]
+            sources = list(n.inputs)
+            fold = folds.get(id(n))
+            if fold is not None and fold[0] == "fold_bias":
+                sources.append((fold[1], fold[2]))
+            for src, oi in sources:
+                if id(src) not in inside and (id(src), oi) not in ins:
+                    ins.append((id(src), oi))
+            outs.extend(
+                (id(n), oi) for oi in range(n.num_outputs)
+                if any(u is None or id(u) not in inside
+                       for u in consumers.get((id(n), oi), ())))
+        segments.append((run, ins, outs))
+    return segments
+
+
 def _build_eval(sym: Symbol, ctx=None):
     """Build eval_fn(arg_vals, aux_vals, key, is_train) -> (outs, aux_updates).
 
@@ -165,49 +219,90 @@ def _build_eval(sym: Symbol, ctx=None):
     out_index = [(id(n), i) for n, i in sym._outputs]
     folds = _plan_conv_bias_bn_fold(sym, nodes)
     _plan_relu_pool_fold(sym, nodes, folds)
+    segments = _plan_mirror_segments(sym, nodes, folds)
+    from . import telemetry
+    telemetry.gauge(
+        "remat_segments", "segments of the last bound graph that a training "
+        "step recomputes in its backward pass (mirror_stage)").set(
+            len(segments))
+
+    def eval_node(seq, n, env, aux_updates, key, is_train):
+        op = get_op(n.op)
+        params = {k: v for k, v in n.attrs.items() if k != "__attrs__"}
+        params["_ctx"] = ctx
+        if op.need_train_flag:
+            params["_is_train"] = is_train
+        if op.need_rng:
+            params["_rng_key"] = jax.random.fold_in(key, seq)
+        fold = folds.get(id(n))
+        if fold is not None:
+            if fold[0] == "drop_bias":
+                params["no_bias"] = True
+            elif fold[0] == "fold_bias":
+                params["_fold_bias"] = env[id(fold[1])][fold[2]]
+            elif fold[0] == "fold_relu":
+                params["_fold_relu"] = True
+            elif fold[0] == "bypass":
+                env[id(n)] = [env[id(n.inputs[0][0])][n.inputs[0][1]]]
+                return
+        ins = [env[id(src)][oi] for src, oi in n.inputs]
+        scope = _scope_attr(n, "profiler_scope")
+        if scope is None:
+            outs = op.fcompute(params, *ins)
+        else:
+            # `mx.AttrScope(profiler_scope=...)`: the name a device trace
+            # shows this node's operations under
+            with jax.named_scope(scope):
+                outs = op.fcompute(params, *ins)
+        if not isinstance(outs, (tuple, list)):
+            outs = (outs,)
+        n_out = op.n_out(params)
+        if op.mutate_aux:
+            for ai, new_val in zip(op.mutate_aux, outs[n_out:]):
+                src, _ = n.inputs[ai]
+                if src.is_var():
+                    aux_updates[src.name] = new_val
+            outs = outs[:n_out]
+        env[id(n)] = list(outs)
+
+    def eval_segment(segment, env, aux_updates, key):
+        run, ins, outs = segment
+
+        def body(vals, key):
+            local, updates = {}, {}
+            for (nid, oi), val in zip(ins, vals):
+                local.setdefault(nid, {})[oi] = val
+            for seq in run:
+                eval_node(seq, nodes[seq], local, updates, key, True)
+            return [local[nid][oi] for nid, oi in outs], updates
+
+        vals, updates = jax.checkpoint(body)(
+            [env[nid][oi] for nid, oi in ins], key)
+        aux_updates.update(updates)
+        for (nid, oi), val in zip(outs, vals):
+            env.setdefault(nid, {})[oi] = val
 
     def eval_fn(arg_vals, aux_vals, key, is_train):
         env = {}
         aux_updates = {}
+        recompute = {s[0][0]: s for s in segments} if is_train else {}
+        covered = {seq for s in recompute.values() for seq in s[0]}
+        for n in nodes:
+            if not n.is_var():
+                continue
+            if n.name in arg_vals:
+                env[id(n)] = [arg_vals[n.name]]
+            elif n.name in aux_vals:
+                env[id(n)] = [aux_vals[n.name]]
+            else:
+                raise MXNetError("unbound variable %s" % n.name)
         for seq, n in enumerate(nodes):
             if n.is_var():
-                if n.name in arg_vals:
-                    env[id(n)] = [arg_vals[n.name]]
-                elif n.name in aux_vals:
-                    env[id(n)] = [aux_vals[n.name]]
-                else:
-                    raise MXNetError("unbound variable %s" % n.name)
                 continue
-            op = get_op(n.op)
-            params = {k: v for k, v in n.attrs.items() if k != "__attrs__"}
-            params["_ctx"] = ctx
-            if op.need_train_flag:
-                params["_is_train"] = is_train
-            if op.need_rng:
-                params["_rng_key"] = jax.random.fold_in(key, seq)
-            fold = folds.get(id(n))
-            if fold is not None:
-                if fold[0] == "drop_bias":
-                    params["no_bias"] = True
-                elif fold[0] == "fold_bias":
-                    params["_fold_bias"] = env[id(fold[1])][fold[2]]
-                elif fold[0] == "fold_relu":
-                    params["_fold_relu"] = True
-                elif fold[0] == "bypass":
-                    env[id(n)] = [env[id(n.inputs[0][0])][n.inputs[0][1]]]
-                    continue
-            ins = [env[id(src)][oi] for src, oi in n.inputs]
-            outs = op.fcompute(params, *ins)
-            if not isinstance(outs, (tuple, list)):
-                outs = (outs,)
-            n_out = op.n_out(params)
-            if op.mutate_aux:
-                for ai, new_val in zip(op.mutate_aux, outs[n_out:]):
-                    src, _ = n.inputs[ai]
-                    if src.is_var():
-                        aux_updates[src.name] = new_val
-                outs = outs[:n_out]
-            env[id(n)] = list(outs)
+            if seq in recompute:
+                eval_segment(recompute[seq], env, aux_updates, key)
+            elif seq not in covered:
+                eval_node(seq, n, env, aux_updates, key, is_train)
         return [env[nid][i] for nid, i in out_index], aux_updates
 
     return eval_fn

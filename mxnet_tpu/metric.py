@@ -35,6 +35,7 @@ a note that names the batch.
 from __future__ import annotations
 
 import collections
+import functools
 import math
 
 import numpy as _np
@@ -83,6 +84,60 @@ def _held(arrays):
     return held
 
 
+@functools.lru_cache(maxsize=None)
+def _picked_log_sum(eps, floor, ignore):
+    """The jitted reduction of `_PickedLogSum`: (pred [..., classes], label)
+    -> float32 [sum of -log(max(floor, p + eps)) over the rows that count,
+    how many count], p the label's probability in a row."""
+    import jax
+    import jax.numpy as jnp
+
+    def reduce(pred, label):
+        label = label.reshape(-1).astype(jnp.int32)
+        pred = pred.reshape(-1, pred.shape[-1])
+        prob = jnp.take_along_axis(pred, label[:, None], axis=1)[:, 0]
+        prob = prob.astype(jnp.float32) + jnp.float32(eps)
+        counts = jnp.ones(label.shape, bool) if ignore is None \
+            else label != ignore
+        prob = jnp.where(counts, jnp.maximum(prob, jnp.float32(floor)), 1.0)
+        return jnp.stack([-jnp.sum(jnp.log(prob)),
+                          jnp.sum(counts).astype(jnp.float32)])
+
+    return jax.jit(reduce)
+
+
+class _PickedLogSum:
+    """One batch's sum of -log p(label) and its count, reduced on the device
+    that holds the outputs (the reference's `Perplexity` picks with
+    `ndarray.pick` on the device too): two numbers cross to the host, not
+    [rows, classes] probabilities. Made when the batch is seen, read when
+    the metric folds it."""
+
+    def __init__(self, label, pred, eps, floor, ignore):
+        import jax
+        from jax.sharding import NamedSharding, PartitionSpec
+        where = pred._data.sharding
+        if isinstance(where, NamedSharding):
+            where = NamedSharding(where.mesh, PartitionSpec())
+        self._terms = _picked_log_sum(eps, floor, ignore)(
+            pred._data, jax.device_put(label._data, where))
+        self._terms.copy_to_host_async()
+
+    @classmethod
+    def of(cls, label, pred, eps=0.0, floor=0.0, ignore=None):
+        """The reduction of a dense (label, pred) pair of `NDArray`s with
+        one label a row; None for anything else, which the metric's numpy
+        arithmetic takes."""
+        if type(label) is not NDArray or type(pred) is not NDArray \
+                or pred.ndim < 2 or label.size * pred.shape[-1] != pred.size:
+            return None
+        return cls(label, pred, eps, floor, ignore)
+
+    def read(self):
+        total, count = _np.asarray(self._terms)
+        return float(total), int(count)
+
+
 class EvalMetric:
     def __init__(self, name, output_names=None, label_names=None, **kwargs):
         self.name = str(name)
@@ -120,13 +175,40 @@ class EvalMetric:
             self._fold()    # nothing, unless a loop left its last ones
             self.update(label, pred)
             return
-        self._pending.append((self._batch, _held(label), _held(pred)))
+        self._pending.append((self._batch, *self._hold(label, pred)))
         self._batch += 1
         self._queued += 1
         self._lagged += self._fold(keep=self._lag)
 
     def update(self, labels, preds):
         raise NotImplementedError()
+
+    def _reduced(self, label, pred):
+        """A device-side reduction of one (label, pred) pair that `update`
+        can take in the pair's place (`_PickedLogSum`), or None: the pair
+        itself, for the metric's numpy arithmetic."""
+        return None
+
+    def _terms(self, label, pred):
+        """``pred`` where `_hold` has reduced the pair already, else its
+        reduction now, or None."""
+        return pred if isinstance(pred, _PickedLogSum) \
+            else self._reduced(label, pred)
+
+    def _hold(self, labels, preds):
+        """(labels, preds) as `update_dict` queues them for a later fold
+        through `update`: the arrays as they are now, their copy to the
+        host started; a pair the metric reduces on the device as (None, its
+        reduction), started here, right behind the step that made the
+        outputs."""
+        if len(labels) != len(preds):
+            return _held(labels), _held(preds)
+        kept_labels, kept_preds = [], []
+        for label, pred in zip(labels, preds):
+            terms = self._reduced(label, pred)
+            kept_labels += [None] if terms is not None else _held([label])
+            kept_preds += [terms] if terms is not None else _held([pred])
+        return kept_labels, kept_preds
 
     def _defer(self, lag):
         """`Module.fit`'s: from now on `update_dict` queues its update and
@@ -392,11 +474,21 @@ class Perplexity(EvalMetric):
         self.ignore_label = ignore_label
         self.axis = axis
 
+    def _reduced(self, label, pred):
+        return _PickedLogSum.of(label, pred, floor=1e-10,
+                                ignore=self.ignore_label)
+
     def update(self, labels, preds):
         assert len(labels) == len(preds)
         loss = 0.0
         num = 0
         for label, pred in zip(labels, preds):
+            terms = self._terms(label, pred)
+            if terms is not None:
+                total, count = terms.read()
+                loss += total
+                num += count
+                continue
             label_np = label.asnumpy().astype("int32").reshape(-1)
             pred_np = pred.asnumpy()
             pred_np = pred_np.reshape(-1, pred_np.shape[-1])
@@ -478,9 +570,20 @@ class CrossEntropy(EvalMetric):
                          label_names=label_names)
         self.eps = eps
 
+    def _reduced(self, label, pred):
+        if pred.ndim != 2:
+            return None
+        return _PickedLogSum.of(label, pred, eps=self.eps)
+
     def update(self, labels, preds):
         labels, preds = check_label_shapes(labels, preds)
         for label, pred in zip(labels, preds):
+            terms = self._terms(label, pred)
+            if terms is not None:
+                total, count = terms.read()
+                self.sum_metric += total
+                self.num_inst += count
+                continue
             label_np = label.asnumpy()
             pred_np = pred.asnumpy()
             label_np = label_np.ravel()

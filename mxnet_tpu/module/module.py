@@ -247,9 +247,13 @@ class Module(BaseModule):
         ``ShardingPolicy``, or an option dict — selecting how parameters
         and the batch are laid out over the named mesh. With a
         multi-device ``context`` list the mesh spans those devices;
-        with a single (default) context it spans every local device.
-        Multi-device contexts without ``spmd`` keep the historical
-        replicated data-parallel layout (overridable via ``MXNET_SPMD``)."""
+        with a single (default) context it spans every local device
+        (or the ``devices`` an option dict names). An explicit ``spmd``
+        also lets the fused step write the new params and gradients over
+        the old buffers (donation); on a mesh of one device that is all
+        it does. Multi-device contexts without ``spmd`` keep the
+        historical replicated data-parallel layout (overridable via
+        ``MXNET_SPMD``)."""
         if force_rebind:
             self._exec = None
             self.binded = False
@@ -403,6 +407,17 @@ class Module(BaseModule):
             policy = spmd_mod.resolve(spmd, devices=devices)
         except (TypeError, ValueError) as e:  # bad policy / devices
             raise MXNetError(str(e))
+        if policy.mesh.size == 1:
+            # a mesh of one device (the only local one, or the one an
+            # option dict's ``devices`` names): nothing to lay out, so the
+            # single-device executor it is (a mesh of one would give the
+            # same buffers a second name, `NamedSharding` beside
+            # `SingleDeviceSharding`, and every program that sees both a
+            # retrace). The explicit choice keeps what it unlocks: the step
+            # writes over its params in place
+            self._spmd = None
+            self._spmd_infer = None
+            return None
         self._spmd = policy
         from ..symbol.symbol import _graph_infer
         arg_shapes_d, out_shapes, _ = _graph_infer(
@@ -685,6 +700,12 @@ class Module(BaseModule):
                 self._note_optimizer_bytes(
                     list(self._updater.states.values()))
 
+    def step_program(self):
+        """The `CompiledProgram` of the fused train step (`_step`), for
+        tools that read its lowered or compiled text; None before the first
+        step and where the step falls back to forward_backward + update."""
+        return self._fused_plan[3] if self._fused_plan else None
+
     def _step(self, data_batch):
         """One-dispatch train step: forward + backward + optimizer update in
         a SINGLE jitted XLA program (the reference needs two engine bulk
@@ -720,9 +741,13 @@ class Module(BaseModule):
             # is donated to the step (arg 7), so the old buffers must
             # not be touched once the program runs
             self._note_optimizer_bytes(state_vals)
-            outs, aux_up, new_ws, new_states, grads = step_fn(
-                grad_args, other_args, aux_vals, key, lrs, wds, rescale,
-                state_vals)
+            step_args = (grad_args, other_args, aux_vals, key, lrs, wds,
+                         rescale, state_vals)
+            if self._fused_donates_grads:
+                # the last step's gradients, to be written over in place
+                step_args += ({n: exec_.grad_dict[n]._data
+                               for n in live_names},)
+            outs, aux_up, new_ws, new_states, grads = step_fn(*step_args)
         from .. import xla_stats
         xla_stats.note_train_step(step_fn, batches=1)
         if stepprof.should_sync():
@@ -783,7 +808,7 @@ class Module(BaseModule):
         heads = tuple([None] * n_outs)
 
         def step(grad_args, other_args, aux_vals, key, lrs, wds, rescale,
-                 state_vals):
+                 state_vals, old_grads=None):
             outs, aux_up, grads = exec_._fwd_bwd_impl(
                 grad_args, other_args, aux_vals, key, heads)
             new_ws, new_states = [], []
@@ -796,8 +821,9 @@ class Module(BaseModule):
                 params["rescale_grad"] = rescale
                 g = grads[name].astype(grad_args[name].dtype)
                 out_grads[name] = g
-                upd_outs = fcompute(params, grad_args[name], g,
-                                    *state_vals[k])
+                with jax.named_scope("optimizer"):
+                    upd_outs = fcompute(params, grad_args[name], g,
+                                        *state_vals[k])
                 new_ws.append(upd_outs[0])
                 new_states.append(tuple(upd_outs[1:]))
             # non-param grads (inputs_need_grad) surface too
@@ -823,12 +849,18 @@ class Module(BaseModule):
         spmd_donate = getattr(self, "_spmd_explicit", False) \
             and not self.inputs_need_grad \
             and compiled_mod.spmd_donate_enabled()
-        donate = (0, 7) if spmd_donate else (7,)
+        # ... and the gradients of the step before (arg 8), which the step
+        # rebinds from its outputs like the params: a program that only
+        # writes over them does not use them, so they are kept for it
+        donate = (0, 7, 8) if spmd_donate else (7,)
         donate = compiled_mod.donate_argnums_for(self._context[0], donate)
         step_fn = compiled_mod.tracked_jit(step, "module.fused_step",
                                            donate_argnums=donate,
                                            lineage=id(self),
-                                           policy=self._spmd)
+                                           policy=self._spmd,
+                                           **({"keep_unused": True}
+                                              if 8 in donate else {}))
+        self._fused_donates_grads = 8 in donate
         indices = [self._param_names.index(n) for n in live_names]
         return (live_names, indices, fused, step_fn, step)
 

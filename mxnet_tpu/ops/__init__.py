@@ -17,6 +17,7 @@ from . import optimizer_ops # noqa: F401
 from . import init_ops      # noqa: F401
 from . import linalg_ops    # noqa: F401
 from . import contrib_ops   # noqa: F401
+from . import ssm_ops       # noqa: F401
 from . import detection     # noqa: F401
 from . import quantization_ops  # noqa: F401
 from . import compat_ops    # noqa: F401

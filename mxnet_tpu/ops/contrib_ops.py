@@ -175,7 +175,8 @@ def _roi_pooling(params, data, rois):
 @register("_contrib_flash_attention", aliases=("flash_attention",))
 def _flash_attention_op(params, q, k, v):
     """Fused multi-head attention (Pallas flash kernel on TPU, interpreter
-    elsewhere). Inputs [B, T, H, D]; new capability — the reference has no
+    elsewhere). Inputs [B, T, H, D], k and v with H or a divisor of it
+    (grouped-query); new capability — the reference has no
     attention op (its sequence stack is cudnn_rnn, SURVEY §2.4). Attrs:
     causal (bool), scale (float, default 1/sqrt(D)), block_q/block_k
     (kernel tile sizes)."""
@@ -186,5 +187,6 @@ def _flash_attention_op(params, q, k, v):
     scale = None if scale in (None, "None") else float(scale)
     block_q = int(_attr_num(params, "block_q", 512))
     block_k = int(_attr_num(params, "block_k", 512))
-    return (flash_attention(q, k, v, scale=scale, causal=causal,
-                            block_q=block_q, block_k=block_k),)
+    with jax.named_scope("flash_attention"):
+        return (flash_attention(q, k, v, scale=scale, causal=causal,
+                                block_q=block_q, block_k=block_k),)

@@ -185,13 +185,14 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
     )(q, k, v)
 
 
-def _dense_attention(q, k, v, scale, causal):
-    """Reference math on [BH, T, D]; used for the backward pass."""
+def _dense_attention(q, k, v, scale, causal, q_start=0):
+    """Reference math on [BH, T, D]; used for the backward pass. The
+    queries are positions ``q_start`` onward of the keys' sequence."""
     s = jnp.einsum("bqd,bkd->bqk", q, k,
                    preferred_element_type=jnp.float32) * scale
     if causal:
         tq, tk = q.shape[1], k.shape[1]
-        mask = jnp.arange(tq)[:, None] >= jnp.arange(tk)[None, :]
+        mask = (q_start + jnp.arange(tq))[:, None] >= jnp.arange(tk)[None, :]
         s = jnp.where(mask[None], s, _NEG)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bqk,bkd->bqd", p.astype(q.dtype), v)
@@ -207,12 +208,33 @@ def _flash_vjp_fwd(q, k, v, scale, causal, block_q, block_k):
 
 
 def _flash_vjp_bwd(scale, causal, block_q, block_k, res, g):
-    # backward recomputes attention with the dense math (O(T^2) memory in
-    # the bwd only); a pallas bwd kernel is a later optimisation
+    # backward recomputes attention with the dense math one block of
+    # queries at a time: the peak is a block's scores, [BH, block_q, Tk]
+    # float32, not Tq x Tk for all heads (2.1 GiB at 32 heads of 4096); the
+    # keys' and values' gradients add up over the blocks in float32. A
+    # pallas bwd kernel is a later optimisation
     q, k, v = res
-    _, vjp = jax.vjp(lambda q, k, v: _dense_attention(q, k, v, scale, causal),
-                     q, k, v)
-    return vjp(g)
+    bh, tq, d = q.shape
+    bq = block_q if tq % block_q == 0 else tq
+    nb = tq // bq
+
+    def block(carry, xs):
+        qb, gb, start = xs
+        _, vjp = jax.vjp(
+            lambda qb, k, v: _dense_attention(qb, k, v, scale, causal, start),
+            qb, k, v)
+        dqb, dkb, dvb = vjp(gb)
+        dk, dv = carry
+        return (dk + dkb.astype(jnp.float32),
+                dv + dvb.astype(jnp.float32)), dqb
+
+    blocks = lambda x: jnp.moveaxis(x.reshape(bh, nb, bq, d), 1, 0)
+    (dk, dv), dq = jax.lax.scan(
+        block, (jnp.zeros(k.shape, jnp.float32),
+                jnp.zeros(v.shape, jnp.float32)),
+        (blocks(q), blocks(g), jnp.arange(nb, dtype=jnp.int32) * bq))
+    return (jnp.moveaxis(dq, 0, 1).reshape(bh, tq, d),
+            dk.astype(k.dtype), dv.astype(v.dtype))
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -221,7 +243,8 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 def flash_attention(q, k, v, scale=None, causal=False,
                     block_q=512, block_k=512):
     """Fused attention on [B, T, H, D] (same layout as
-    `parallel.ring_attention`). Differentiable; forward is a Pallas kernel,
+    `parallel.ring_attention`); k and v may have fewer heads, a divisor of
+    q's (grouped-query). Differentiable; forward is a Pallas kernel,
     interpret-mode on CPU.
 
     Block defaults are measured on v5e (T=4096, d=64, causal): 512/512 runs
@@ -231,7 +254,15 @@ def flash_attention(q, k, v, scale=None, causal=False,
     The k axis must stay the innermost sequential grid dim — the streaming
     softmax scratch carries across it."""
     b, tq, h, d = q.shape
-    tk = k.shape[1]
+    tk, hk = k.shape[1], k.shape[2]
+    if hk != h:
+        # grouped-query: each key-value head serves h // hk query heads
+        # (autodiff of the repeat sums their gradients)
+        if h % hk:
+            raise ValueError("%d query heads over %d key-value heads"
+                             % (h, hk))
+        k = jnp.repeat(k, h // hk, axis=2)
+        v = jnp.repeat(v, h // hk, axis=2)
     scale = (1.0 / d ** 0.5) if scale is None else scale
     to_bh = lambda x, t: jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, t, d)
     o = _flash(to_bh(q, tq), to_bh(k, tk), to_bh(v, tk),

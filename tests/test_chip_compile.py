@@ -172,3 +172,43 @@ def test_resnet50_module_train_step_bf16_bs128(one_chip, on_tpu,
     total = mem.argument_size_in_bytes + mem.temp_size_in_bytes \
         + mem.output_size_in_bytes
     assert total < 15 << 30, "the step must fit one v5e chip's 16 GB"
+
+
+def test_mamba2_ssd_scan_at_published_widths(one_chip, on_tpu):
+    """The chunked state-space scan of one Granite-4.0-H Mamba-2 layer at
+    the benchmark's size (4,096 tokens, 64 heads of 64, state 128, chunks
+    of 256), forward and backward in bfloat16: it compiles for the chip
+    and its temporaries stay under 2 GiB (a layer is recomputed beside
+    11.5 GiB of state)."""
+    from mxnet_tpu.ops import ssm_ops
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    spec = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                  sharding=one_chip)
+    args = (spec((1, 4096, 64, 64), bf16), spec((1, 4096, 64), f32),
+            spec((64,), f32), spec((1, 4096, 1, 128), bf16),
+            spec((1, 4096, 1, 128), bf16), spec((64,), f32))
+
+    def loss(*a):
+        return jnp.sum(ssm_ops.mamba2_ssd(*a, 256).astype(f32))
+
+    exe = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5)), *args)
+    assert exe.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
+def test_flash_attention_grouped_backward_in_query_blocks(one_chip, on_tpu):
+    """Granite-4.0-H's attention layer at 4,096 tokens: 32 query heads
+    over 8 key-value heads of 64, the model's own scale. The backward pass
+    goes over query blocks, so its temporaries are a block's scores (32 x
+    512 x 4096 float32, 0.25 GiB, and what autodiff keeps of them) and not
+    T x T for all heads (2 GiB for the scores alone)."""
+    bf16 = jnp.bfloat16
+    spec = lambda heads: jax.ShapeDtypeStruct((1, 4096, heads, 64), bf16,
+                                              sharding=one_chip)
+
+    def loss(q, k, v):
+        return jnp.sum(pk.flash_attention(q, k, v, scale=1 / 64, causal=True)
+                       .astype(jnp.float32))
+
+    exe = _compile(jax.grad(loss, argnums=(0, 1, 2)), spec(32), spec(8),
+                   spec(8))
+    assert exe.memory_analysis().temp_size_in_bytes < 1 << 30

@@ -326,6 +326,13 @@ def test_a_healthy_fit_trips_no_sentinel_whatever_resets_the_metric(
     from mxnet_tpu import runprof, stepprof, telemetry
     monkeypatch.setenv("MXNET_RUNPROF_CHECK_EVERY", "1")
     monkeypatch.setenv("MXNET_RUNPROF_HALT", halt)
+    # the sentinels of the METRIC are what is tested. The step-time spike
+    # detector accuses a step four times the median of the eight before,
+    # and these steps take under a millisecond: on a host that runs five
+    # other test workers one of 24 is that late now and then (the driver's
+    # run of PR 31: [1-0] failed, with K = 4 there are too few dispatches
+    # for it to speak at all)
+    monkeypatch.setenv("MXNET_RUNPROF_SPIKE_FACTOR", "0")
     telemetry.reset(), stepprof.reset(), runprof.reset()
     try:
         seen, snap = _fit_with_sweeps(k)
@@ -420,9 +427,10 @@ def test_share_of_the_updates_that_lagged(reader, monkeypatch, steps, want):
 
 def test_the_manifest_names_the_reader_and_the_cells():
     doc = json.load(open(os.path.join(ROOT, "BENCHMARK.json")))
-    assert doc["per_layer"][-1]["name"] == NAME
-    entry = doc["per_layer"][-1]
+    (entry,) = [m for m in doc["per_layer"] if m["name"] == NAME]
+    # every cell whose entry drives `Module.fit` (all of them so far)
     assert entry["workloads"] == [w["name"] for w in doc["workloads"]]
     assert (entry["moves"], entry["source"], entry["better"], entry["unit"]) \
         == ("train_samples_per_s", "program_span", "higher", "%")
-    assert entry["layer"] in {m["layer"] for m in doc["per_layer"][:-1]}
+    assert entry["layer"] in {m["layer"] for m in doc["per_layer"]
+                              if m is not entry}

@@ -262,6 +262,48 @@ class _FakeAccel:
     device_type = "tpu"
 
 
+@pytest.mark.parametrize("policy", ["data_parallel", "fsdp"])
+def test_a_mesh_of_one_named_device_is_the_single_device_executor(policy):
+    """An option dict's ``devices`` names the mesh whatever else is visible
+    (8 devices here). One device lays nothing out: the module runs the
+    single-device executor (one sharding name for its buffers, no retrace
+    between two `fit`s), and the explicit choice keeps the donation it
+    unlocks; the numbers are the plain fused step's."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+    one = jax.devices()[0]
+    accs, args, mod = _train({"policy": policy, "devices": [one]}, epochs=2)
+    assert mod._spmd is None and mod._spmd_explicit
+    w = mod._exec.arg_dict["fc1_weight"]._data
+    assert isinstance(w.sharding, SingleDeviceSharding)
+    assert w.sharding.device_set == {one}
+    assert mod.step_program().policy is None
+    accs1, args1, _ = _train(None, epochs=2)
+    assert accs == pytest.approx(accs1, abs=1e-6)
+    for name in args1:
+        np.testing.assert_allclose(args[name], args1[name], atol=1e-6)
+
+
+def test_step_program_is_the_fused_step_and_has_its_compiled_text():
+    """`Module.step_program()`: None until a step has run, then the
+    `CompiledProgram` `_step` dispatches, whose `compiled_text()` is the
+    optimized HLO of each executable it holds (what a device trace names
+    its events by)."""
+    X, y = _make_data(n=64)
+    it = mx.io.NDArrayIter(X, y, batch_size=32, label_name="softmax_label")
+    mod = mx.mod.Module(_mlp(), context=mx.cpu())
+    mod.bind(data_shapes=it.provide_data, label_shapes=it.provide_label)
+    mod.init_params()
+    mod.init_optimizer(optimizer="sgd")
+    assert mod.step_program() is None
+    mod._step(next(iter(it)))
+    program = mod.step_program()
+    assert program is mod._fused_plan[3]
+    texts = program.compiled_text()
+    assert len(texts) == 1 and "HloModule" in texts[0]
+    assert re.search(r'op_name="[^"]*optimizer', texts[0])
+
+
 def test_donation_decision(monkeypatch):
     # accelerators donate, CPU backends don't (no donation support)
     assert compiled.donate_argnums_for(_FakeAccel(), (0, 7)) == (0, 7)
