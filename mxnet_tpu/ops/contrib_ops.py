@@ -179,7 +179,7 @@ def _flash_attention_op(params, q, k, v):
     (grouped-query); new capability — the reference has no
     attention op (its sequence stack is cudnn_rnn, SURVEY §2.4). Attrs:
     causal (bool), scale (float, default 1/sqrt(D)), block_q/block_k
-    (kernel tile sizes)."""
+    (the forward kernel's tile sizes)."""
     from .pallas_kernels import flash_attention
     from .nn import _attr_bool, _attr_num
     causal = _attr_bool(params, "causal")

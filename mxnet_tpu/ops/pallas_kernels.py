@@ -7,11 +7,12 @@ reference). On non-TPU backends every kernel runs through the Pallas
 interpreter, so the same code path is testable on CPU.
 
 Design notes (see /opt/skills/guides/pallas_guide.md):
-- flash attention: grid over (batch*heads, q blocks); K/V stay resident in
-  VMEM per (batch, head) and the kernel streams q blocks, accumulating the
-  numerically-stable streaming softmax in f32 registers. Causal mode bounds
-  the inner k-block loop at the diagonal so masked blocks are never
-  computed.
+- flash attention: grid over (batch*heads, q blocks, k blocks), k
+  innermost; the streaming softmax's stats and accumulator live in f32 VMEM
+  scratch, and causal blocks wholly above the diagonal are skipped. The
+  backward is two kernels (dK/dV over q blocks, dQ over k blocks) that
+  rebuild each score block from q, k and the forward's log-sum-exp in
+  VMEM.
 - fused LSTM: the input projection x@Wx for ALL timesteps is one big MXU
   matmul outside the kernel; the kernel walks time on the grid with h/c
   held in VMEM scratch, doing only the recurrent h@Wh matmul per step.
@@ -64,13 +65,34 @@ def _lanes_bcast(x, n):
     return jnp.tile(x, (1, n // _LANES))
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-                      scale, causal, block_q, block_k, seq_k):
+def _clamp(block, t):
+    # a block wider than the sequence is clamped to it, then rounded down
+    # to a lane multiple: the in-kernel lane broadcast only supports widths
+    # that are multiples of 128 (or below one lane group); padding fills
+    # out the final partial block
+    block = min(block, t)
+    if block > _LANES:
+        block = (block // _LANES) * _LANES
+    return block
+
+
+def _pad_to(x, t):
+    """Zero-pad axis 1 of ``x`` to length ``t``."""
+    if x.shape[1] == t:
+        return x
+    pad = [(0, 0)] * x.ndim
+    pad[1] = (0, t - x.shape[1])
+    return jnp.pad(x, pad)
+
+
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
+                      acc_scr, *, scale, causal, block_q, block_k, seq_k):
     """Grid (bh, q blocks, k blocks); k innermost. The streaming-softmax
     stats m/l and the output accumulator live in VMEM scratch (persisted
     across the k sweep) with lane-replicated (block_q, 128) stats — value
     carries of big f32 arrays through fori_loop blow Mosaic's register
-    budget."""
+    budget. Besides the output it writes each row's log-sum-exp
+    ``m + log(l)``, lane-replicated, for the backward."""
     qi = pl.program_id(1)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
@@ -127,32 +149,21 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     @pl.when(ki == nk - 1)
     def _():
         o_ref[0] = _cast(acc_scr[:], o_ref.dtype)
+        lse_ref[0] = m_scr[:] + jnp.log(l_scr[:])
 
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
-    """q,k,v: [BH, T, D] -> [BH, T, D]."""
+    """q,k,v: [BH, T, D] -> (o [BH, T, D], lse [BH, T, 128] float32, each
+    row's log-sum-exp of its scaled scores, lane-replicated)."""
     bh, tq, d = q.shape
     tk = k.shape[1]
-
-    def _clamp(block, t):
-        # a block wider than the sequence is clamped to it, then rounded
-        # down to a lane multiple: the in-kernel lane broadcast only
-        # supports widths that are multiples of 128 (or below one lane
-        # group); padding fills out the final partial block
-        block = min(block, t)
-        if block > _LANES:
-            block = (block // _LANES) * _LANES
-        return block
-
     block_q = _clamp(block_q, tq)
     block_k = _clamp(block_k, tk)
     # pad K/V to a block multiple so every grid block is full-size; the
     # kpos mask neutralises the padded keys
     tk_pad = pl.cdiv(tk, block_k) * block_k
-    if tk_pad != tk:
-        pad = [(0, 0), (0, tk_pad - tk), (0, 0)]
-        k = jnp.pad(k, pad)
-        v = jnp.pad(v, pad)
+    k = _pad_to(k, tk_pad)
+    v = _pad_to(v, tk_pad)
     grid = (bh, pl.cdiv(tq, block_q), tk_pad // block_k)
     kern = functools.partial(
         _flash_fwd_kernel, scale=scale, causal=causal,
@@ -173,9 +184,16 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, i * 0),
                          memory_space=pltpu.VMEM),
         ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, j * 0),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
+        out_specs=[
+            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, j * 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, j * 0),
+                         memory_space=pltpu.VMEM),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, tq, _LANES), jnp.float32),
+        ],
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, _LANES), jnp.float32),
@@ -185,56 +203,223 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
     )(q, k, v)
 
 
-def _dense_attention(q, k, v, scale, causal, q_start=0):
-    """Reference math on [BH, T, D]; used for the backward pass. The
-    queries are positions ``q_start`` onward of the keys' sequence."""
-    s = jnp.einsum("bqd,bkd->bqk", q, k,
-                   preferred_element_type=jnp.float32) * scale
+def _bwd_masked(s, qi, ki, q_axis, *, causal, block_q, block_k, seq_q,
+                seq_k):
+    """Score block ``s`` (queries along ``q_axis``) with the scores that do
+    not count set to _NEG: the K tail padding, the q tail padding and the
+    causal mask, each only where it can exclude something."""
+    iota = lambda axis: jax.lax.broadcasted_iota(jnp.int32, s.shape, axis)
+    qpos = qi * block_q + iota(q_axis)
+    kpos = ki * block_k + iota(1 - q_axis)
+    mask = None
+    for on, cond in ((seq_k % block_k != 0, lambda: kpos < seq_k),
+                     (seq_q % block_q != 0, lambda: qpos < seq_q),
+                     (causal, lambda: qpos >= kpos)):
+        if on:
+            mask = cond() if mask is None else jnp.logical_and(mask, cond())
+    return jnp.where(mask, s, jnp.float32(_NEG))
+
+
+def _bwd_blocks(body, qi, ki, *, causal, block_q, block_k, seq_q, seq_k):
+    """Run ``body(masked)`` on one (q block, k block) pair of the
+    backward: not at all when the block lies wholly above the diagonal,
+    with the mask only where the diagonal or a tail padding crosses it."""
+    q_ragged, k_ragged = seq_q % block_q != 0, seq_k % block_k != 0
+    crossed = []
     if causal:
-        tq, tk = q.shape[1], k.shape[1]
-        mask = (q_start + jnp.arange(tq))[:, None] >= jnp.arange(tk)[None, :]
-        s = jnp.where(mask[None], s, _NEG)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bqk,bkd->bqd", p.astype(q.dtype), v)
+        crossed.append(qi * block_q < ki * block_k + (block_k - 1))
+    if q_ragged:
+        crossed.append(qi * block_q + block_q > seq_q)
+    if k_ragged:
+        crossed.append(ki * block_k + block_k > seq_k)
+    run = (qi * block_q + (block_q - 1) >= ki * block_k) if causal else True
+    if not crossed:
+        pl.when(run)(lambda: body(False))
+        return
+    edge = functools.reduce(jnp.logical_or, crossed)
+    pl.when(jnp.logical_and(run, edge))(lambda: body(True))
+    pl.when(jnp.logical_and(run, jnp.logical_not(edge)))(
+        lambda: body(False))
+
+
+def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      dk_ref, dv_ref, dk_scr, dv_scr, *, scale, **kw):
+    """Grid (bh, k blocks, q blocks); q innermost, dK and dV accumulate in
+    f32 VMEM scratch across the q sweep. The block works on TRANSPOSED
+    scores (block_k, block_q), so each query's log-sum-exp and delta come
+    as one (1, block_q) row and both products are plain ones:
+    dV += P^T dO, dK += dS^T q."""
+    ki = pl.program_id(1)
+    qi = pl.program_id(2)
+    nq = pl.num_programs(2)
+
+    @pl.when(qi == 0)
+    def _():
+        dk_scr[:] = jnp.zeros(dk_scr.shape, jnp.float32)
+        dv_scr[:] = jnp.zeros(dv_scr.shape, jnp.float32)
+
+    def body(masked):
+        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+        st = jax.lax.dot_general(
+            k, q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * jnp.float32(scale)
+        if masked:
+            st = _bwd_masked(st, qi, ki, 1, **kw)
+        pt = jnp.exp(st - lse_ref[0])                     # (bk, bq)
+        dv_scr[:] += jnp.dot(_cast(pt, do.dtype), do,
+                             preferred_element_type=jnp.float32)
+        dpt = jax.lax.dot_general(
+            v, do, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)           # (bk, bq)
+        dst = pt * (dpt - delta_ref[0])
+        dk_scr[:] += jnp.dot(_cast(dst, q.dtype), q,
+                             preferred_element_type=jnp.float32)
+
+    _bwd_blocks(body, qi, ki, **kw)
+
+    @pl.when(qi == nq - 1)
+    def _():
+        dk_ref[0] = _cast(dk_scr[:] * jnp.float32(scale), dk_ref.dtype)
+        dv_ref[0] = _cast(dv_scr[:], dv_ref.dtype)
+
+
+def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
+                     dq_scr, delta_scr, *, scale, **kw):
+    """Grid (bh, q blocks, k blocks); k innermost, dQ accumulates in f32
+    VMEM scratch across the k sweep. lse comes lane-replicated,
+    (block_q, 128), as the forward keeps its stats; the block's delta is
+    formed here, in the same layout, at the sweep's first step."""
+    qi = pl.program_id(1)
+    ki = pl.program_id(2)
+    nk = pl.num_programs(2)
+
+    @pl.when(ki == 0)
+    def _():
+        dq_scr[:] = jnp.zeros(dq_scr.shape, jnp.float32)
+        delta = jnp.sum(_cast(do_ref[0], jnp.float32)
+                        * _cast(o_ref[0], jnp.float32), axis=1)
+        delta_scr[:] = jnp.broadcast_to(delta[:, None], delta_scr.shape)
+
+    def body(masked):
+        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * jnp.float32(scale)
+        if masked:
+            s = _bwd_masked(s, qi, ki, 0, **kw)
+        p = jnp.exp(s - _lanes_bcast(lse_ref[0], s.shape[1]))  # (bq, bk)
+        dp = jax.lax.dot_general(
+            do, v, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        ds = p * (dp - _lanes_bcast(delta_scr[:], s.shape[1]))
+        dq_scr[:] += jnp.dot(_cast(ds, k.dtype), k,
+                             preferred_element_type=jnp.float32)
+
+    _bwd_blocks(body, qi, ki, **kw)
+
+    @pl.when(ki == nk - 1)
+    def _():
+        dq_ref[0] = _cast(dq_scr[:] * jnp.float32(scale), dq_ref.dtype)
+
+
+# The backward's blocks, whatever the forward's: measured on the v5e at
+# [32, 4096, 64] bf16 causal (docs/architecture/note_kernels.md), 1024 x 512
+# ran 4.56 ms, 512 x 512 4.72, 256 x 512 6.10; 1024 x 1024 (4.35) runs out
+# of VMEM at a head width of 256 in float32, 1024 x 512 fits every width
+# up to 256 in either dtype
+_BWD_BLOCK_Q, _BWD_BLOCK_K = 1024, 512
+
+
+def _flash_bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k):
+    """Gradients of `_flash_fwd`'s output: q,k,v,o,do [BH, T, D], lse its
+    [BH, Tq, 128] -> (dq, dk, dv). Two kernels recompute each score block
+    from q, k and lse and keep it in VMEM: one sweeps q blocks for a k
+    block's dK and dV, one sweeps k blocks for a q block's dQ. Under
+    ``causal`` a block wholly above the diagonal is skipped, and its index
+    map names the block the sweep runs next to it, so no DMA is spent on
+    it. P and dS enter the products in the operands' dtype; the softmax
+    algebra and the accumulators are float32."""
+    bh, tq, d = q.shape
+    tk = k.shape[1]
+    block_q = _clamp(block_q, tq)
+    block_k = _clamp(block_k, tk)
+    nq, nk = pl.cdiv(tq, block_q), pl.cdiv(tk, block_k)
+    # delta_i = sum_j P_ij dP_ij = dO_i . o_i, the softmax VJP's row term;
+    # the dK/dV kernel takes it as rows from here, the dQ kernel forms it
+    # from its own o and dO blocks (no lane-replicated copy in HBM)
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    q, do, o = (_pad_to(x, nq * block_q) for x in (q, do, o))
+    k, v = _pad_to(k, nk * block_k), _pad_to(v, nk * block_k)
+    lse, delta = _pad_to(lse, nq * block_q), _pad_to(delta, nq * block_q)
+    kw = dict(scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+              seq_q=tq, seq_k=tk)
+
+    def vmem(shape, index):
+        return pl.BlockSpec(shape, index, memory_space=pltpu.VMEM)
+
+    # index maps stay in i32 lax ops: jnp's min, max and floor division
+    # wrap an identity convert that Mosaic's lowering recurses on, and a
+    # Python int beside an i32 index is i64 here (see `_flash_fwd`)
+    const = lambda x, n: x * 0 + n
+    div = lambda x, n: jax.lax.div(x, const(x, n))
+
+    # dK/dV: grid (b, j, i). A q block above the diagonal comes before the
+    # first one that runs for k block j, so it names that one
+    if causal:
+        q_at = lambda j, i: jax.lax.min(
+            jax.lax.max(i, div(j * block_k, block_q)), const(i, nq - 1))
+    else:
+        q_at = lambda j, i: i
+    q_spec = vmem((1, block_q, d), lambda b, j, i: (b, q_at(j, i), i * 0))
+    k_spec = vmem((1, block_k, d), lambda b, j, i: (b, j, i * 0))
+    row_spec = vmem((1, 1, block_q), lambda b, j, i: (b, i * 0, q_at(j, i)))
+    dk, dv = pl.pallas_call(
+        functools.partial(_flash_dkv_kernel, **kw),
+        grid=(bh, nk, nq),
+        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+        out_specs=[k_spec, k_spec],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                        pltpu.VMEM((block_k, d), jnp.float32)],
+        interpret=_interpret(),
+    )(q, k, v, do, lse[:, None, :, 0], delta[:, None, :])
+
+    # dQ: grid (b, i, j). A k block above the diagonal comes after the
+    # last one that runs for q block i, so it names that one
+    if causal:
+        k_at = lambda i, j: jax.lax.min(
+            j, div(i * block_q + (block_q - 1), block_k))
+    else:
+        k_at = lambda i, j: j
+    q_spec = vmem((1, block_q, d), lambda b, i, j: (b, i, j * 0))
+    k_spec = vmem((1, block_k, d), lambda b, i, j: (b, k_at(i, j), j * 0))
+    lane_spec = vmem((1, block_q, _LANES), lambda b, i, j: (b, i, j * 0))
+    dq = pl.pallas_call(
+        functools.partial(_flash_dq_kernel, **kw),
+        grid=(bh, nq, nk),
+        in_specs=[q_spec, k_spec, k_spec, q_spec, q_spec, lane_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
+                        pltpu.VMEM((block_q, _LANES), jnp.float32)],
+        interpret=_interpret(),
+    )(q, k, v, do, o, lse)
+    return dq[:, :tq], dk[:, :tk], dv[:, :tk]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash(q, k, v, scale, causal, block_q, block_k):
-    return _flash_fwd(q, k, v, scale, causal, block_q, block_k)
+    return _flash_fwd(q, k, v, scale, causal, block_q, block_k)[0]
 
 
 def _flash_vjp_fwd(q, k, v, scale, causal, block_q, block_k):
-    return _flash_fwd(q, k, v, scale, causal, block_q, block_k), (q, k, v)
+    o, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k)
+    return o, (q, k, v, o, lse)
 
 
 def _flash_vjp_bwd(scale, causal, block_q, block_k, res, g):
-    # backward recomputes attention with the dense math one block of
-    # queries at a time: the peak is a block's scores, [BH, block_q, Tk]
-    # float32, not Tq x Tk for all heads (2.1 GiB at 32 heads of 4096); the
-    # keys' and values' gradients add up over the blocks in float32. A
-    # pallas bwd kernel is a later optimisation
-    q, k, v = res
-    bh, tq, d = q.shape
-    bq = block_q if tq % block_q == 0 else tq
-    nb = tq // bq
-
-    def block(carry, xs):
-        qb, gb, start = xs
-        _, vjp = jax.vjp(
-            lambda qb, k, v: _dense_attention(qb, k, v, scale, causal, start),
-            qb, k, v)
-        dqb, dkb, dvb = vjp(gb)
-        dk, dv = carry
-        return (dk + dkb.astype(jnp.float32),
-                dv + dvb.astype(jnp.float32)), dqb
-
-    blocks = lambda x: jnp.moveaxis(x.reshape(bh, nb, bq, d), 1, 0)
-    (dk, dv), dq = jax.lax.scan(
-        block, (jnp.zeros(k.shape, jnp.float32),
-                jnp.zeros(v.shape, jnp.float32)),
-        (blocks(q), blocks(g), jnp.arange(nb, dtype=jnp.int32) * bq))
-    return (jnp.moveaxis(dq, 0, 1).reshape(bh, tq, d),
-            dk.astype(k.dtype), dv.astype(v.dtype))
+    return _flash_bwd(*res, g, scale, causal, _BWD_BLOCK_Q, _BWD_BLOCK_K)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
@@ -244,15 +429,14 @@ def flash_attention(q, k, v, scale=None, causal=False,
                     block_q=512, block_k=512):
     """Fused attention on [B, T, H, D] (same layout as
     `parallel.ring_attention`); k and v may have fewer heads, a divisor of
-    q's (grouped-query). Differentiable; forward is a Pallas kernel,
-    interpret-mode on CPU.
+    q's (grouped-query). Differentiable: the forward and the backward are
+    Pallas kernels, interpret-mode on CPU.
 
-    Block defaults are measured on v5e (T=4096, d=64, causal): 512/512 runs
-    ~12x faster than 128/128 (grid-invocation overhead dominates small
-    blocks) and ~6x faster than XLA's dense attention, while the s-block
-    (block_q x block_k f32 = 1MB) keeps ample VMEM headroom up to d=128.
-    The k axis must stay the innermost sequential grid dim — the streaming
-    softmax scratch carries across it."""
+    ``block_q`` and ``block_k`` tile the forward; the backward's tiles are
+    `_BWD_BLOCK_Q` x `_BWD_BLOCK_K`. Both were measured on the v5e
+    (docs/architecture/note_kernels.md). The k axis must stay the
+    innermost sequential grid dim — the streaming softmax scratch carries
+    across it."""
     b, tq, h, d = q.shape
     tk, hk = k.shape[1], k.shape[2]
     if hk != h:

@@ -195,20 +195,29 @@ def test_mamba2_ssd_scan_at_published_widths(one_chip, on_tpu):
     assert exe.memory_analysis().temp_size_in_bytes < 2 << 30
 
 
-def test_flash_attention_grouped_backward_in_query_blocks(one_chip, on_tpu):
+def test_flash_attention_grouped_backward_kernels(one_chip, on_tpu):
     """Granite-4.0-H's attention layer at 4,096 tokens: 32 query heads
-    over 8 key-value heads of 64, the model's own scale. The backward pass
-    goes over query blocks, so its temporaries are a block's scores (32 x
-    512 x 4096 float32, 0.25 GiB, and what autodiff keeps of them) and not
-    T x T for all heads (2 GiB for the scores alone)."""
+    over 8 key-value heads of 64, the model's own scale. The gradient is
+    three kernels, the forward and the backward's dK/dV and dQ, each under
+    the scope it was built in (what `attn.device_ms` reads them by); the
+    scores never leave them, so the temporaries are the residuals and the
+    gradients ([32, 4096, 128] float32 log-sum-exp and [32, 4096, 64]
+    arrays), not a block's scores (32 x 512 x 4096 float32, 0.25 GiB)."""
+    import re
     bf16 = jnp.bfloat16
     spec = lambda heads: jax.ShapeDtypeStruct((1, 4096, heads, 64), bf16,
                                               sharding=one_chip)
 
     def loss(q, k, v):
-        return jnp.sum(pk.flash_attention(q, k, v, scale=1 / 64, causal=True)
-                       .astype(jnp.float32))
+        with jax.named_scope("attention"):
+            o = pk.flash_attention(q, k, v, scale=1 / 64, causal=True)
+        return jnp.sum(o.astype(jnp.float32))
 
     exe = _compile(jax.grad(loss, argnums=(0, 1, 2)), spec(32), spec(8),
                    spec(8))
-    assert exe.memory_analysis().temp_size_in_bytes < 1 << 30
+    calls = [line for line in exe.as_text().splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 3
+    for line in calls:
+        assert re.search(r'op_name="([^"]*[/(])?attention[/)"]', line)
+    assert exe.memory_analysis().temp_size_in_bytes < 256 << 20

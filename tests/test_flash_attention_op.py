@@ -64,13 +64,29 @@ def _grads(fn, q, k, v, w):
                     argnums=(0, 1, 2))(q, k, v)
 
 
+def _dense_attention(q, k, v, scale, causal):
+    """The dense math on [BH, T, D], scores of all keys at once: the
+    oracle of the kernels' output and, through autodiff, their
+    gradients."""
+    import jax
+    import jax.numpy as jnp
+    s = jnp.einsum("bqd,bkd->bqk", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    if causal:
+        tq, tk = q.shape[1], k.shape[1]
+        mask = jnp.arange(tq)[:, None] >= jnp.arange(tk)[None, :]
+        s = jnp.where(mask[None], s, -1e30)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bqk,bkd->bqd", p.astype(q.dtype), v)
+
+
 @pytest.mark.parametrize("heads_kv", [4, 2, 1])
 def test_grouped_query_matches_repeated_heads(heads_kv):
     """k and v with a divisor of q's heads: the output and all three
     gradients are those of the heads repeated by hand, at the model's own
     scale (not 1/sqrt(D))."""
     import jax.numpy as jnp
-    from mxnet_tpu.ops.pallas_kernels import flash_attention, _dense_attention
+    from mxnet_tpu.ops.pallas_kernels import flash_attention
     rng = np.random.RandomState(3)
     q = jnp.asarray(rng.randn(2, 16, 4, 8), jnp.float32)
     k = jnp.asarray(rng.randn(2, 16, heads_kv, 8), jnp.float32)
@@ -93,27 +109,49 @@ def test_grouped_query_matches_repeated_heads(heads_kv):
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_long_sequence_backward_goes_over_query_blocks(causal):
-    """The backward works a block of queries at a time: the same gradients
-    as the dense math, and no [T, T] array of scores in its program."""
+@pytest.mark.parametrize("causal,heads_kv,t,scale", [
+    (False, 4, 256, None),
+    (True, 4, 256, None),
+    (True, 2, 256, 1 / 64),
+    (True, 1, 256, 1 / 64),
+    (True, 2, 200, 1 / 64),
+    (False, 1, 200, None),
+], ids=["dense", "causal", "grouped2-model-scale", "grouped1-model-scale",
+        "ragged-causal", "ragged-dense-grouped1"])
+def test_backward_kernels_match_dense_math(monkeypatch, causal, heads_kv, t,
+                                           scale):
+    """The backward's two kernels, over many blocks of queries and keys
+    (32 a block here, forward and backward): the gradients of the dense
+    math, causal or not, over 4, 2 or 1 key-value heads, at a length that
+    is not a block multiple and at the model's own scale. Scores stay
+    inside the kernels: the grad's program holds no [T, T] and no
+    [block, T] float32 array."""
+    import re
     import jax
     import jax.numpy as jnp
-    from mxnet_tpu.ops.pallas_kernels import flash_attention, _dense_attention
+    from mxnet_tpu.ops import pallas_kernels as pk
+    blk, heads = 32, 4
+    monkeypatch.setattr(pk, "_BWD_BLOCK_Q", blk)
+    monkeypatch.setattr(pk, "_BWD_BLOCK_K", blk)
     rng = np.random.RandomState(4)
-    t, blk = 256, 32
-    q, k, v, w = (jnp.asarray(rng.randn(1, t, 2, 8), jnp.float32)
-                  for _ in range(4))
-    bh = lambda x: jnp.transpose(x, (0, 2, 1, 3)).reshape(2, t, 8)
+    q, w = (jnp.asarray(rng.randn(1, t, heads, 8), jnp.float32)
+            for _ in range(2))
+    k, v = (jnp.asarray(rng.randn(1, t, heads_kv, 8), jnp.float32)
+            for _ in range(2))
+    bh = lambda x: jnp.transpose(x, (0, 2, 1, 3)).reshape(heads, t, 8)
 
     def dense(q, k, v):
-        o = _dense_attention(bh(q), bh(k), bh(v), 8 ** -0.5, causal)
-        return jnp.transpose(o.reshape(1, 2, t, 8), (0, 2, 1, 3))
+        rep = heads // heads_kv
+        kk, vv = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+        o = _dense_attention(bh(q), bh(kk), bh(vv),
+                             8 ** -0.5 if scale is None else scale, causal)
+        return jnp.transpose(o.reshape(1, heads, t, 8), (0, 2, 1, 3))
 
-    flash = lambda q, k, v: flash_attention(q, k, v, causal=causal,
-                                            block_q=blk, block_k=blk)
+    flash = lambda q, k, v: pk.flash_attention(
+        q, k, v, scale=scale, causal=causal, block_q=blk, block_k=blk)
     for got, want in zip(_grads(flash, q, k, v, w), _grads(dense, q, k, v, w)):
+        assert got.shape == want.shape
         np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
     text = jax.jit(lambda *a: _grads(flash, *a)).lower(q, k, v, w).as_text()
-    assert "%dx%dxf32" % (t, t) not in text
-    assert "%dx%dxf32" % (blk, t) in text
+    for rows in (t, blk):
+        assert not re.search(r"(?<!\d)%dx%dxf32" % (rows, t), text), rows

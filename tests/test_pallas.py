@@ -37,8 +37,13 @@ def test_flash_attention_ragged_blocks():
                                rtol=2e-5, atol=2e-5)
 
 
-def test_flash_attention_grad():
-    q, k, v = _qkv(t=32)
+@pytest.mark.parametrize("t", [32, 40], ids=["blocks", "ragged"])
+def test_flash_attention_grad(monkeypatch, t):
+    # the backward's kernels over several 16-wide blocks each way
+    from mxnet_tpu.ops import pallas_kernels as pk
+    monkeypatch.setattr(pk, "_BWD_BLOCK_Q", 16)
+    monkeypatch.setattr(pk, "_BWD_BLOCK_K", 16)
+    q, k, v = _qkv(t=t)
     f = lambda q, k, v: jnp.sum(
         flash_attention(q, k, v, causal=True, block_q=16, block_k=16) ** 2)
     fd = lambda q, k, v: jnp.sum(local_attention(q, k, v, causal=True) ** 2)
@@ -47,6 +52,28 @@ def test_flash_attention_grad():
     for a, b in zip(g, gw):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_flash_forward_log_sum_exp(causal):
+    """The forward's second output, the backward's residual: each row's
+    log-sum-exp of its scaled, masked scores, the same in all 128 lanes;
+    the attention output is what the one-output forward gives."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+    rng = np.random.RandomState(5)
+    q, k, v = (jnp.asarray(rng.randn(3, 40, 8), jnp.float32)
+               for _ in range(3))
+    o, lse = pk._flash_fwd(q, k, v, 0.3, causal, 16, 16)
+    s = jnp.einsum("bqd,bkd->bqk", q, k) * 0.3
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones((40, 40), bool))[None], s, -1e30)
+    assert lse.shape == (3, 40, 128)
+    np.testing.assert_allclose(np.asarray(lse),
+                               np.asarray(jnp.broadcast_to(
+                                   jax.nn.logsumexp(s, axis=-1)[..., None],
+                                   lse.shape)), rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(
+        np.asarray(o), np.asarray(pk._flash(q, k, v, 0.3, causal, 16, 16)))
 
 
 def _lstm_ref(x, h0, c0, wx, wh, b):
