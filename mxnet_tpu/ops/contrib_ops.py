@@ -172,6 +172,30 @@ def _roi_pooling(params, data, rois):
     return (out.astype(data.dtype),)
 
 
+def rope(x, theta):
+    """Rotary position term on [B, T, H, D], rotate-half over all of D:
+    position t turns the pair (x_i, x_(i + D/2)) by the angle
+    ``t * theta^(-2i/D)``. Positions count from 0 at a row's start; the
+    angles and the turn are float32, the result takes x's type."""
+    t, d = x.shape[1], x.shape[-1]
+    inv = jnp.asarray(theta ** (-np.arange(0, d, 2, dtype=np.float64) / d),
+                      jnp.float32)
+    angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None]
+    cos = jnp.tile(jnp.cos(angle), (1, 2))[None, :, None]
+    sin = jnp.tile(jnp.sin(angle), (1, 2))[None, :, None]
+    x32 = x.astype(jnp.float32)
+    half = jnp.concatenate([-x32[..., d // 2:], x32[..., :d // 2]], axis=-1)
+    return (x32 * cos + half * sin).astype(x.dtype)
+
+
+@register("rope")
+def _rope(params, x):
+    """See `rope`; attr theta (10000)."""
+    from .nn import _attr_num
+    with jax.named_scope("rope"):
+        return (rope(x, _attr_num(params, "theta", 10000.0)),)
+
+
 @register("_contrib_flash_attention", aliases=("flash_attention",))
 def _flash_attention_op(params, q, k, v):
     """Fused multi-head attention (Pallas flash kernel on TPU, interpreter
@@ -179,14 +203,18 @@ def _flash_attention_op(params, q, k, v):
     (grouped-query); new capability — the reference has no
     attention op (its sequence stack is cudnn_rnn, SURVEY §2.4). Attrs:
     causal (bool), scale (float, default 1/sqrt(D)), block_q/block_k
-    (the forward kernel's tile sizes)."""
-    from .pallas_kernels import flash_attention
+    (the forward kernel's tile sizes), window (int, with causal: query i
+    sees keys i - window < j <= i; none by default)."""
+    from . import pallas_kernels
     from .nn import _attr_bool, _attr_num
     causal = _attr_bool(params, "causal")
     scale = params.get("scale")
     scale = None if scale in (None, "None") else float(scale)
+    window = params.get("window")
+    window = None if window in (None, "None") else int(window)
     block_q = int(_attr_num(params, "block_q", 512))
     block_k = int(_attr_num(params, "block_k", 512))
     with jax.named_scope("flash_attention"):
-        return (flash_attention(q, k, v, scale=scale, causal=causal,
-                                block_q=block_q, block_k=block_k),)
+        return (pallas_kernels.flash_attention(
+            q, k, v, scale=scale, causal=causal, block_q=block_q,
+            block_k=block_k, window=window),)

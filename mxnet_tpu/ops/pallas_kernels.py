@@ -9,10 +9,10 @@ interpreter, so the same code path is testable on CPU.
 Design notes (see /opt/skills/guides/pallas_guide.md):
 - flash attention: grid over (batch*heads, q blocks, k blocks), k
   innermost; the streaming softmax's stats and accumulator live in f32 VMEM
-  scratch, and causal blocks wholly above the diagonal are skipped. The
-  backward is two kernels (dK/dV over q blocks, dQ over k blocks) that
-  rebuild each score block from q, k and the forward's log-sum-exp in
-  VMEM.
+  scratch, and causal blocks wholly above the diagonal (or wholly below a
+  sliding window) are skipped. The backward is two kernels (dK/dV over q
+  blocks, dQ over k blocks) that rebuild each score block from q, k and
+  the forward's log-sum-exp in VMEM.
 - fused LSTM: the input projection x@Wx for ALL timesteps is one big MXU
   matmul outside the kernel; the kernel walks time on the grid with h/c
   held in VMEM scratch, doing only the recurrent h@Wh matmul per step.
@@ -86,7 +86,8 @@ def _pad_to(x, t):
 
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
-                      acc_scr, *, scale, causal, block_q, block_k, seq_k):
+                      acc_scr, *, scale, causal, window, block_q, block_k,
+                      seq_k):
     """Grid (bh, q blocks, k blocks); k innermost. The streaming-softmax
     stats m/l and the output accumulator live in VMEM scratch (persisted
     across the k sweep) with lane-replicated (block_q, 128) stats — value
@@ -105,11 +106,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    if causal:
-        # skip blocks strictly above the diagonal
-        run = qi * block_q + (block_q - 1) >= ki * block_k
-    else:
-        run = True
+    run = _block_runs(qi, ki, causal=causal, window=window, block_q=block_q,
+                      block_k=block_k)
 
     @pl.when(run)
     def _():
@@ -126,6 +124,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
             qpos = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
             mask = jnp.logical_and(mask, qpos >= kpos)
+        if window is not None:
+            mask = jnp.logical_and(mask, qpos - kpos < window)
         s = jnp.where(mask, s, neg)
 
         m_prev = m_scr[:]                                 # (bq, 128)
@@ -152,7 +152,20 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         lse_ref[0] = m_scr[:] + jnp.log(l_scr[:])
 
 
-def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
+def _block_runs(qi, ki, *, causal, window, block_q, block_k):
+    """Whether the (q block, k block) pair holds a score that counts:
+    not wholly above the diagonal (``causal``) and not wholly below the
+    sliding window (query i sees keys j with i - window < j <= i)."""
+    run = True
+    if causal:
+        run = qi * block_q + (block_q - 1) >= ki * block_k
+    if window is not None:
+        run = jnp.logical_and(
+            run, qi * block_q - (ki * block_k + (block_k - 1)) < window)
+    return run
+
+
+def _flash_fwd(q, k, v, scale, causal, block_q, block_k, window=None):
     """q,k,v: [BH, T, D] -> (o [BH, T, D], lse [BH, T, 128] float32, each
     row's log-sum-exp of its scaled scores, lane-replicated)."""
     bh, tq, d = q.shape
@@ -166,7 +179,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
     v = _pad_to(v, tk_pad)
     grid = (bh, pl.cdiv(tq, block_q), tk_pad // block_k)
     kern = functools.partial(
-        _flash_fwd_kernel, scale=scale, causal=causal,
+        _flash_fwd_kernel, scale=scale, causal=causal, window=window,
         block_q=block_q, block_k=block_k, seq_k=tk)
     return pl.pallas_call(
         kern,
@@ -203,27 +216,31 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k):
     )(q, k, v)
 
 
-def _bwd_masked(s, qi, ki, q_axis, *, causal, block_q, block_k, seq_q,
-                seq_k):
+def _bwd_masked(s, qi, ki, q_axis, *, causal, window, block_q, block_k,
+                seq_q, seq_k):
     """Score block ``s`` (queries along ``q_axis``) with the scores that do
-    not count set to _NEG: the K tail padding, the q tail padding and the
-    causal mask, each only where it can exclude something."""
+    not count set to _NEG: the K tail padding, the q tail padding, the
+    causal mask and the sliding window, each only where it can exclude
+    something."""
     iota = lambda axis: jax.lax.broadcasted_iota(jnp.int32, s.shape, axis)
     qpos = qi * block_q + iota(q_axis)
     kpos = ki * block_k + iota(1 - q_axis)
     mask = None
     for on, cond in ((seq_k % block_k != 0, lambda: kpos < seq_k),
                      (seq_q % block_q != 0, lambda: qpos < seq_q),
-                     (causal, lambda: qpos >= kpos)):
+                     (causal, lambda: qpos >= kpos),
+                     (window is not None, lambda: qpos - kpos < window)):
         if on:
             mask = cond() if mask is None else jnp.logical_and(mask, cond())
     return jnp.where(mask, s, jnp.float32(_NEG))
 
 
-def _bwd_blocks(body, qi, ki, *, causal, block_q, block_k, seq_q, seq_k):
+def _bwd_blocks(body, qi, ki, *, causal, window, block_q, block_k, seq_q,
+                seq_k):
     """Run ``body(masked)`` on one (q block, k block) pair of the
-    backward: not at all when the block lies wholly above the diagonal,
-    with the mask only where the diagonal or a tail padding crosses it."""
+    backward: not at all when the block lies wholly above the diagonal or
+    below the window, with the mask only where the diagonal, the window's
+    edge or a tail padding crosses it."""
     q_ragged, k_ragged = seq_q % block_q != 0, seq_k % block_k != 0
     crossed = []
     if causal:
@@ -232,7 +249,10 @@ def _bwd_blocks(body, qi, ki, *, causal, block_q, block_k, seq_q, seq_k):
         crossed.append(qi * block_q + block_q > seq_q)
     if k_ragged:
         crossed.append(ki * block_k + block_k > seq_k)
-    run = (qi * block_q + (block_q - 1) >= ki * block_k) if causal else True
+    if window is not None:
+        crossed.append(qi * block_q + (block_q - 1) - ki * block_k >= window)
+    run = _block_runs(qi, ki, causal=causal, window=window, block_q=block_q,
+                      block_k=block_k)
     if not crossed:
         pl.when(run)(lambda: body(False))
         return
@@ -330,15 +350,17 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
 _BWD_BLOCK_Q, _BWD_BLOCK_K = 1024, 512
 
 
-def _flash_bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k):
+def _flash_bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
+               window=None):
     """Gradients of `_flash_fwd`'s output: q,k,v,o,do [BH, T, D], lse its
     [BH, Tq, 128] -> (dq, dk, dv). Two kernels recompute each score block
     from q, k and lse and keep it in VMEM: one sweeps q blocks for a k
     block's dK and dV, one sweeps k blocks for a q block's dQ. Under
-    ``causal`` a block wholly above the diagonal is skipped, and its index
-    map names the block the sweep runs next to it, so no DMA is spent on
-    it. P and dS enter the products in the operands' dtype; the softmax
-    algebra and the accumulators are float32."""
+    ``causal`` a block wholly above the diagonal, and under ``window`` one
+    wholly below the window, is skipped, and its index map names the block
+    the sweep runs next to it, so no DMA is spent on it. P and dS enter
+    the products in the operands' dtype; the softmax algebra and the
+    accumulators are float32."""
     bh, tq, d = q.shape
     tk = k.shape[1]
     block_q = _clamp(block_q, tq)
@@ -351,8 +373,8 @@ def _flash_bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k):
     q, do, o = (_pad_to(x, nq * block_q) for x in (q, do, o))
     k, v = _pad_to(k, nk * block_k), _pad_to(v, nk * block_k)
     lse, delta = _pad_to(lse, nq * block_q), _pad_to(delta, nq * block_q)
-    kw = dict(scale=scale, causal=causal, block_q=block_q, block_k=block_k,
-              seq_q=tq, seq_k=tk)
+    kw = dict(scale=scale, causal=causal, window=window, block_q=block_q,
+              block_k=block_k, seq_q=tq, seq_k=tk)
 
     def vmem(shape, index):
         return pl.BlockSpec(shape, index, memory_space=pltpu.VMEM)
@@ -364,10 +386,16 @@ def _flash_bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k):
     div = lambda x, n: jax.lax.div(x, const(x, n))
 
     # dK/dV: grid (b, j, i). A q block above the diagonal comes before the
-    # first one that runs for k block j, so it names that one
+    # first one that runs for k block j, so it names that one; one below
+    # the window comes after the last, and names that one
     if causal:
+        last = lambda j, i: const(i, nq - 1)
+        if window is not None:
+            last = lambda j, i: jax.lax.min(
+                div(j * block_k + (block_k + window - 2), block_q),
+                const(i, nq - 1))
         q_at = lambda j, i: jax.lax.min(
-            jax.lax.max(i, div(j * block_k, block_q)), const(i, nq - 1))
+            jax.lax.max(i, div(j * block_k, block_q)), last(j, i))
     else:
         q_at = lambda j, i: i
     q_spec = vmem((1, block_q, d), lambda b, j, i: (b, q_at(j, i), i * 0))
@@ -386,10 +414,15 @@ def _flash_bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k):
     )(q, k, v, do, lse[:, None, :, 0], delta[:, None, :])
 
     # dQ: grid (b, i, j). A k block above the diagonal comes after the
-    # last one that runs for q block i, so it names that one
+    # last one that runs for q block i, so it names that one; one below the
+    # window comes before the first, and names that one
     if causal:
         k_at = lambda i, j: jax.lax.min(
             j, div(i * block_q + (block_q - 1), block_k))
+        if window is not None:
+            upto = k_at
+            k_at = lambda i, j: jax.lax.max(upto(i, j), div(jax.lax.max(
+                i * block_q - (window - 1), const(i, 0)), block_k))
     else:
         k_at = lambda i, j: j
     q_spec = vmem((1, block_q, d), lambda b, i, j: (b, i, j * 0))
@@ -408,29 +441,34 @@ def _flash_bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k):
     return dq[:, :tq], dk[:, :tk], dv[:, :tk]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, scale, causal, block_q, block_k):
-    return _flash_fwd(q, k, v, scale, causal, block_q, block_k)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, scale, causal, block_q, block_k, window=None):
+    return _flash_fwd(q, k, v, scale, causal, block_q, block_k, window)[0]
 
 
-def _flash_vjp_fwd(q, k, v, scale, causal, block_q, block_k):
-    o, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k)
+def _flash_vjp_fwd(q, k, v, scale, causal, block_q, block_k, window):
+    o, lse = _flash_fwd(q, k, v, scale, causal, block_q, block_k, window)
     return o, (q, k, v, o, lse)
 
 
-def _flash_vjp_bwd(scale, causal, block_q, block_k, res, g):
-    return _flash_bwd(*res, g, scale, causal, _BWD_BLOCK_Q, _BWD_BLOCK_K)
+def _flash_vjp_bwd(scale, causal, block_q, block_k, window, res, g):
+    return _flash_bwd(*res, g, scale, causal, _BWD_BLOCK_Q, _BWD_BLOCK_K,
+                      window)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 def flash_attention(q, k, v, scale=None, causal=False,
-                    block_q=512, block_k=512):
+                    block_q=512, block_k=512, window=None):
     """Fused attention on [B, T, H, D] (same layout as
     `parallel.ring_attention`); k and v may have fewer heads, a divisor of
     q's (grouped-query). Differentiable: the forward and the backward are
     Pallas kernels, interpret-mode on CPU.
+
+    ``window`` (with ``causal``) is a sliding window: query i sees the keys
+    j with i - window < j <= i, itself among them; blocks wholly below it
+    are skipped. None, every key up to the query.
 
     ``block_q`` and ``block_k`` tile the forward; the backward's tiles are
     `_BWD_BLOCK_Q` x `_BWD_BLOCK_K`. Both were measured on the v5e
@@ -439,6 +477,9 @@ def flash_attention(q, k, v, scale=None, causal=False,
     across it."""
     b, tq, h, d = q.shape
     tk, hk = k.shape[1], k.shape[2]
+    if window is not None and (not causal or window < 1):
+        raise ValueError("a window of %r keys needs causal attention and at "
+                         "least one key" % (window,))
     if hk != h:
         # grouped-query: each key-value head serves h // hk query heads
         # (autodiff of the repeat sums their gradients)
@@ -450,7 +491,7 @@ def flash_attention(q, k, v, scale=None, causal=False,
     scale = (1.0 / d ** 0.5) if scale is None else scale
     to_bh = lambda x, t: jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, t, d)
     o = _flash(to_bh(q, tq), to_bh(k, tk), to_bh(v, tk),
-               scale, causal, block_q, block_k)
+               scale, causal, block_q, block_k, window)
     return jnp.transpose(o.reshape(b, h, tq, d), (0, 2, 1, 3))
 
 

@@ -221,3 +221,56 @@ def test_flash_attention_grouped_backward_kernels(one_chip, on_tpu):
     for line in calls:
         assert re.search(r'op_name="([^"]*[/(])?attention[/)"]', line)
     assert exe.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
+def test_flash_attention_windowed_kernels(one_chip, on_tpu):
+    """SmallThinker's window layers at 8,192 tokens: 28 query heads over 4
+    key-value heads of 128, a window of 4,096 keys. The forward and the
+    backward's two kernels compile with the window's bound and the blocks
+    below it skipped; the scores stay inside them."""
+    bf16 = jnp.bfloat16
+    spec = lambda heads: jax.ShapeDtypeStruct((2, 8192, heads, 128), bf16,
+                                              sharding=one_chip)
+
+    def loss(q, k, v):
+        o = pk.flash_attention(q, k, v, scale=128 ** -0.5, causal=True,
+                               window=4096)
+        return jnp.sum(o.astype(jnp.float32))
+
+    exe = _compile(jax.grad(loss, argnums=(0, 1, 2)), spec(28), spec(4),
+                   spec(4))
+    assert exe.as_text().count("tpu_custom_call") == 3
+    assert exe.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+def test_moe_held_experts_at_published_widths(one_chip, on_tpu):
+    """One SmallThinker expert layer's share at the benchmark's size: two
+    rows of 8,192 tokens routed over 64 experts, 6 a token, 16 held, ReGLU
+    of 768 at hidden 2,560, bfloat16 operands, forward and gradients. The
+    grouped products lower to the chip's own kernels, under ``moe_experts``
+    inside ``moe``, and the temporaries stay within a few GiB (a layer is
+    recomputed beside 8.3 GiB of state). XLA names those kernels
+    ``ragged-dot-*`` and keeps no ``op_name`` on them, which is why
+    `benchmark/grouped.py` finds them by that name."""
+    import re
+    from mxnet_tpu.ops import moe_ops
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    spec = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt,
+                                                  sharding=one_chip)
+
+    def loss(router_x, x, router_w, gate_up, down):
+        with jax.named_scope("moe"):
+            y = moe_ops.moe(router_x, x, router_w, gate_up, down,
+                            first_held=16, top_k=6)
+        return jnp.sum(y.astype(f32))
+
+    exe = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                   spec((2, 8192, 2560), f32), spec((2, 8192, 2560), bf16),
+                   spec((64, 2560), f32), spec((16, 1536, 2560), bf16),
+                   spec((16, 2560, 768), bf16))
+    names = re.findall(r"^\s*(?:ROOT\s+)?%(\S+) = .*tpu_custom_call",
+                       exe.as_text(), re.M)
+    assert sum(re.match(r"ragged-dot-none", n) is not None
+               for n in names) >= 4, names
+    assert all(n.startswith("ragged-dot") for n in names), names
+    assert exe.memory_analysis().temp_size_in_bytes < 4 << 30

@@ -64,17 +64,20 @@ def _grads(fn, q, k, v, w):
                     argnums=(0, 1, 2))(q, k, v)
 
 
-def _dense_attention(q, k, v, scale, causal):
+def _dense_attention(q, k, v, scale, causal, window=None):
     """The dense math on [BH, T, D], scores of all keys at once: the
     oracle of the kernels' output and, through autodiff, their
-    gradients."""
+    gradients. ``window``: query i sees keys i - window < j <= i."""
     import jax
     import jax.numpy as jnp
     s = jnp.einsum("bqd,bkd->bqk", q, k,
                    preferred_element_type=jnp.float32) * scale
     if causal:
         tq, tk = q.shape[1], k.shape[1]
-        mask = jnp.arange(tq)[:, None] >= jnp.arange(tk)[None, :]
+        i, j = jnp.arange(tq)[:, None], jnp.arange(tk)[None, :]
+        mask = i >= j
+        if window is not None:
+            mask = mask & (i - j < window)
         s = jnp.where(mask[None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bqk,bkd->bqd", p.astype(q.dtype), v)
@@ -155,3 +158,82 @@ def test_backward_kernels_match_dense_math(monkeypatch, causal, heads_kv, t,
     text = jax.jit(lambda *a: _grads(flash, *a)).lower(q, k, v, w).as_text()
     for rows in (t, blk):
         assert not re.search(r"(?<!\d)%dx%dxf32" % (rows, t), text), rows
+
+
+@pytest.mark.parametrize("t,window,heads_kv", [
+    (256, 40, 2),
+    (256, 64, 4),
+    (200, 72, 1),
+    (200, 200, 2),
+    (256, 300, 4),
+], ids=["under-grouped2", "block-multiple", "ragged-grouped1", "equal-to-t",
+        "over-t"])
+def test_window_kernels_match_dense_math(monkeypatch, t, window, heads_kv):
+    """The sliding window in the forward and both backward kernels, over
+    many 32 x 32 blocks: a window under T (whole blocks below it skipped),
+    one that is a block multiple, one over a ragged length with one
+    key-value head, one equal to T and one over it (both the causal
+    result). Output and the three gradients are the dense math's."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import pallas_kernels as pk
+    blk, heads = 32, 4
+    monkeypatch.setattr(pk, "_BWD_BLOCK_Q", blk)
+    monkeypatch.setattr(pk, "_BWD_BLOCK_K", blk)
+    rng = np.random.RandomState(6)
+    q, w = (jnp.asarray(rng.randn(1, t, heads, 8), jnp.float32)
+            for _ in range(2))
+    k, v = (jnp.asarray(rng.randn(1, t, heads_kv, 8), jnp.float32)
+            for _ in range(2))
+    bh = lambda x: jnp.transpose(x, (0, 2, 1, 3)).reshape(heads, t, 8)
+
+    def dense(q, k, v):
+        rep = heads // heads_kv
+        kk, vv = jnp.repeat(k, rep, axis=2), jnp.repeat(v, rep, axis=2)
+        o = _dense_attention(bh(q), bh(kk), bh(vv), 1 / 64, True, window)
+        return jnp.transpose(o.reshape(1, heads, t, 8), (0, 2, 1, 3))
+
+    flash = lambda q, k, v: pk.flash_attention(
+        q, k, v, scale=1 / 64, causal=True, block_q=blk, block_k=blk,
+        window=window)
+    np.testing.assert_allclose(flash(q, k, v), dense(q, k, v), rtol=1e-4,
+                               atol=1e-5)
+    for got, want in zip(_grads(flash, q, k, v, w), _grads(dense, q, k, v, w)):
+        assert got.shape == want.shape
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+    if window >= t:
+        causal = lambda q, k, v: pk.flash_attention(
+            q, k, v, scale=1 / 64, causal=True, block_q=blk, block_k=blk)
+        np.testing.assert_array_equal(flash(q, k, v), causal(q, k, v))
+
+
+@pytest.mark.parametrize("window", [1, 33, 64, 100, 4096])
+def test_window_runs_exactly_the_blocks_that_hold_a_pair(window):
+    """`_block_runs` skips a (q block, k block) pair exactly when none of
+    its (query, key) pairs is causal and inside the window; the backward's
+    index maps name, for a skipped block, a block of its sweep that runs."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import pallas_kernels as pk
+    bq, bk, t = 32, 16, 256
+    i, j = np.arange(t)[:, None], np.arange(t)[None]
+    admitted = (j <= i) & (i - j < window)
+    for qi in range(t // bq):
+        for ki in range(t // bk):
+            holds = admitted[qi * bq:(qi + 1) * bq, ki * bk:(ki + 1) * bk]
+            runs = pk._block_runs(jnp.int32(qi), jnp.int32(ki), causal=True,
+                                  window=window, block_q=bq, block_k=bk)
+            assert bool(runs) == bool(holds.any()), (qi, ki)
+
+
+def test_window_attr_through_the_op():
+    rng = np.random.RandomState(7)
+    q, k, v = (rng.randn(1, 24, 2, 8).astype("f") for _ in range(3))
+    out = mx.nd.contrib.flash_attention(
+        mx.nd.array(q), mx.nd.array(k), mx.nd.array(v), causal=True,
+        window=5).asnumpy()
+    s = np.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(8)
+    i, j = np.arange(24)[:, None], np.arange(24)[None]
+    s = np.where(((j <= i) & (i - j < 5))[None, None], s, -1e30)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    np.testing.assert_allclose(out, np.einsum("bhqk,bkhd->bqhd", p, v),
+                               rtol=1e-4, atol=1e-5)
