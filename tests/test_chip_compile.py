@@ -248,7 +248,8 @@ def test_moe_held_experts_at_published_widths(one_chip, on_tpu):
     rows of 8,192 tokens routed over 64 experts, 6 a token, 16 held, ReGLU
     of 768 at hidden 2,560, bfloat16 operands, forward and gradients. The
     grouped products lower to the chip's own kernels, under ``moe_experts``
-    inside ``moe``, and the temporaries stay within a few GiB (a layer is
+    inside ``moe``, no scatter runs over the buffer of assignments or the
+    tokens, and the temporaries stay within a few GiB (a layer is
     recomputed beside 8.3 GiB of state). XLA names those kernels
     ``ragged-dot-*`` and keeps no ``op_name`` on them, which is why
     `benchmark/grouped.py` finds them by that name."""
@@ -273,4 +274,6 @@ def test_moe_held_experts_at_published_widths(one_chip, on_tpu):
     assert sum(re.match(r"ragged-dot-none", n) is not None
                for n in names) >= 4, names
     assert all(n.startswith("ragged-dot") for n in names), names
+    wide = re.findall(r"= \w+\[[\d,]*2560\]\S* scatter\(", exe.as_text())
+    assert not wide, wide    # rows move by gathers both ways
     assert exe.memory_analysis().temp_size_in_bytes < 4 << 30

@@ -7,6 +7,7 @@ Adam on float32 master weights, and each assumption the configuration
 states."""
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -137,6 +138,86 @@ def test_the_op_refuses_a_share_outside_the_router():
         mx.nd.moe(*args, first_held=7, top_k=3)
     with pytest.raises(mx.MXNetError, match="top_k 9"):
         mx.nd.moe(*args, first_held=6, top_k=9)
+
+
+def _results(text, op):
+    """The result types (``tensor<AxBx..>``) of every ``op`` in a lowered
+    StableHLO text."""
+    out, i = [], text.find('"stablehlo.%s"(' % op)
+    while i >= 0:
+        out.append(re.search(r"\) -> (tensor<[^>]*>)", text[i:]).group(1))
+        i = text.find('"stablehlo.%s"(' % op, i + 1)
+    return out
+
+
+def test_rows_move_by_gathers_and_one_sort():
+    """The layer's gradient moves rows by gathers both ways: no scatter
+    into the buffer of assignments or into the tokens (a result whose last
+    axis is the hidden width; top-k's own, over the router's experts, is
+    left), and the assignments are sorted once."""
+    args = _held(_layer_inputs(dim=24), 2, 4)
+
+    def loss(*a):
+        return jnp.sum(moe_ops.moe(*a, first_held=2, top_k=3))
+
+    text = jax.jit(jax.grad(loss, argnums=range(5))).lower(*args).as_text()
+    scatters = _results(text, "scatter")
+    assert not [t for t in scatters if t.endswith("x24xf32>")], scatters
+    assert len(_results(text, "sort")) == 1
+
+
+def test_rank_inverts_the_sorted_order():
+    """The sort-free ``rank`` is the inverse of ``order`` (`argsort`), and
+    ``order`` the stable sort of the keys, for assignments that include a
+    token with no held slot and a token with every slot held."""
+    rng = np.random.RandomState(4)
+    tokens, top_k, experts, first, held = 50, 4, 12, 3, 5
+    chosen = np.stack([rng.choice(experts, top_k, replace=False)
+                       for _ in range(tokens)])
+    chosen[0] = [0, 1, 2, 11]        # none held
+    chosen[1] = [3, 4, 6, 7]         # every slot held
+    local = chosen - first
+    key = np.where((local >= 0) & (local < held), local, held).reshape(-1)
+    order, rank, counts = moe_ops.permutation(jnp.asarray(key, jnp.int32),
+                                              held + 1)
+    np.testing.assert_array_equal(order, np.argsort(key, kind="stable"))
+    np.testing.assert_array_equal(rank, np.argsort(np.asarray(order)))
+    np.testing.assert_array_equal(counts, np.bincount(key, minlength=held + 1))
+
+
+@pytest.mark.tpu
+def test_held_experts_are_the_dense_layer_on_the_chip():
+    """On the chip, where the grouped products are XLA's own kernels (the
+    CPU decomposes them and cannot show their faults): the layer inside
+    `jax.jit`, on bfloat16 operands, against its dense form in float32 at
+    ``highest``, output and all five gradients, each within 3 % by norm
+    (bfloat16's rounding; a kernel that computes the wrong rows is off by
+    the order of the values)."""
+    if jax.devices()[0].platform != "tpu":
+        pytest.skip("no TPU visible")
+    router_x, x, router_w, gate_up, down = _layer_inputs(
+        tokens=256, experts=16, dim=256, width=128)
+    args = (router_x, x.astype(jnp.bfloat16), router_w,
+            gate_up.astype(jnp.bfloat16), down.astype(jnp.bfloat16))
+    held = _held(args, 4, 8)
+    w = jnp.asarray(np.random.RandomState(9).randn(2, 256, 256), jnp.float32)
+
+    def op(*a):
+        return moe_ops.moe(*a, first_held=4, top_k=4).astype(jnp.float32)
+
+    def dense(*a):
+        with jax.default_matmul_precision("highest"):
+            return _dense_layer(*[v.astype(jnp.float32) for v in a], 4, 4)
+
+    def value_and_grads(fn):
+        return jax.jit(lambda *a: (fn(*a), jax.grad(
+            lambda *b: jnp.sum(fn(*b) * w), argnums=range(5))(*a)))(*held)
+
+    (got, got_grads), (want, want_grads) = map(value_and_grads, (op, dense))
+    for g, v in [(got, want)] + list(zip(got_grads, want_grads)):
+        g, v = np.asarray(g, np.float32), np.asarray(v, np.float32)
+        assert np.isfinite(g).all()
+        assert np.linalg.norm(g - v) < 0.03 * np.linalg.norm(v)
 
 
 # -- the assumptions the configuration states ---------------------------------
