@@ -566,8 +566,9 @@ class CompiledProgram:
             from . import xla_stats
             xla_stats.flight_recorder.last["compile"] = meta
             if compiled is not None:
-                # communication anatomy: inventory the executable's
-                # collectives (HLO text parse — no compile of its own)
+                # program anatomy: inventory the executable's collectives
+                # and index its ops by scope (one HLO text parse — no
+                # compile of its own)
                 try:
                     from . import shardprof
                     shardprof.note_program(self.site, self._lineage,

@@ -630,6 +630,31 @@ class Executor:
         return [self.grad_dict.get(n) for n in self._arg_names]
 
     @property
+    def outputs(self):
+        """The outputs of the program run last. After `last_outputs_of`
+        they are the last row of each stacked output, sliced when first
+        read."""
+        if self._stacked_outputs is not None:
+            stacked, self._stacked_outputs = self._stacked_outputs, None
+            self._outputs = [_from_data(o[o.shape[0] - 1], self._ctx)
+                             for o in stacked]
+        return self._outputs
+
+    @outputs.setter
+    def outputs(self, value):
+        self._stacked_outputs = None
+        self._outputs = value
+
+    def last_outputs_of(self, stacked):
+        """Make the last row of each of ``stacked`` (arrays [K, ...], a
+        scanned step's outputs) the outputs, sliced when `outputs` is first
+        read and not now: the slice is a program of its own, and one
+        launched right behind a dispatch can be the launch the runtime
+        holds until an earlier program ends (it caps the programs in
+        flight), a wait in whatever phase the caller is in."""
+        self._stacked_outputs = list(stacked)
+
+    @property
     def aux_arrays(self):
         return [self.aux_dict[n] for n in self._aux_names]
 

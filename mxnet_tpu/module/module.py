@@ -1008,11 +1008,12 @@ class Module(BaseModule):
             if dst is not None:
                 dst._data = val
         fused.commit_states(indices, sv)
-        # K - 1, not -1: jax normalises a negative index with eager scalar
-        # ops on the device, and with `fit` one dispatch ahead one of them
-        # held this call until the dispatch BEFORE had ended (727 ms, in no
-        # phase; PERF.md section 6, PR 31)
-        exec_.outputs = [_from_data(o[K - 1], exec_._ctx) for o in outs]
+        # the last batch's outputs are sliced when first read: a slice
+        # launched here, right behind the dispatch, was the launch the
+        # runtime held until the dispatch BEFORE had ended (its cap on
+        # programs in flight), a wait in no phase of `fit` (PERF.md
+        # section 6); `fit` reads the stacked outputs themselves
+        exec_.last_outputs_of(outs)
         self._params_dirty = True
         return [_from_data(o, exec_._ctx) for o in outs]
 

@@ -146,33 +146,35 @@ def _space_to_depth_conv(data, weight, pad):
 
 @register("Convolution")
 def _convolution(params, data, weight, *bias):
-    kernel = tuple(params["kernel"])
-    nd = len(kernel)
-    stride = _tup(params.get("stride"), nd, 1)
-    dilate = _tup(params.get("dilate"), nd, 1)
-    pad = _tup(params.get("pad"), nd, 0)
-    groups = params.get("num_group", 1)
-    dspec, wspec, caxis = _layout_spec(params, nd)
-    if _s2d_eligible(params, data, weight, kernel, stride, dilate, groups,
-                     caxis):
-        out = _space_to_depth_conv(data, weight, pad)
-    else:
-        dn = lax.conv_dimension_numbers(data.shape, weight.shape,
-                                        (dspec, wspec, dspec))
-        # no preferred_element_type: the TPU MXU accumulates bf16 convs in
-        # f32 natively, and forcing f32 here leaks an f32 cotangent into the
-        # conv transpose rule, which rejects mixed bf16/f32 operands
-        out = lax.conv_general_dilated(
-            data, weight, window_strides=stride,
-            padding=[(p, p) for p in pad],
-            rhs_dilation=dilate, dimension_numbers=dn,
-            feature_group_count=groups)
-    if not params.get("no_bias", False) and bias:
-        if caxis == 1:
-            out = out + bias[0].reshape((1, -1) + (1,) * nd)
+    with jax.named_scope("convolution"):
+        kernel = tuple(params["kernel"])
+        nd = len(kernel)
+        stride = _tup(params.get("stride"), nd, 1)
+        dilate = _tup(params.get("dilate"), nd, 1)
+        pad = _tup(params.get("pad"), nd, 0)
+        groups = params.get("num_group", 1)
+        dspec, wspec, caxis = _layout_spec(params, nd)
+        if _s2d_eligible(params, data, weight, kernel, stride, dilate, groups,
+                         caxis):
+            out = _space_to_depth_conv(data, weight, pad)
         else:
-            out = out + bias[0]
-    return (out,)
+            dn = lax.conv_dimension_numbers(data.shape, weight.shape,
+                                            (dspec, wspec, dspec))
+            # no preferred_element_type: the TPU MXU accumulates bf16 convs
+            # in f32 natively, and forcing f32 here leaks an f32 cotangent
+            # into the conv transpose rule, which rejects mixed bf16/f32
+            # operands
+            out = lax.conv_general_dilated(
+                data, weight, window_strides=stride,
+                padding=[(p, p) for p in pad],
+                rhs_dilation=dilate, dimension_numbers=dn,
+                feature_group_count=groups)
+        if not params.get("no_bias", False) and bias:
+            if caxis == 1:
+                out = out + bias[0].reshape((1, -1) + (1,) * nd)
+            else:
+                out = out + bias[0]
+        return (out,)
 
 
 @register("Deconvolution")
@@ -211,69 +213,76 @@ def _deconvolution(params, data, weight, *bias):
 # ---------------------------------------------------------------------------
 @register("Pooling", aliases=("Pooling_v1",))
 def _pooling(params, data):
-    pool_type = params.get("pool_type", "max")
-    global_pool = params.get("global_pool", False)
-    nd = data.ndim - 2
-    _, _, caxis = _layout_spec(params, nd)
-    spatial_axes = tuple(range(2, 2 + nd)) if caxis == 1 else \
-        tuple(range(1, 1 + nd))
-    if global_pool:
-        kernel = tuple(data.shape[a] for a in spatial_axes)
-        stride = (1,) * nd
-        pad = pad_end = (0,) * nd
-    else:
-        kernel = _tup(params["kernel"], nd, 1)
-        stride = _tup(params.get("stride"), nd, 1)
-        pad = _tup(params.get("pad"), nd, 0)
-        # pad_end: asymmetric begin/end padding (ONNX importer); padding
-        # cells never join the max (init=-inf) and are excluded from the
-        # avg count when count_include_pad=False, so semantics stay exact
-        pad_end = _tup(params["pad_end"], nd, 0) if params.get("pad_end") \
-            is not None else pad
-        from ..base import MXNetError
-        for i, (k, p, pe) in enumerate(zip(kernel, pad, pad_end)):
-            if k > data.shape[spatial_axes[i]] + p + pe:
-                raise MXNetError(
-                    "Pooling kernel %s exceeds padded input %s"
-                    % (kernel, tuple(data.shape[a] for a in spatial_axes)))
+    with jax.named_scope("pooling"):
+        pool_type = params.get("pool_type", "max")
+        global_pool = params.get("global_pool", False)
+        nd = data.ndim - 2
+        _, _, caxis = _layout_spec(params, nd)
+        spatial_axes = tuple(range(2, 2 + nd)) if caxis == 1 else \
+            tuple(range(1, 1 + nd))
+        if global_pool:
+            kernel = tuple(data.shape[a] for a in spatial_axes)
+            stride = (1,) * nd
+            pad = pad_end = (0,) * nd
+        else:
+            kernel = _tup(params["kernel"], nd, 1)
+            stride = _tup(params.get("stride"), nd, 1)
+            pad = _tup(params.get("pad"), nd, 0)
+            # pad_end: asymmetric begin/end padding (ONNX importer); padding
+            # cells never join the max (init=-inf) and are excluded from the
+            # avg count when count_include_pad=False, so semantics stay exact
+            pad_end = _tup(params["pad_end"], nd, 0) if params.get("pad_end") \
+                is not None else pad
+            from ..base import MXNetError
+            for i, (k, p, pe) in enumerate(zip(kernel, pad, pad_end)):
+                if k > data.shape[spatial_axes[i]] + p + pe:
+                    raise MXNetError(
+                        "Pooling kernel %s exceeds padded input %s"
+                        % (kernel, tuple(data.shape[a] for a in spatial_axes)))
 
-    def _full(kern, strd, padd):
-        if caxis == 1:
-            return (1, 1) + tuple(kern), (1, 1) + tuple(strd), \
-                ((0, 0), (0, 0)) + tuple(padd)
-        return (1,) + tuple(kern) + (1,), (1,) + tuple(strd) + (1,), \
-            ((0, 0),) + tuple(padd) + ((0, 0),)
+        def _full(kern, strd, padd):
+            if caxis == 1:
+                return (1, 1) + tuple(kern), (1, 1) + tuple(strd), \
+                    ((0, 0), (0, 0)) + tuple(padd)
+            return (1,) + tuple(kern) + (1,), (1,) + tuple(strd) + (1,), \
+                ((0, 0),) + tuple(padd) + ((0, 0),)
 
-    window, strides, padding = _full(kernel, stride, list(zip(pad, pad_end)))
-    if params.get("pooling_convention", "valid") == "full" and not global_pool:
-        # ceil-mode output: extend right/bottom padding as needed
-        extra = []
-        for i, (k, s, p, pe) in enumerate(zip(kernel, stride, pad, pad_end)):
-            in_sz = data.shape[spatial_axes[i]]
-            out_full = int(np.ceil((in_sz + p + pe - k) / s)) + 1
-            needed = (out_full - 1) * s + k - in_sz - p
-            extra.append((p, max(needed, pe)))
-        _, _, padding = _full(kernel, stride, extra)
-    if pool_type == "max":
-        init = -jnp.inf if jnp.issubdtype(data.dtype, jnp.floating) else jnp.iinfo(data.dtype).min
-        out = lax.reduce_window(data, init, lax.max, window, strides,
-                                padding)
-        if params.get("_fold_relu"):
-            # executor relu->maxpool fold: maxpool(relu(x)) ==
-            # max(maxpool(x), 0); grads agree (see _plan_relu_pool_fold)
-            out = jnp.maximum(out, jnp.zeros((), out.dtype))
-    elif pool_type in ("avg", "sum"):
-        out = lax.reduce_window(data, 0.0, lax.add, window, strides, padding)
-        if pool_type == "avg":
-            if params.get("count_include_pad", True):
-                out = out / float(np.prod(kernel))
-            else:
-                ones = jnp.ones_like(data)
-                cnt = lax.reduce_window(ones, 0.0, lax.add, window, strides, padding)
-                out = out / cnt
-    else:
-        raise MXNetError("unsupported pool_type " + pool_type)
-    return (out.astype(data.dtype),)
+        window, strides, padding = _full(kernel, stride,
+                                         list(zip(pad, pad_end)))
+        if params.get("pooling_convention", "valid") == "full" \
+                and not global_pool:
+            # ceil-mode output: extend right/bottom padding as needed
+            extra = []
+            for i, (k, s, p, pe) in enumerate(zip(kernel, stride, pad,
+                                                  pad_end)):
+                in_sz = data.shape[spatial_axes[i]]
+                out_full = int(np.ceil((in_sz + p + pe - k) / s)) + 1
+                needed = (out_full - 1) * s + k - in_sz - p
+                extra.append((p, max(needed, pe)))
+            _, _, padding = _full(kernel, stride, extra)
+        if pool_type == "max":
+            init = -jnp.inf if jnp.issubdtype(data.dtype, jnp.floating) \
+                else jnp.iinfo(data.dtype).min
+            out = lax.reduce_window(data, init, lax.max, window, strides,
+                                    padding)
+            if params.get("_fold_relu"):
+                # executor relu->maxpool fold: maxpool(relu(x)) ==
+                # max(maxpool(x), 0); grads agree (see _plan_relu_pool_fold)
+                out = jnp.maximum(out, jnp.zeros((), out.dtype))
+        elif pool_type in ("avg", "sum"):
+            out = lax.reduce_window(data, 0.0, lax.add, window, strides,
+                                    padding)
+            if pool_type == "avg":
+                if params.get("count_include_pad", True):
+                    out = out / float(np.prod(kernel))
+                else:
+                    ones = jnp.ones_like(data)
+                    cnt = lax.reduce_window(ones, 0.0, lax.add, window,
+                                            strides, padding)
+                    out = out / cnt
+        else:
+            raise MXNetError("unsupported pool_type " + pool_type)
+        return (out.astype(data.dtype),)
 
 
 @register("_contrib_AdaptiveAvgPooling2D")
@@ -371,36 +380,38 @@ _bn_train_core = jax.custom_vjp(_bn_train_core_impl, nondiff_argnums=(0, 1))
 
 
 def _bn_core_fwd(axis, eps, data, g, beta):
-    mean, var, _, bshape = _bn_stats(axis, eps, data)
-    inv = lax.rsqrt(var + eps)
-    out = _bn_apply(data, g, beta, mean, var, eps, bshape)
-    return (out, mean, var), (data, g, mean, inv)
+    with jax.named_scope("batch_norm"):
+        mean, var, _, bshape = _bn_stats(axis, eps, data)
+        inv = lax.rsqrt(var + eps)
+        out = _bn_apply(data, g, beta, mean, var, eps, bshape)
+        return (out, mean, var), (data, g, mean, inv)
 
 
 def _bn_core_bwd(axis, eps, res, cts):
-    data, g, mean, inv = res
-    dy = cts[0]  # mean/var outputs are statistics, not differentiated
-    # (cuDNN batch-norm backward likewise exposes no stat gradients)
-    red_axes = tuple(i for i in range(data.ndim) if i != axis)
-    bshape = tuple(-1 if i == axis else 1 for i in range(data.ndim))
-    n = 1.0
-    for i in red_axes:
-        n *= data.shape[i]
-    mean_b = mean.reshape(bshape)
-    inv_b = inv.reshape(bshape)
-    xhat = (data.astype(jnp.float32) - mean_b) * inv_b  # recomputed, fused
-    dy32 = dy.astype(jnp.float32)
-    # the two sums read dy through a convert of their own: with dx's
-    # shared, the TPU compiler emits another program (PERF.md section 6,
-    # PR 30), so merging them is a change to measure, not a tidy-up
-    sdy32 = dy.astype(jnp.float32)
-    sum_dy = jnp.sum(sdy32, axis=red_axes)
-    sum_dy_xhat = jnp.sum(sdy32 * xhat, axis=red_axes)
-    coef = (g.astype(jnp.float32) * inv).reshape(bshape)
-    dx = coef * (dy32 - sum_dy.reshape(bshape) / n
-                 - xhat * (sum_dy_xhat.reshape(bshape) / n))
-    return (dx.astype(data.dtype), sum_dy_xhat.astype(g.dtype),
-            sum_dy.astype(g.dtype))
+    with jax.named_scope("batch_norm"):
+        data, g, mean, inv = res
+        dy = cts[0]  # mean/var outputs are statistics, not differentiated
+        # (cuDNN batch-norm backward likewise exposes no stat gradients)
+        red_axes = tuple(i for i in range(data.ndim) if i != axis)
+        bshape = tuple(-1 if i == axis else 1 for i in range(data.ndim))
+        n = 1.0
+        for i in red_axes:
+            n *= data.shape[i]
+        mean_b = mean.reshape(bshape)
+        inv_b = inv.reshape(bshape)
+        xhat = (data.astype(jnp.float32) - mean_b) * inv_b  # recomputed, fused
+        dy32 = dy.astype(jnp.float32)
+        # the two sums read dy through a convert of their own: with dx's
+        # shared, the TPU compiler emits another program (PERF.md section
+        # 6), so merging them is a change to measure, not a tidy-up
+        sdy32 = dy.astype(jnp.float32)
+        sum_dy = jnp.sum(sdy32, axis=red_axes)
+        sum_dy_xhat = jnp.sum(sdy32 * xhat, axis=red_axes)
+        coef = (g.astype(jnp.float32) * inv).reshape(bshape)
+        dx = coef * (dy32 - sum_dy.reshape(bshape) / n
+                     - xhat * (sum_dy_xhat.reshape(bshape) / n))
+        return (dx.astype(data.dtype), sum_dy_xhat.astype(g.dtype),
+                sum_dy.astype(g.dtype))
 
 
 _bn_train_core.defvjp(_bn_core_fwd, _bn_core_bwd)
@@ -417,46 +428,50 @@ def _batch_norm(params, data, gamma, beta, moving_mean, moving_var):
     normalization applies as ONE scale/shift multiply-add in the data dtype.
     On bf16 ResNet-50 train this is worth ~20% end-to-end vs normalizing
     through an f32 intermediate (tools/perf/resnet_ablate.py 'bnmixed')."""
-    eps = params.get("eps", 1e-3)
-    momentum = params.get("momentum", 0.9)
-    axis = params.get("axis", 1)
-    fix_gamma = params.get("fix_gamma", True)
-    use_global = params.get("use_global_stats", False) or not params.get("_is_train", False)
-    # bias folded out of the producing conv by the executor's
-    # conv-bias->BN elision pass (executor._plan_conv_bias_bn_fold): our
-    # input is x where the reference graph normalized x+b. Batch stats:
-    # mean(x+b) = mean(x)+b and var is shift-invariant, so normalization is
-    # unchanged; only the running-mean bookkeeping needs the +b.
-    fold_b = params.get("_fold_bias")
-    g = jnp.ones_like(gamma) if fix_gamma else gamma
-    axis_n = axis % data.ndim
-    bshape = tuple(-1 if i == axis_n else 1 for i in range(data.ndim))
-    if use_global:
-        mean, var = moving_mean, moving_var
-        inv = lax.rsqrt(var.astype(jnp.float32) + eps)
-        scale = g.astype(jnp.float32) * inv
-        m32 = mean.astype(jnp.float32)
+    with jax.named_scope("batch_norm"):
+        eps = params.get("eps", 1e-3)
+        momentum = params.get("momentum", 0.9)
+        axis = params.get("axis", 1)
+        fix_gamma = params.get("fix_gamma", True)
+        use_global = params.get("use_global_stats", False) \
+            or not params.get("_is_train", False)
+        # bias folded out of the producing conv by the executor's
+        # conv-bias->BN elision pass (executor._plan_conv_bias_bn_fold): our
+        # input is x where the reference graph normalized x+b. Batch stats:
+        # mean(x+b) = mean(x)+b and var is shift-invariant, so normalization is
+        # unchanged; only the running-mean bookkeeping needs the +b.
+        fold_b = params.get("_fold_bias")
+        g = jnp.ones_like(gamma) if fix_gamma else gamma
+        axis_n = axis % data.ndim
+        bshape = tuple(-1 if i == axis_n else 1 for i in range(data.ndim))
+        if use_global:
+            mean, var = moving_mean, moving_var
+            inv = lax.rsqrt(var.astype(jnp.float32) + eps)
+            scale = g.astype(jnp.float32) * inv
+            m32 = mean.astype(jnp.float32)
+            if fold_b is not None:
+                # running stats are in the x+b domain; our input is x
+                m32 = m32 - fold_b.astype(jnp.float32)
+            shift = beta.astype(jnp.float32) - m32 * scale
+            out = data * scale.astype(data.dtype).reshape(bshape) \
+                + shift.astype(data.dtype).reshape(bshape)
+            return (out, mean.astype(jnp.float32), var.astype(jnp.float32),
+                    moving_mean, moving_var)
+        # training: fused-backward core (custom VJP, see _bn_train_core above)
+        out, mean, var = _bn_train_core(axis_n, float(eps), data, g, beta)
         if fold_b is not None:
-            # running stats are in the x+b domain; our input is x
-            m32 = m32 - fold_b.astype(jnp.float32)
-        shift = beta.astype(jnp.float32) - m32 * scale
-        out = data * scale.astype(data.dtype).reshape(bshape) \
-            + shift.astype(data.dtype).reshape(bshape)
-        return (out, mean.astype(jnp.float32), var.astype(jnp.float32),
-                moving_mean, moving_var)
-    # training: fused-backward core (custom VJP, see _bn_train_core above)
-    out, mean, var = _bn_train_core(axis_n, float(eps), data, g, beta)
-    if fold_b is not None:
-        # report/track stats in the x+b domain (running_mean parity with
-        # the unfused reference graph); an O(C) add, not an O(NHWC) one
-        mean = mean + lax.stop_gradient(fold_b).astype(mean.dtype)
-    new_mm = lax.stop_gradient(
-        momentum * moving_mean + (1 - momentum) * mean.astype(moving_mean.dtype))
-    new_mv = lax.stop_gradient(
-        momentum * moving_var + (1 - momentum) * var.astype(moving_var.dtype))
-    # mean/var outputs stay f32 regardless of data dtype (cuDNN BN keeps
-    # fp32 stats for fp16 inputs the same way)
-    return (out, mean, var, new_mm, new_mv)
+            # report/track stats in the x+b domain (running_mean parity with
+            # the unfused reference graph); an O(C) add, not an O(NHWC) one
+            mean = mean + lax.stop_gradient(fold_b).astype(mean.dtype)
+        new_mm = lax.stop_gradient(
+            momentum * moving_mean
+            + (1 - momentum) * mean.astype(moving_mean.dtype))
+        new_mv = lax.stop_gradient(
+            momentum * moving_var
+            + (1 - momentum) * var.astype(moving_var.dtype))
+        # mean/var outputs stay f32 regardless of data dtype (cuDNN BN keeps
+        # fp32 stats for fp16 inputs the same way)
+        return (out, mean, var, new_mm, new_mv)
 
 
 @register("LayerNorm", num_outputs=3, num_visible_outputs=1)

@@ -1,5 +1,5 @@
-"""Communication anatomy: collective profiling, sharding audit, and
-overlap verdicts for SPMD programs.
+"""Program anatomy: collective profiling, the op index, sharding audit,
+and overlap verdicts for SPMD programs.
 
 PR 8 moved gradient sync INSIDE the compiled step (sharding constraints
 on a named mesh), so the kvstore push/pull counters that used to show
@@ -25,7 +25,19 @@ visible again, hooked into the one `CompiledProgram` choke point
    ``xla_stats.compile_counts()`` diffs prove the instrumentation is
    free of retraces (asserted in ``tests/test_shardprof.py``).
 
-2. **Sharding audit** — :func:`audit` walks a bound Module's (or gluon
+2. **Op index** — in the same pass over the same text, every
+   instruction that runs as an op of its own (all but those inside a
+   fusion) is recorded with the ``op_name`` it was traced under, the
+   scope path components (`jax.named_scope`) of what it holds (a
+   fusion's: of every instruction of the computation it calls), and
+   the ``op_name`` of the convolution it is or holds, traced under the
+   scope ``convolution``. A device trace names its ``XLA Ops`` events by
+   these instructions and leaves ``op_name`` out, so the index is how
+   an op event finds its layer. Kept per ``(site, lineage)``, latest
+   compile wins, and looked up by the HLO module's name
+   (:func:`op_index`, ``jit_step``, ``jit_scan_step``).
+
+3. **Sharding audit** — :func:`audit` walks a bound Module's (or gluon
    Trainer's) params, grads, and optimizer state and reports spec-vs-
    actual sharding per parameter: ``replicated`` where the policy said
    sharded (the `init_params` bias-bug class PR 8 fixed in
@@ -34,7 +46,7 @@ visible again, hooked into the one `CompiledProgram` choke point
    ``spmd_sharded_param_bytes`` (global bytes by ACTUAL placement) and
    rendered as a table by the report CLI.
 
-3. **Overlap verdict** — measured per-step wall/device time (stepprof)
+4. **Overlap verdict** — measured per-step wall/device time (stepprof)
    + the collective byte inventory + a per-link bandwidth table
    (``MXNET_SHARDPROF_LINK_GBPS`` override, defaults per device kind)
    combine into predicted comm seconds per step and an
@@ -47,14 +59,16 @@ visible again, hooked into the one `CompiledProgram` choke point
    ROADMAP items 1-2 (fsdp gather not overlapped -> donation/scan;
    all-reduce ~= grad bytes -> compression / larger per-device batch).
 
-4. **Cross-host** — per-host ``shardprof_host<h>_pid<p>.json``
+5. **Cross-host** — per-host ``shardprof_host<h>_pid<p>.json``
    snapshots ride the stepprof/reqtrace telemetry-dir transport
    (throttled exporter thread + atexit); the report CLI merges them so
    a MULTICHIP run shows per-host comm bytes and the skew between them.
 
 CLI: ``python -m mxnet_tpu.shardprof report [path|dir]``. Enablement:
 ``MXNET_SHARDPROF=0`` disables the compile hook (the query API then
-reports empty); recording costs one regex scan per compile.
+reports empty, :func:`op_index` None); recording costs one pass of
+regexes over the HLO text per compile, its seconds kept as the
+ledger entry's ``parse_s``.
 
 Import cost: stdlib + telemetry + stepprof only — jax is imported
 lazily inside the audit helpers, so the report CLI runs on a machine
@@ -77,7 +91,8 @@ from . import telemetry
 from . import stepprof
 
 __all__ = ["COLLECTIVE_KINDS", "enabled", "parse_hlo_collectives",
-           "inventory_of", "note_program", "programs", "site_inventory",
+           "inventory_of", "read_hlo", "op_index_of", "note_program",
+           "programs", "op_index", "site_inventory",
            "train_step_inventory", "collective_totals", "link_gbps",
            "LINK_GBPS_BY_KIND", "comm_stats", "audit", "snapshot",
            "reset", "write_host_snapshot", "merge_host_snapshots",
@@ -94,6 +109,7 @@ _TRAIN_SITES = ("module.scan_step", "module.fused_step",
 
 _lock = threading.Lock()
 _programs = {}   # (site, lineage) -> inventory entry (latest compile)
+_op_indexes = {}  # (site, lineage) -> (module, {instruction: record})
 _state = {"param_bytes_global": None, "last_audit": None,
           "export_thread": None}
 
@@ -109,6 +125,7 @@ def reset():
     counters are NOT touched — pair with ``telemetry.reset()``."""
     with _lock:
         _programs.clear()
+        _op_indexes.clear()
         _state["param_bytes_global"] = None
         _state["last_audit"] = None
 
@@ -124,7 +141,7 @@ _DTYPE_BITS = {
     "f32": 32, "f64": 64, "c64": 64, "c128": 128,
 }
 
-_INSTR_RE = re.compile(r"^\s*(?:ROOT\s+)?%[\w.\-]+\s*=\s*(.*)$")
+_INSTR_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
 _KIND_RE = re.compile(r"\b(%s)(-start|-done)?\("
                       % "|".join(COLLECTIVE_KINDS))
 _SHAPE_RE = re.compile(r"([a-z][a-z0-9]*)\[([0-9,]*)\]")
@@ -156,6 +173,127 @@ def _replica_groups(line):
     return None
 
 
+def _collective(rest, line):
+    """The collective of one instruction (``rest`` is its text after
+    ``=``), or None: see :func:`parse_hlo_collectives`."""
+    km = _KIND_RE.search(rest)
+    if km is None or km.group(2) == "-done":
+        return None
+    is_start = km.group(2) == "-start"
+    shapes = _SHAPE_RE.findall(rest[:km.start()])
+    if not shapes:
+        return None
+    if is_start and len(shapes) >= 2:
+        # (operand..., output...) tuple: the output half is the wire
+        shapes = shapes[len(shapes) // 2:]
+    bits = sum(_shape_bits(dt, dims) for dt, dims in shapes)
+    return {"kind": km.group(1), "bytes": (bits + 7) // 8,
+            "async": is_start, "replica_groups": _replica_groups(line)}
+
+
+_MODULE_RE = re.compile(r"^HloModule\s+([^\s,]+)")
+_COMPUTATION_RE = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+_OPCODE_RE = re.compile(r"(?:^|\s)([a-z][\w\-]*)\(")
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_CALLS_RE = re.compile(r"\bcalls=%?([\w.\-]+)")
+_WRAPPED_RE = re.compile(r"^[\w\-]+\((.*)\)$")
+#: the opcodes of a convolution's products: XLA may lower a 1x1
+#: convolution, or one of its gradients, to a dot
+_CONV_OPCODES = ("convolution", "dot")
+
+
+def _scopes_of(op_name, cache):
+    """The scope path components of an ``op_name``: every component but
+    the last (the primitive), each unwrapped of its transformations
+    (``transpose(jvp(batch_norm))`` is ``batch_norm``, ``jit(step)``
+    is ``step``)."""
+    got = cache.get(op_name)
+    if got is None:
+        got = []
+        for part in op_name.split("/")[:-1]:
+            m = _WRAPPED_RE.match(part)
+            while m is not None:
+                part = m.group(1)
+                m = _WRAPPED_RE.match(part)
+            if part:
+                got.append(part)
+        got = cache[op_name] = frozenset(got)
+    return got
+
+
+def read_hlo(text):
+    """One pass over an HLO text: ``(module name, collectives, op
+    index)``, the collectives as :func:`parse_hlo_collectives` gives
+    them and the op index as :func:`op_index_of` does."""
+    module, collectives = None, []
+    comps = {}     # computation -> [(instruction, op_name, scopes, conv,
+    #                                 callee of a fusion)]
+    fused = set()
+    cache = {}
+    body = None
+    for line in text.splitlines():
+        m = _INSTR_RE.match(line)
+        if m is None:
+            m = _COMPUTATION_RE.match(line)
+            if m is not None:
+                body = comps.setdefault(m.group(1), [])
+            elif module is None:
+                m = _MODULE_RE.match(line)
+                module = m.group(1) if m else None
+            continue
+        name, rest = m.groups()
+        c = _collective(rest, line)
+        if c is not None:
+            collectives.append(c)
+        if body is None:
+            continue
+        m = _OP_NAME_RE.search(rest)
+        op_name = m.group(1) if m else ""
+        scopes = _scopes_of(op_name, cache)
+        m = _OPCODE_RE.search(rest)
+        opcode = m.group(1) if m else ""
+        conv = None
+        if opcode in _CONV_OPCODES and "convolution" in scopes:
+            conv = op_name
+        elif opcode == "convolution" and not op_name:
+            conv = ""   # rebuilt by a compiler pass, metadata dropped
+        callee = None
+        if opcode == "fusion":
+            m = _CALLS_RE.search(rest)
+            if m is not None:
+                callee = m.group(1)
+                fused.add(callee)
+        body.append((name, op_name, scopes, conv, callee))
+
+    held = {}   # computation -> (scopes, conv) of all it holds
+
+    def holds(comp):
+        if comp not in held:
+            scopes, conv = set(), None
+            for _, _, s, c, callee in comps.get(comp, ()):
+                if callee is not None:
+                    s2, c2 = holds(callee)
+                    scopes |= s2
+                    conv = c2 if conv is None else conv
+                scopes |= s
+                conv = c if conv is None else conv
+            held[comp] = (frozenset(scopes), conv)
+        return held[comp]
+
+    ops = {}
+    for comp, instructions in comps.items():
+        if comp in fused:
+            continue
+        for name, op_name, scopes, conv, callee in instructions:
+            if callee is not None:
+                s2, c2 = holds(callee)
+                scopes = scopes | s2
+                conv = c2 if conv is None else conv
+            ops[name] = {"op_name": op_name, "scopes": scopes,
+                         "conv": conv}
+    return module, collectives, ops
+
+
 def parse_hlo_collectives(text):
     """Collective instructions out of an HLO text dump:
     ``[{"kind", "bytes", "async", "replica_groups"}, ...]``.
@@ -167,35 +305,34 @@ def parse_hlo_collectives(text):
     kind inside metadata (``op_name="...all_reduce..."``) never match:
     the pattern anchors on the ``= <shape> <kind>(`` instruction form.
     """
-    out = []
-    for line in text.splitlines():
-        m = _INSTR_RE.match(line)
-        if m is None:
-            continue
-        rest = m.group(1)
-        km = _KIND_RE.search(rest)
-        if km is None or km.group(2) == "-done":
-            continue
-        is_start = km.group(2) == "-start"
-        shapes = _SHAPE_RE.findall(rest[:km.start()])
-        if not shapes:
-            continue
-        if is_start and len(shapes) >= 2:
-            # (operand..., output...) tuple: the output half is the wire
-            shapes = shapes[len(shapes) // 2:]
-        bits = sum(_shape_bits(dt, dims) for dt, dims in shapes)
-        out.append({"kind": km.group(1), "bytes": (bits + 7) // 8,
-                    "async": is_start,
-                    "replica_groups": _replica_groups(line)})
-    return out
+    return read_hlo(text)[1]
+
+
+def op_index_of(text):
+    """The op index of an HLO text: ``{instruction: {"op_name",
+    "scopes", "conv"}}`` for every instruction that runs as an op of its
+    own, that is every one outside a fused computation (the names a
+    device trace's ``XLA Ops`` events carry). ``op_name`` is its own,
+    ``""`` where it has none; ``scopes`` the frozenset of scope path
+    components of its own ``op_name`` and, for a fusion, of every
+    instruction of the computation it calls; ``conv`` the ``op_name`` of
+    the convolution (or dot) under the scope ``convolution`` that it is
+    or that its fusion holds, ``""`` for a convolution with no
+    ``op_name`` at all (a compiler pass rebuilt it from one and dropped
+    its metadata: the CPU's weight gradients), else None."""
+    return read_hlo(text)[2]
 
 
 def inventory_of(text):
     """Aggregate :func:`parse_hlo_collectives` output per kind:
     ``{kind: {"count", "bytes", "replica_groups"}}`` (``replica_groups``
     is the sorted list of distinct ``(n_groups, group_size)`` shapes)."""
+    return _aggregate(parse_hlo_collectives(text))
+
+
+def _aggregate(collectives):
     inv = {}
-    for c in parse_hlo_collectives(text):
+    for c in collectives:
         d = inv.setdefault(c["kind"], {"count": 0, "bytes": 0,
                                        "replica_groups": set()})
         d["count"] += 1
@@ -237,15 +374,18 @@ def note_program(site, lineage, compiled):
     own. Returns the ledger entry (None when disabled/no executable)."""
     if not enabled() or compiled is None:
         return None
-    text = None
+    text = ops = None
     try:
         text = compiled.as_text()
     except Exception as exc:
         telemetry.swallowed("shardprof.hlo_text", exc)
     if text is not None:
-        inv = inventory_of(text)
+        t0 = time.perf_counter()
+        module, collectives, ops = read_hlo(text)
+        inv = _aggregate(collectives)
         entry = {"site": site, "source": "hlo", "collectives": inv,
                  "bytes": sum(d["bytes"] for d in inv.values()),
+                 "module": module, "parse_s": time.perf_counter() - t0,
                  "updated": time.time()}
     else:
         entry = {"site": site, "source": "cost_analysis",
@@ -255,6 +395,10 @@ def note_program(site, lineage, compiled):
         prev = _programs.get((site, lineage))
         entry["compiles"] = (prev["compiles"] + 1) if prev else 1
         _programs[(site, lineage)] = entry
+        if ops is not None:
+            _op_indexes[(site, lineage)] = (entry["module"], ops)
+        else:
+            _op_indexes.pop((site, lineage), None)
     for kind, d in entry["collectives"].items():
         telemetry.counter("spmd_collectives_total",
                           help="collective instructions in compiled "
@@ -275,6 +419,18 @@ def programs():
     ``{(site, lineage): entry}`` — latest compile per key."""
     with _lock:
         return dict(_programs)
+
+
+def op_index(module):
+    """The op index (:func:`op_index_of`) of the latest compile of the
+    HLO module named ``module`` (``jit_step``: a trace's ``XLA Modules``
+    event name up to its fingerprint), or None: no such compile, or the
+    hook is off (``MXNET_SHARDPROF=0``)."""
+    with _lock:
+        found = [(_programs[key]["updated"], ops)
+                 for key, (name, ops) in _op_indexes.items()
+                 if name == module]
+    return max(found, key=lambda f: f[0])[1] if found else None
 
 
 def site_inventory(site):
