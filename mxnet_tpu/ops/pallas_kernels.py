@@ -7,12 +7,13 @@ reference). On non-TPU backends every kernel runs through the Pallas
 interpreter, so the same code path is testable on CPU.
 
 Design notes (see /opt/skills/guides/pallas_guide.md):
-- flash attention: grid over (batch*heads, q blocks, k blocks), k
-  innermost; the streaming softmax's stats and accumulator live in f32 VMEM
-  scratch, and causal blocks wholly above the diagonal (or wholly below a
-  sliding window) are skipped. The backward is two kernels (dK/dV over q
-  blocks, dQ over k blocks) that rebuild each score block from q, k and
-  the forward's log-sum-exp in VMEM.
+- flash attention: grid over (batch*heads, schedule entries), where a
+  schedule lists only the (q block, k block) pairs that hold a pair that
+  counts (not wholly above the diagonal, not wholly below a sliding
+  window), in the sweep's order, prefetched into SMEM; the streaming
+  softmax's stats and accumulator live in f32 VMEM scratch. The backward
+  is two kernels (dK/dV over q blocks, dQ over k blocks) that rebuild each
+  score block from q, k and the forward's log-sum-exp in VMEM.
 - fused LSTM: the input projection x@Wx for ALL timesteps is one big MXU
   matmul outside the kernel; the kernel walks time on the grid with h/c
   held in VMEM scratch, doing only the recurrent h@Wh matmul per step.
@@ -23,8 +24,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .. import telemetry
 
 __all__ = ["flash_attention", "fused_lstm", "is_tpu"]
 
@@ -85,49 +89,163 @@ def _pad_to(x, t):
     return jnp.pad(x, pad)
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
-                      acc_scr, *, scale, causal, window, block_q, block_k,
-                      seq_k):
-    """Grid (bh, q blocks, k blocks); k innermost. The streaming-softmax
-    stats m/l and the output accumulator live in VMEM scratch (persisted
-    across the k sweep) with lane-replicated (block_q, 128) stats — value
-    carries of big f32 arrays through fori_loop blow Mosaic's register
-    budget. Besides the output it writes each row's log-sum-exp
-    ``m + log(l)``, lane-replicated, for the backward."""
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
-    scale32 = jnp.float32(scale)
+# the flags of a schedule entry (`_schedule`)
+_FIRST, _LAST, _EDGE, _EMPTY = 1, 2, 4, 8
+
+
+def _schedule(*, causal, window, block_q, block_k, seq_q, seq_k, k_major):
+    """The (q block, k block) pairs a kernel visits, in the order of its
+    sweep: q-major with k ascending (the forward, dQ) or k-major with q
+    ascending (dK/dV). A pair is listed when it holds a (query, key) pair
+    that counts: causal, inside the window (query i sees the keys j with
+    i - window < j <= i) and neither one padding. `_FIRST` and `_LAST`
+    mark the ends of each output block's run and `_EDGE` a listed pair
+    that also holds one that does not count, the only ones the kernels
+    mask. An output block with no pair that counts gets one `_EMPTY`
+    entry, which writes it as zeros. Returns int32 arrays (q blocks,
+    k blocks, flags); without ``causal`` the table is the dense grid."""
+    nq, nk = -(-seq_q // block_q), -(-seq_k // block_k)
+    q0 = np.arange(nq)[:, None] * block_q
+    k0 = np.arange(nk)[None, :] * block_k
+    q1 = np.minimum(q0 + block_q, seq_q) - 1          # last query, last key
+    k1 = np.minimum(k0 + block_k, seq_k) - 1
+    runs = np.ones((nq, nk), bool)
+    whole = (q0 + block_q <= seq_q) & (k0 + block_k <= seq_k)
+    if causal:
+        runs &= k0 <= q1
+        whole &= k1 <= q0
+    if window is not None:
+        runs &= q0 - k1 < window
+        whole &= q1 - k0 < window
+    if k_major:
+        runs, whole = runs.T, whole.T
+    qb, kb, fl = [], [], []
+    for out, row in enumerate(runs):
+        inner = np.flatnonzero(row)
+        flags = np.where(whole[out, inner], 0, _EDGE)
+        if not len(inner):
+            inner, flags = np.zeros(1, int), np.array([_EMPTY])
+        flags[0] |= _FIRST
+        flags[-1] |= _LAST
+        outer = np.full(len(inner), out)
+        qb.append(inner if k_major else outer)
+        kb.append(outer if k_major else inner)
+        fl.append(flags)
+    return tuple(np.concatenate(x).astype(np.int32) for x in (qb, kb, fl))
+
+
+# the index maps of a kernel's blocks, from the entry's (q block i, k
+# block j). They return j*0 instead of a literal 0: the package runs with
+# jax_enable_x64 (base.py), under which a Python int lowers as i64, and
+# Mosaic cannot legalize an i64 in the index map's func.return; j*0 stays
+# i32 like the table's entries (tests/test_chip_compile.py compiles these
+# for v5e)
+def _at_q(b, i, j):
+    return b, i, j * 0
+
+
+def _at_k(b, i, j):
+    return b, j, i * 0
+
+
+def _sweep(kernel, name, table, dense, bh, in_specs, out_specs, out_shape,
+           scratch_shapes, *args):
+    """``kernel`` over the grid (row-heads, entries of ``table``), the
+    table prefetched into SMEM. Each spec is (block shape, index map of
+    the entry's block indices). ``dense``: the steps of the (q block,
+    k block) grid, for ``flash_attention_block_steps_total``."""
+    qb, kb, fl = table
+    for kind, steps in (("run", len(fl)), ("dense", dense)):
+        telemetry.counter(
+            "flash_attention_block_steps_total",
+            help="flash attention's grid steps a lowering, by kernel: the "
+                 "schedule's (run) against the dense grid's (dense)",
+            kernel=name, kind=kind).inc(bh * steps)
+
+    def spec(shape, index):
+        return pl.BlockSpec(shape, lambda b, s, qb, kb, fl: index(
+            b, qb[s], kb[s]), memory_space=pltpu.VMEM)
+
+    kernel = functools.partial(kernel, edges=bool((fl & _EDGE).any()),
+                               empties=bool((fl & _EMPTY).any()))
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(bh, len(fl)),
+            in_specs=[spec(*s) for s in in_specs],
+            out_specs=[spec(*s) for s in out_specs],
+            scratch_shapes=scratch_shapes),
+        out_shape=out_shape,
+        interpret=_interpret(),
+    )(qb, kb, fl, *args)
+
+
+def _entry(qb_ref, kb_ref, fl_ref):
+    """This grid step's q block, k block and flags."""
+    s = pl.program_id(1)
+    return qb_ref[s], kb_ref[s], fl_ref[s]
+
+
+def _visit(body, flags, *, edges, empties):
+    """Run ``body(masked)`` on a schedule entry: masked on an `_EDGE` one,
+    not at all on an `_EMPTY` one. ``edges`` and ``empties`` say whether
+    the table holds any, so a table without them traces no test."""
+    if edges:
+        pl.when((flags & _EDGE) != 0)(lambda: body(True))
+    if edges or empties:
+        pl.when((flags & (_EDGE | _EMPTY)) == 0)(lambda: body(False))
+    else:
+        body(False)
+
+
+def _masked(s, qi, ki, q_axis, *, causal, window, block_q, block_k, seq_q,
+            seq_k):
+    """Score block ``s`` (queries along ``q_axis``) with the scores that do
+    not count set to _NEG: the K tail padding, the q tail padding, the
+    causal mask and the sliding window, each only where it can exclude
+    something."""
+    iota = lambda axis: jax.lax.broadcasted_iota(jnp.int32, s.shape, axis)
+    qpos = qi * block_q + iota(q_axis)
+    kpos = ki * block_k + iota(1 - q_axis)
+    mask = None
+    for on, cond in ((seq_k % block_k != 0, lambda: kpos < seq_k),
+                     (seq_q % block_q != 0, lambda: qpos < seq_q),
+                     (causal, lambda: qpos >= kpos),
+                     (window is not None, lambda: qpos - kpos < window)):
+        if on:
+            mask = cond() if mask is None else jnp.logical_and(mask, cond())
+    return jnp.where(mask, s, jnp.float32(_NEG))
+
+
+def _flash_fwd_kernel(qb_ref, kb_ref, fl_ref, q_ref, k_ref, v_ref, o_ref,
+                      lse_ref, m_scr, l_scr, acc_scr, *, scale, edges,
+                      empties, **kw):
+    """Grid (bh, schedule entries): each q block's k blocks in ascending
+    order. The streaming-softmax stats m/l and the output accumulator live
+    in VMEM scratch (persisted across the q block's entries) with
+    lane-replicated (block_q, 128) stats — value carries of big f32 arrays
+    through fori_loop blow Mosaic's register budget. Besides the output it
+    writes each row's log-sum-exp ``m + log(l)``, lane-replicated, for the
+    backward."""
+    qi, ki, flags = _entry(qb_ref, kb_ref, fl_ref)
     neg = jnp.float32(_NEG)
 
-    @pl.when(ki == 0)
+    @pl.when((flags & _FIRST) != 0)
     def _():
         m_scr[:] = jnp.full(m_scr.shape, neg, jnp.float32)
         l_scr[:] = jnp.zeros(l_scr.shape, jnp.float32)
         acc_scr[:] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    run = _block_runs(qi, ki, causal=causal, window=window, block_q=block_q,
-                      block_k=block_k)
-
-    @pl.when(run)
-    def _():
+    def body(masked):
         q = _cast(q_ref[0], jnp.float32)                  # (block_q, d)
         k = _cast(k_ref[0], jnp.float32)                  # (block_k, d)
         v = _cast(v_ref[0], jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale32  # (bq, bk)
-        kpos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = kpos < seq_k                               # K/V tail padding
-        if causal:
-            qpos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            mask = jnp.logical_and(mask, qpos >= kpos)
-        if window is not None:
-            mask = jnp.logical_and(mask, qpos - kpos < window)
-        s = jnp.where(mask, s, neg)
-
+            preferred_element_type=jnp.float32) * jnp.float32(scale)
+        if masked:
+            s = _masked(s, qi, ki, 0, **kw)                # (bq, bk)
+        block_k = s.shape[1]
         m_prev = m_scr[:]                                 # (bq, 128)
         l_prev = l_scr[:]
         m_curr = jnp.max(s, axis=1)[:, None]              # (bq, 1)
@@ -146,23 +264,12 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * _lanes_bcast(l_inv, d)
 
-    @pl.when(ki == nk - 1)
+    _visit(body, flags, edges=edges, empties=empties)
+
+    @pl.when((flags & _LAST) != 0)
     def _():
         o_ref[0] = _cast(acc_scr[:], o_ref.dtype)
         lse_ref[0] = m_scr[:] + jnp.log(l_scr[:])
-
-
-def _block_runs(qi, ki, *, causal, window, block_q, block_k):
-    """Whether the (q block, k block) pair holds a score that counts:
-    not wholly above the diagonal (``causal``) and not wholly below the
-    sliding window (query i sees keys j with i - window < j <= i)."""
-    run = True
-    if causal:
-        run = qi * block_q + (block_q - 1) >= ki * block_k
-    if window is not None:
-        run = jnp.logical_and(
-            run, qi * block_q - (ki * block_k + (block_k - 1)) < window)
-    return run
 
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, window=None):
@@ -172,108 +279,38 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, window=None):
     tk = k.shape[1]
     block_q = _clamp(block_q, tq)
     block_k = _clamp(block_k, tk)
-    # pad K/V to a block multiple so every grid block is full-size; the
-    # kpos mask neutralises the padded keys
-    tk_pad = pl.cdiv(tk, block_k) * block_k
-    k = _pad_to(k, tk_pad)
-    v = _pad_to(v, tk_pad)
-    grid = (bh, pl.cdiv(tq, block_q), tk_pad // block_k)
-    kern = functools.partial(
-        _flash_fwd_kernel, scale=scale, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, seq_k=tk)
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            # index maps return j*0 instead of a literal 0: the package
-            # runs with jax_enable_x64 (base.py), under which a Python
-            # int lowers as i64, and Mosaic cannot legalize an i64 in
-            # the index map's func.return; j*0 stays i32 like the grid
-            # index (tests/test_chip_compile.py compiles these for v5e)
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, j * 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, i * 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, i * 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, j * 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, j * 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, tq, _LANES), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
-        ],
-        interpret=_interpret(),
-    )(q, k, v)
+    # pad K/V to a block multiple so every k block is full-size; the mask
+    # of the tail's entries neutralises the padded keys
+    nq, nk = pl.cdiv(tq, block_q), pl.cdiv(tk, block_k)
+    k = _pad_to(k, nk * block_k)
+    v = _pad_to(v, nk * block_k)
+    kw = dict(causal=causal, window=window, block_q=block_q,
+              block_k=block_k, seq_q=tq, seq_k=tk)
+    q_blk, k_blk = (1, block_q, d), (1, block_k, d)
+    return _sweep(
+        functools.partial(_flash_fwd_kernel, scale=scale, **kw), "fwd",
+        _schedule(k_major=False, **kw), nq * nk, bh,
+        [(q_blk, _at_q), (k_blk, _at_k), (k_blk, _at_k)],
+        [(q_blk, _at_q), ((1, block_q, _LANES), _at_q)],
+        [jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
+         jax.ShapeDtypeStruct((bh, tq, _LANES), jnp.float32)],
+        [pltpu.VMEM((block_q, _LANES), jnp.float32),
+         pltpu.VMEM((block_q, _LANES), jnp.float32),
+         pltpu.VMEM((block_q, d), jnp.float32)],
+        q, k, v)
 
 
-def _bwd_masked(s, qi, ki, q_axis, *, causal, window, block_q, block_k,
-                seq_q, seq_k):
-    """Score block ``s`` (queries along ``q_axis``) with the scores that do
-    not count set to _NEG: the K tail padding, the q tail padding, the
-    causal mask and the sliding window, each only where it can exclude
-    something."""
-    iota = lambda axis: jax.lax.broadcasted_iota(jnp.int32, s.shape, axis)
-    qpos = qi * block_q + iota(q_axis)
-    kpos = ki * block_k + iota(1 - q_axis)
-    mask = None
-    for on, cond in ((seq_k % block_k != 0, lambda: kpos < seq_k),
-                     (seq_q % block_q != 0, lambda: qpos < seq_q),
-                     (causal, lambda: qpos >= kpos),
-                     (window is not None, lambda: qpos - kpos < window)):
-        if on:
-            mask = cond() if mask is None else jnp.logical_and(mask, cond())
-    return jnp.where(mask, s, jnp.float32(_NEG))
-
-
-def _bwd_blocks(body, qi, ki, *, causal, window, block_q, block_k, seq_q,
-                seq_k):
-    """Run ``body(masked)`` on one (q block, k block) pair of the
-    backward: not at all when the block lies wholly above the diagonal or
-    below the window, with the mask only where the diagonal, the window's
-    edge or a tail padding crosses it."""
-    q_ragged, k_ragged = seq_q % block_q != 0, seq_k % block_k != 0
-    crossed = []
-    if causal:
-        crossed.append(qi * block_q < ki * block_k + (block_k - 1))
-    if q_ragged:
-        crossed.append(qi * block_q + block_q > seq_q)
-    if k_ragged:
-        crossed.append(ki * block_k + block_k > seq_k)
-    if window is not None:
-        crossed.append(qi * block_q + (block_q - 1) - ki * block_k >= window)
-    run = _block_runs(qi, ki, causal=causal, window=window, block_q=block_q,
-                      block_k=block_k)
-    if not crossed:
-        pl.when(run)(lambda: body(False))
-        return
-    edge = functools.reduce(jnp.logical_or, crossed)
-    pl.when(jnp.logical_and(run, edge))(lambda: body(True))
-    pl.when(jnp.logical_and(run, jnp.logical_not(edge)))(
-        lambda: body(False))
-
-
-def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                      dk_ref, dv_ref, dk_scr, dv_scr, *, scale, **kw):
-    """Grid (bh, k blocks, q blocks); q innermost, dK and dV accumulate in
-    f32 VMEM scratch across the q sweep. The block works on TRANSPOSED
-    scores (block_k, block_q), so each query's log-sum-exp and delta come
-    as one (1, block_q) row and both products are plain ones:
+def _flash_dkv_kernel(qb_ref, kb_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
+                      lse_ref, delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
+                      scale, edges, empties, **kw):
+    """Grid (bh, schedule entries): each k block's q blocks in ascending
+    order, dK and dV accumulating in f32 VMEM scratch. The block works on
+    TRANSPOSED scores (block_k, block_q), so each query's log-sum-exp and
+    delta come as one (1, block_q) row and both products are plain ones:
     dV += P^T dO, dK += dS^T q."""
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
-    nq = pl.num_programs(2)
+    qi, ki, flags = _entry(qb_ref, kb_ref, fl_ref)
 
-    @pl.when(qi == 0)
+    @pl.when((flags & _FIRST) != 0)
     def _():
         dk_scr[:] = jnp.zeros(dk_scr.shape, jnp.float32)
         dv_scr[:] = jnp.zeros(dv_scr.shape, jnp.float32)
@@ -284,7 +321,7 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             k, q, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * jnp.float32(scale)
         if masked:
-            st = _bwd_masked(st, qi, ki, 1, **kw)
+            st = _masked(st, qi, ki, 1, **kw)
         pt = jnp.exp(st - lse_ref[0])                     # (bk, bq)
         dv_scr[:] += jnp.dot(_cast(pt, do.dtype), do,
                              preferred_element_type=jnp.float32)
@@ -295,25 +332,24 @@ def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[:] += jnp.dot(_cast(dst, q.dtype), q,
                              preferred_element_type=jnp.float32)
 
-    _bwd_blocks(body, qi, ki, **kw)
+    _visit(body, flags, edges=edges, empties=empties)
 
-    @pl.when(qi == nq - 1)
+    @pl.when((flags & _LAST) != 0)
     def _():
         dk_ref[0] = _cast(dk_scr[:] * jnp.float32(scale), dk_ref.dtype)
         dv_ref[0] = _cast(dv_scr[:], dv_ref.dtype)
 
 
-def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
-                     dq_scr, delta_scr, *, scale, **kw):
-    """Grid (bh, q blocks, k blocks); k innermost, dQ accumulates in f32
-    VMEM scratch across the k sweep. lse comes lane-replicated,
+def _flash_dq_kernel(qb_ref, kb_ref, fl_ref, q_ref, k_ref, v_ref, do_ref,
+                     o_ref, lse_ref, dq_ref, dq_scr, delta_scr, *, scale,
+                     edges, empties, **kw):
+    """Grid (bh, schedule entries): each q block's k blocks in ascending
+    order, dQ accumulating in f32 VMEM scratch. lse comes lane-replicated,
     (block_q, 128), as the forward keeps its stats; the block's delta is
-    formed here, in the same layout, at the sweep's first step."""
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    formed here, in the same layout, at the q block's first entry."""
+    qi, ki, flags = _entry(qb_ref, kb_ref, fl_ref)
 
-    @pl.when(ki == 0)
+    @pl.when((flags & _FIRST) != 0)
     def _():
         dq_scr[:] = jnp.zeros(dq_scr.shape, jnp.float32)
         delta = jnp.sum(_cast(do_ref[0], jnp.float32)
@@ -326,7 +362,7 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * jnp.float32(scale)
         if masked:
-            s = _bwd_masked(s, qi, ki, 0, **kw)
+            s = _masked(s, qi, ki, 0, **kw)
         p = jnp.exp(s - _lanes_bcast(lse_ref[0], s.shape[1]))  # (bq, bk)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
@@ -335,9 +371,9 @@ def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
         dq_scr[:] += jnp.dot(_cast(ds, k.dtype), k,
                              preferred_element_type=jnp.float32)
 
-    _bwd_blocks(body, qi, ki, **kw)
+    _visit(body, flags, edges=edges, empties=empties)
 
-    @pl.when(ki == nk - 1)
+    @pl.when((flags & _LAST) != 0)
     def _():
         dq_ref[0] = _cast(dq_scr[:] * jnp.float32(scale), dq_ref.dtype)
 
@@ -354,13 +390,10 @@ def _flash_bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
                window=None):
     """Gradients of `_flash_fwd`'s output: q,k,v,o,do [BH, T, D], lse its
     [BH, Tq, 128] -> (dq, dk, dv). Two kernels recompute each score block
-    from q, k and lse and keep it in VMEM: one sweeps q blocks for a k
-    block's dK and dV, one sweeps k blocks for a q block's dQ. Under
-    ``causal`` a block wholly above the diagonal, and under ``window`` one
-    wholly below the window, is skipped, and its index map names the block
-    the sweep runs next to it, so no DMA is spent on it. P and dS enter
-    the products in the operands' dtype; the softmax algebra and the
-    accumulators are float32."""
+    from q, k and lse and keep it in VMEM: one sweeps a k block's q blocks
+    for its dK and dV, one a q block's k blocks for its dQ, each over the
+    pairs its schedule lists. P and dS enter the products in the operands'
+    dtype; the softmax algebra and the accumulators are float32."""
     bh, tq, d = q.shape
     tk = k.shape[1]
     block_q = _clamp(block_q, tq)
@@ -373,71 +406,33 @@ def _flash_bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
     q, do, o = (_pad_to(x, nq * block_q) for x in (q, do, o))
     k, v = _pad_to(k, nk * block_k), _pad_to(v, nk * block_k)
     lse, delta = _pad_to(lse, nq * block_q), _pad_to(delta, nq * block_q)
-    kw = dict(scale=scale, causal=causal, window=window, block_q=block_q,
+    kw = dict(causal=causal, window=window, block_q=block_q,
               block_k=block_k, seq_q=tq, seq_k=tk)
+    q_blk, k_blk = (1, block_q, d), (1, block_k, d)
+    f32 = lambda rows: pltpu.VMEM((rows, d), jnp.float32)
 
-    def vmem(shape, index):
-        return pl.BlockSpec(shape, index, memory_space=pltpu.VMEM)
+    def row(b, i, j):
+        return b, i * 0, i
 
-    # index maps stay in i32 lax ops: jnp's min, max and floor division
-    # wrap an identity convert that Mosaic's lowering recurses on, and a
-    # Python int beside an i32 index is i64 here (see `_flash_fwd`)
-    const = lambda x, n: x * 0 + n
-    div = lambda x, n: jax.lax.div(x, const(x, n))
-
-    # dK/dV: grid (b, j, i). A q block above the diagonal comes before the
-    # first one that runs for k block j, so it names that one; one below
-    # the window comes after the last, and names that one
-    if causal:
-        last = lambda j, i: const(i, nq - 1)
-        if window is not None:
-            last = lambda j, i: jax.lax.min(
-                div(j * block_k + (block_k + window - 2), block_q),
-                const(i, nq - 1))
-        q_at = lambda j, i: jax.lax.min(
-            jax.lax.max(i, div(j * block_k, block_q)), last(j, i))
-    else:
-        q_at = lambda j, i: i
-    q_spec = vmem((1, block_q, d), lambda b, j, i: (b, q_at(j, i), i * 0))
-    k_spec = vmem((1, block_k, d), lambda b, j, i: (b, j, i * 0))
-    row_spec = vmem((1, 1, block_q), lambda b, j, i: (b, i * 0, q_at(j, i)))
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_dkv_kernel, **kw),
-        grid=(bh, nk, nq),
-        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
-        out_specs=[k_spec, k_spec],
-        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
-        interpret=_interpret(),
-    )(q, k, v, do, lse[:, None, :, 0], delta[:, None, :])
-
-    # dQ: grid (b, i, j). A k block above the diagonal comes after the
-    # last one that runs for q block i, so it names that one; one below the
-    # window comes before the first, and names that one
-    if causal:
-        k_at = lambda i, j: jax.lax.min(
-            j, div(i * block_q + (block_q - 1), block_k))
-        if window is not None:
-            upto = k_at
-            k_at = lambda i, j: jax.lax.max(upto(i, j), div(jax.lax.max(
-                i * block_q - (window - 1), const(i, 0)), block_k))
-    else:
-        k_at = lambda i, j: j
-    q_spec = vmem((1, block_q, d), lambda b, i, j: (b, i, j * 0))
-    k_spec = vmem((1, block_k, d), lambda b, i, j: (b, k_at(i, j), j * 0))
-    lane_spec = vmem((1, block_q, _LANES), lambda b, i, j: (b, i, j * 0))
-    dq = pl.pallas_call(
-        functools.partial(_flash_dq_kernel, **kw),
-        grid=(bh, nq, nk),
-        in_specs=[q_spec, k_spec, k_spec, q_spec, q_spec, lane_spec],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
-                        pltpu.VMEM((block_q, _LANES), jnp.float32)],
-        interpret=_interpret(),
-    )(q, k, v, do, o, lse)
+    dk, dv = _sweep(
+        functools.partial(_flash_dkv_kernel, scale=scale, **kw), "dkv",
+        _schedule(k_major=True, **kw), nq * nk, bh,
+        [(q_blk, _at_q), (k_blk, _at_k), (k_blk, _at_k), (q_blk, _at_q),
+         ((1, 1, block_q), row), ((1, 1, block_q), row)],
+        [(k_blk, _at_k), (k_blk, _at_k)],
+        [jax.ShapeDtypeStruct(k.shape, k.dtype),
+         jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        [f32(block_k), f32(block_k)],
+        q, k, v, do, lse[:, None, :, 0], delta[:, None, :])
+    dq, = _sweep(
+        functools.partial(_flash_dq_kernel, scale=scale, **kw), "dq",
+        _schedule(k_major=False, **kw), nq * nk, bh,
+        [(q_blk, _at_q), (k_blk, _at_k), (k_blk, _at_k), (q_blk, _at_q),
+         (q_blk, _at_q), ((1, block_q, _LANES), _at_q)],
+        [(q_blk, _at_q)],
+        [jax.ShapeDtypeStruct(q.shape, q.dtype)],
+        [f32(block_q), pltpu.VMEM((block_q, _LANES), jnp.float32)],
+        q, k, v, do, o, lse)
     return dq[:, :tq], dk[:, :tk], dv[:, :tk]
 
 
@@ -468,13 +463,13 @@ def flash_attention(q, k, v, scale=None, causal=False,
 
     ``window`` (with ``causal``) is a sliding window: query i sees the keys
     j with i - window < j <= i, itself among them; blocks wholly below it
-    are skipped. None, every key up to the query.
+    are not visited. None, every key up to the query.
 
     ``block_q`` and ``block_k`` tile the forward; the backward's tiles are
     `_BWD_BLOCK_Q` x `_BWD_BLOCK_K`. Both were measured on the v5e
-    (docs/architecture/note_kernels.md). The k axis must stay the
-    innermost sequential grid dim — the streaming softmax scratch carries
-    across it."""
+    (docs/architecture/note_kernels.md). Each kernel visits the block
+    pairs its `_schedule` lists, one output block's run after another —
+    the streaming softmax scratch carries across a run."""
     b, tq, h, d = q.shape
     tk, hk = k.shape[1], k.shape[2]
     if window is not None and (not causal or window < 1):
@@ -569,7 +564,7 @@ def fused_lstm(x, h0, c0, wx, wh, b):
         kern,
         grid=(t,),
         in_specs=[
-            # i*0 instead of literal 0: see _flash_fwd index-map note
+            # i*0 instead of literal 0: see the note above `_at_q`
             pl.BlockSpec((1, bs, 4 * hidden), lambda i: (i, i * 0, i * 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((hidden, 4 * hidden), lambda i: (i * 0, i * 0),

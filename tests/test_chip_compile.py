@@ -223,11 +223,24 @@ def test_flash_attention_grouped_backward_kernels(one_chip, on_tpu):
     assert exe.memory_analysis().temp_size_in_bytes < 256 << 20
 
 
+def _pallas_grids(jaxpr):
+    """The grid of every Pallas call in ``jaxpr``, nested ones too."""
+    grids = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grids.append(tuple(eqn.params["grid_mapping"].grid))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            grids += _pallas_grids(sub)
+    return grids
+
+
 def test_flash_attention_windowed_kernels(one_chip, on_tpu):
     """SmallThinker's window layers at 8,192 tokens: 28 query heads over 4
     key-value heads of 128, a window of 4,096 keys. The forward and the
-    backward's two kernels compile with the window's bound and the blocks
-    below it skipped; the scores stay inside them."""
+    backward's two kernels compile with the window's bound, each over a
+    grid of (row-heads, its schedule's entries): the blocks below the
+    window and above the diagonal are not grid steps. The scores stay
+    inside the kernels."""
     bf16 = jnp.bfloat16
     spec = lambda heads: jax.ShapeDtypeStruct((2, 8192, heads, 128), bf16,
                                               sharding=one_chip)
@@ -237,10 +250,19 @@ def test_flash_attention_windowed_kernels(one_chip, on_tpu):
                                window=4096)
         return jnp.sum(o.astype(jnp.float32))
 
-    exe = _compile(jax.grad(loss, argnums=(0, 1, 2)), spec(28), spec(4),
-                   spec(4))
+    grad = jax.grad(loss, argnums=(0, 1, 2))
+    specs = spec(28), spec(4), spec(4)
+    exe = _compile(grad, *specs)
     assert exe.as_text().count("tpu_custom_call") == 3
     assert exe.memory_analysis().temp_size_in_bytes < 1 << 30
+    entries = lambda bq, k_major: len(pk._schedule(
+        causal=True, window=4096, block_q=bq, block_k=512, seq_q=8192,
+        seq_k=8192, k_major=k_major)[0])
+    fwd, dkv, dq = entries(512, False), entries(1024, True), entries(
+        1024, False)
+    assert (fwd, dkv, dq) == (108, 60, 60)
+    assert sorted(_pallas_grids(jax.make_jaxpr(grad)(*specs).jaxpr)) == \
+        sorted([(56, fwd), (56, dkv), (56, dq)])
 
 
 def test_moe_held_experts_at_published_widths(one_chip, on_tpu):
