@@ -200,7 +200,8 @@ def _rope(params, x):
 def _flash_attention_op(params, q, k, v):
     """Fused multi-head attention (Pallas flash kernel on TPU, interpreter
     elsewhere). Inputs [B, T, H, D], k and v with H or a divisor of it
-    (grouped-query); new capability — the reference has no
+    (grouped-query), v with a head width DV of its own (the output is
+    [B, T, H, DV]); new capability — the reference has no
     attention op (its sequence stack is cudnn_rnn, SURVEY §2.4). Attrs:
     causal (bool), scale (float, default 1/sqrt(D)), block_q/block_k
     (the forward kernel's tile sizes), window (int, with causal: query i

@@ -134,7 +134,9 @@ def invoke(op_name, inputs, params=None, out=None, name=None, ctx=None):
 
 
 def _aux_arrs(in_arrs, op):
-    return [in_arrs[i] for i in op.mutate_aux]
+    # an op whose auxiliary input is optional (`moe`'s selection bias) may
+    # be called without it
+    return [in_arrs[i] for i in op.mutate_aux if i < len(in_arrs)]
 
 
 def _apply(op, params, *vals):

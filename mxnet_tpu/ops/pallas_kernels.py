@@ -273,10 +273,11 @@ def _flash_fwd_kernel(qb_ref, kb_ref, fl_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, window=None):
-    """q,k,v: [BH, T, D] -> (o [BH, T, D], lse [BH, T, 128] float32, each
-    row's log-sum-exp of its scaled scores, lane-replicated)."""
+    """q,k: [BH, T, D], v: [BH, T, DV] -> (o [BH, T, DV], lse [BH, T, 128]
+    float32, each row's log-sum-exp of its scaled scores, lane-replicated).
+    A head's values may be narrower or wider than its queries and keys."""
     bh, tq, d = q.shape
-    tk = k.shape[1]
+    tk, dv = k.shape[1], v.shape[-1]
     block_q = _clamp(block_q, tq)
     block_k = _clamp(block_k, tk)
     # pad K/V to a block multiple so every k block is full-size; the mask
@@ -287,16 +288,17 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, window=None):
     kw = dict(causal=causal, window=window, block_q=block_q,
               block_k=block_k, seq_q=tq, seq_k=tk)
     q_blk, k_blk = (1, block_q, d), (1, block_k, d)
+    o_blk, v_blk = (1, block_q, dv), (1, block_k, dv)
     return _sweep(
         functools.partial(_flash_fwd_kernel, scale=scale, **kw), "fwd",
         _schedule(k_major=False, **kw), nq * nk, bh,
-        [(q_blk, _at_q), (k_blk, _at_k), (k_blk, _at_k)],
-        [(q_blk, _at_q), ((1, block_q, _LANES), _at_q)],
-        [jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
+        [(q_blk, _at_q), (k_blk, _at_k), (v_blk, _at_k)],
+        [(o_blk, _at_q), ((1, block_q, _LANES), _at_q)],
+        [jax.ShapeDtypeStruct((bh, tq, dv), q.dtype),
          jax.ShapeDtypeStruct((bh, tq, _LANES), jnp.float32)],
         [pltpu.VMEM((block_q, _LANES), jnp.float32),
          pltpu.VMEM((block_q, _LANES), jnp.float32),
-         pltpu.VMEM((block_q, d), jnp.float32)],
+         pltpu.VMEM((block_q, dv), jnp.float32)],
         q, k, v)
 
 
@@ -388,14 +390,15 @@ _BWD_BLOCK_Q, _BWD_BLOCK_K = 1024, 512
 
 def _flash_bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
                window=None):
-    """Gradients of `_flash_fwd`'s output: q,k,v,o,do [BH, T, D], lse its
-    [BH, Tq, 128] -> (dq, dk, dv). Two kernels recompute each score block
-    from q, k and lse and keep it in VMEM: one sweeps a k block's q blocks
-    for its dK and dV, one a q block's k blocks for its dQ, each over the
-    pairs its schedule lists. P and dS enter the products in the operands'
-    dtype; the softmax algebra and the accumulators are float32."""
+    """Gradients of `_flash_fwd`'s output: q,k [BH, T, D], v,o,do [BH, T,
+    DV], lse its [BH, Tq, 128] -> (dq, dk, dv). Two kernels recompute each
+    score block from q, k and lse and keep it in VMEM: one sweeps a k
+    block's q blocks for its dK and dV, one a q block's k blocks for its
+    dQ, each over the pairs its schedule lists. P and dS enter the
+    products in the operands' dtype; the softmax algebra and the
+    accumulators are float32."""
     bh, tq, d = q.shape
-    tk = k.shape[1]
+    tk, d_v = k.shape[1], v.shape[-1]
     block_q = _clamp(block_q, tq)
     block_k = _clamp(block_k, tk)
     nq, nk = pl.cdiv(tq, block_q), pl.cdiv(tk, block_k)
@@ -409,7 +412,8 @@ def _flash_bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
     kw = dict(causal=causal, window=window, block_q=block_q,
               block_k=block_k, seq_q=tq, seq_k=tk)
     q_blk, k_blk = (1, block_q, d), (1, block_k, d)
-    f32 = lambda rows: pltpu.VMEM((rows, d), jnp.float32)
+    o_blk, v_blk = (1, block_q, d_v), (1, block_k, d_v)
+    f32 = lambda rows, width: pltpu.VMEM((rows, width), jnp.float32)
 
     def row(b, i, j):
         return b, i * 0, i
@@ -417,21 +421,21 @@ def _flash_bwd(q, k, v, o, lse, do, scale, causal, block_q, block_k,
     dk, dv = _sweep(
         functools.partial(_flash_dkv_kernel, scale=scale, **kw), "dkv",
         _schedule(k_major=True, **kw), nq * nk, bh,
-        [(q_blk, _at_q), (k_blk, _at_k), (k_blk, _at_k), (q_blk, _at_q),
+        [(q_blk, _at_q), (k_blk, _at_k), (v_blk, _at_k), (o_blk, _at_q),
          ((1, 1, block_q), row), ((1, 1, block_q), row)],
-        [(k_blk, _at_k), (k_blk, _at_k)],
+        [(k_blk, _at_k), (v_blk, _at_k)],
         [jax.ShapeDtypeStruct(k.shape, k.dtype),
          jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        [f32(block_k), f32(block_k)],
+        [f32(block_k, d), f32(block_k, d_v)],
         q, k, v, do, lse[:, None, :, 0], delta[:, None, :])
     dq, = _sweep(
         functools.partial(_flash_dq_kernel, scale=scale, **kw), "dq",
         _schedule(k_major=False, **kw), nq * nk, bh,
-        [(q_blk, _at_q), (k_blk, _at_k), (k_blk, _at_k), (q_blk, _at_q),
-         (q_blk, _at_q), ((1, block_q, _LANES), _at_q)],
+        [(q_blk, _at_q), (k_blk, _at_k), (v_blk, _at_k), (o_blk, _at_q),
+         (o_blk, _at_q), ((1, block_q, _LANES), _at_q)],
         [(q_blk, _at_q)],
         [jax.ShapeDtypeStruct(q.shape, q.dtype)],
-        [f32(block_q), pltpu.VMEM((block_q, _LANES), jnp.float32)],
+        [f32(block_q, d), pltpu.VMEM((block_q, _LANES), jnp.float32)],
         q, k, v, do, o, lse)
     return dq[:, :tq], dk[:, :tk], dv[:, :tk]
 
@@ -458,7 +462,9 @@ def flash_attention(q, k, v, scale=None, causal=False,
                     block_q=512, block_k=512, window=None):
     """Fused attention on [B, T, H, D] (same layout as
     `parallel.ring_attention`); k and v may have fewer heads, a divisor of
-    q's (grouped-query). Differentiable: the forward and the backward are
+    q's (grouped-query), and v a head width of its own, DV: the output is
+    [B, T, H, DV] (latent attention's 192-wide queries and keys over
+    128-wide values). Differentiable: the forward and the backward are
     Pallas kernels, interpret-mode on CPU.
 
     ``window`` (with ``causal``) is a sliding window: query i sees the keys
@@ -471,7 +477,7 @@ def flash_attention(q, k, v, scale=None, causal=False,
     pairs its `_schedule` lists, one output block's run after another —
     the streaming softmax scratch carries across a run."""
     b, tq, h, d = q.shape
-    tk, hk = k.shape[1], k.shape[2]
+    tk, hk, dv = k.shape[1], k.shape[2], v.shape[-1]
     if window is not None and (not causal or window < 1):
         raise ValueError("a window of %r keys needs causal attention and at "
                          "least one key" % (window,))
@@ -484,10 +490,11 @@ def flash_attention(q, k, v, scale=None, causal=False,
         k = jnp.repeat(k, h // hk, axis=2)
         v = jnp.repeat(v, h // hk, axis=2)
     scale = (1.0 / d ** 0.5) if scale is None else scale
-    to_bh = lambda x, t: jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, t, d)
+    to_bh = lambda x, t: jnp.transpose(x, (0, 2, 1, 3)).reshape(
+        b * h, t, x.shape[-1])
     o = _flash(to_bh(q, tq), to_bh(k, tk), to_bh(v, tk),
                scale, causal, block_q, block_k, window)
-    return jnp.transpose(o.reshape(b, h, tq, d), (0, 2, 1, 3))
+    return jnp.transpose(o.reshape(b, h, tq, dv), (0, 2, 1, 3))
 
 
 # ---------------------------------------------------------------- fused LSTM

@@ -271,7 +271,11 @@ def op_table(logdir, line_filter=None, by="op", device_only=True):
     planes = []
     for p in files:
         planes.extend(parse_xspace(p))
-    dev = [p for p in planes if "/device:" in p.name]
+    # a device plane with no events (libtpu, once loaded in a process that
+    # runs on the CPU, adds an empty "/device:CUSTOM:Megascale Trace") is
+    # no device to read
+    dev = [p for p in planes if "/device:" in p.name
+           and any(l.events for l in p.lines)]
     if not dev and device_only:
         # pure-host capture: the busiest host line is the best signal
         dev = [p for p in planes if p.name.startswith("/host:")

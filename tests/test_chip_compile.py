@@ -265,6 +265,31 @@ def test_flash_attention_windowed_kernels(one_chip, on_tpu):
         sorted([(56, fwd), (56, dkv), (56, dq)])
 
 
+def test_flash_attention_latent_widths_kernels(one_chip, on_tpu):
+    """Moonlight-16B-A3B's latent attention at 8,192 tokens: 16 heads whose
+    queries and keys are 192 wide (128 without position, 64 rotary) over
+    values 128 wide, the scale 1/sqrt(192). The forward and the backward's
+    two kernels compile with the 192-wide blocks whole (no padding to 256)
+    and the 128-wide value blocks; the output and dV are 128 wide."""
+    bf16 = jnp.bfloat16
+    spec = lambda width: jax.ShapeDtypeStruct((2, 8192, 16, width), bf16,
+                                              sharding=one_chip)
+
+    def loss(q, k, v):
+        o = pk.flash_attention(q, k, v, scale=192 ** -0.5, causal=True)
+        assert o.shape == (2, 8192, 16, 128)
+        return jnp.sum(o.astype(jnp.float32))
+
+    grad = jax.grad(loss, argnums=(0, 1, 2))
+    specs = spec(192), spec(192), spec(128)
+    exe = _compile(grad, *specs)
+    assert exe.as_text().count("tpu_custom_call") == 3
+    assert exe.memory_analysis().temp_size_in_bytes < 1 << 30
+    dq, dk, dv = jax.eval_shape(grad, *specs)
+    assert dq.shape == dk.shape == (2, 8192, 16, 192)
+    assert dv.shape == (2, 8192, 16, 128)
+
+
 def test_moe_held_experts_at_published_widths(one_chip, on_tpu):
     """One SmallThinker expert layer's share at the benchmark's size: two
     rows of 8,192 tokens routed over 64 experts, 6 a token, 16 held, ReGLU

@@ -373,3 +373,57 @@ def test_window_attr_through_the_op():
     p /= p.sum(-1, keepdims=True)
     np.testing.assert_allclose(out, np.einsum("bhqk,bkhd->bqhd", p, v),
                                rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("causal,dv,t", [
+    (True, 16, 96), (False, 16, 96), (True, 8, 80), (True, 32, 64),
+], ids=["causal-narrow", "dense-narrow", "causal-ragged", "causal-wide"])
+def test_value_width_apart_from_key_width(monkeypatch, causal, dv, t):
+    """Latent attention's heads: queries and keys 24 wide (16 without
+    position, 8 rotary), values ``dv`` wide. The output is ``dv`` wide and
+    it and all three gradients are the dense math's, over several blocks
+    (32 a block, forward and backward), causal or not, at a length that is
+    not a block multiple, at the scale 1/sqrt(24) given explicitly."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops import pallas_kernels as pk
+    blk, heads, d = 32, 2, 24
+    monkeypatch.setattr(pk, "_BWD_BLOCK_Q", blk)
+    monkeypatch.setattr(pk, "_BWD_BLOCK_K", blk)
+    rng = np.random.RandomState(8)
+    q, k = (jnp.asarray(rng.randn(2, t, heads, d), jnp.float32)
+            for _ in range(2))
+    v, w = (jnp.asarray(rng.randn(2, t, heads, dv), jnp.float32)
+            for _ in range(2))
+    bh = lambda x: jnp.transpose(x, (0, 2, 1, 3)).reshape(
+        2 * heads, t, x.shape[-1])
+
+    def dense(q, k, v):
+        o = _dense_attention(bh(q), bh(k), bh(v), d ** -0.5, causal)
+        return jnp.transpose(o.reshape(2, heads, t, dv), (0, 2, 1, 3))
+
+    flash = lambda q, k, v: pk.flash_attention(
+        q, k, v, scale=d ** -0.5, causal=causal, block_q=blk, block_k=blk)
+    out = flash(q, k, v)
+    assert out.shape == (2, t, heads, dv)
+    np.testing.assert_allclose(out, dense(q, k, v), rtol=1e-4, atol=1e-5)
+    for got, want in zip(_grads(flash, q, k, v, w), _grads(dense, q, k, v, w)):
+        assert got.shape == want.shape
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+
+
+def test_value_width_through_the_op():
+    """The symbol's output takes v's head width; the scale is the attr."""
+    rng = np.random.RandomState(9)
+    qn, kn = (rng.randn(1, 16, 2, 12).astype("f") for _ in range(2))
+    vn = rng.randn(1, 16, 2, 4).astype("f")
+    sym = mx.sym.contrib.flash_attention(
+        mx.sym.var("q"), mx.sym.var("k"), mx.sym.var("v"), causal=True,
+        scale=12 ** -0.5)
+    _, out_shapes, _ = sym.infer_shape(q=qn.shape, k=kn.shape, v=vn.shape)
+    assert tuple(out_shapes[0]) == (1, 16, 2, 4)
+    ex = sym.simple_bind(mx.cpu(), q=qn.shape, k=kn.shape, v=vn.shape)
+    for name, x in (("q", qn), ("k", kn), ("v", vn)):
+        ex.arg_dict[name][:] = x
+    np.testing.assert_allclose(ex.forward()[0].asnumpy(),
+                               _oracle(qn, kn, vn, True), rtol=1e-4,
+                               atol=1e-5)

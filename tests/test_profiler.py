@@ -150,3 +150,21 @@ def test_xplane_cli(tmp_path):
     assert out.returncode == 0, out.stderr
     assert "TOTAL" in out.stdout
     assert (tmp_path / "t.json").exists()
+
+
+def test_op_table_reads_the_host_beside_an_empty_device_plane(
+        tmp_path, monkeypatch):
+    """A process that has loaded libtpu (a chip-free compile for a
+    described TPU) traces an empty ``/device:CUSTOM:Megascale Trace``
+    plane beside the CPU's: the table still falls back to the host's
+    events, as in a pure-CPU trace."""
+    from mxnet_tpu import xplane
+    (tmp_path / "t.xplane.pb").write_bytes(b"")
+    op = xplane.Event("dot.3", 0, 5000, {})
+    planes = [
+        xplane.Plane("/device:CUSTOM:Megascale Trace", [], {}, {}),
+        xplane.Plane("/host:CPU", [
+            xplane.Line("tf_XLAPjRtCpuClient/1", 0, [op])], {}, {})]
+    monkeypatch.setattr(xplane, "parse_xspace", lambda path: planes)
+    table = xplane.op_table(str(tmp_path), line_filter="PjRtCpuClient")
+    assert table["dot"]["total_ps"] == 5000
